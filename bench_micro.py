@@ -116,7 +116,7 @@ def bench_fanout_decision():
     """Per-tick fan-out decision cost: host scan (every subscriber gets a
     time check, ref data.go:175-291) vs device due-mask consumption (only
     due subscribers are visited). The device cost is flat in subscriber
-    count — VERDICT r1 item #3's acceptance metric."""
+    count."""
     from channeld_tpu.core.channel import Channel
     from channeld_tpu.core.data import FanOutConnection, ChannelData, tick_data
     from channeld_tpu.core.subscription import ChannelSubscription

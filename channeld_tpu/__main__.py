@@ -8,9 +8,9 @@ import sys
 
 
 def main() -> None:
-    from .utils.devices import pin_cpu_if_virtual_devices
+    from .utils.devices import place_compile_cache
 
-    pin_cpu_if_virtual_devices()
+    place_compile_cache()
     from .core.server import run_server
 
     try:

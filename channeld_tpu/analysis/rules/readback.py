@@ -1,10 +1,10 @@
 """hot-readback: no per-connection device->host syncs in tick paths.
 
-ROADMAP item 1 measured the bug class this rule now pins: a
-device->host readback per connection inside ``_apply_follow_interests``
-cost ~330us per follower and was closed at ~11x by batching every
-follower into ONE transfer (``engine.interested_cells_batch``,
-BENCH_RESULTS.md round 12).  The fix only stays fixed if nobody
+PR 12 measured the bug class this rule now pins: a device->host
+readback per connection inside ``_apply_follow_interests`` cost ~330us
+per follower on a CPU host and was closed at ~11x by batching every
+follower into ONE transfer (``engine.interested_cells_batch``; the
+record of that run was deleted in PR 21).  The fix only stays fixed if nobody
 reintroduces an implicit sync — ``.item()``, ``np.asarray`` /
 ``np.array`` on engine arrays, ``float()`` over a scalar index, direct
 scalar indexing of engine device arrays, or a call to the single-row

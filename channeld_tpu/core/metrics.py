@@ -716,8 +716,7 @@ follower_readbacks = Counter(
     "_apply_follow_interests — one BATCHED transfer per pass covering "
     "every AOI follower (engine.interested_cells_batch). Before the "
     "batching this counted one transfer per follower per pass "
-    "(ROADMAP item 1's measured bottleneck, ~330us each; "
-    "BENCH_RESULTS.md round 12 has the before/after)",
+    "(~330us each on a CPU host, PR 12's own before/after run)",
     registry=registry,
 )
 

@@ -204,6 +204,12 @@ def introspect() -> dict:
         doc["device"] = guard.state.name
     except Exception as e:
         doc["device"] = repr(e)
+    # What the spatial engine computes on (platform, device_kind, count,
+    # mesh, use_pallas, native_codec); None without a device controller.
+    from ..spatial.controller import get_spatial_controller
+
+    engine = getattr(get_spatial_controller(), "engine", None)
+    doc["engine"] = dict(engine.device_info) if engine is not None else None
     try:
         doc["wal"] = {
             "configured": bool(global_settings.wal_path),

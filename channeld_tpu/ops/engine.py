@@ -41,6 +41,35 @@ from .spatial_ops import (
 logger = get_logger("ops.engine")
 
 
+def _bucket(rows) -> np.ndarray:
+    """A dirty-row collection as an i32 index vector padded to the next
+    power of two by repeating its last index. Scatter shapes then come
+    from a fixed set that ``warmup`` compiles before the listeners open,
+    instead of following the dirty count into a fresh XLA compile inside
+    the watchdog window (on the chip that read as a hang; PR 21). A
+    repeated ``.set`` of the same row with the same value is idempotent."""
+    k = len(rows)
+    idx = np.fromiter(rows, np.int32, k)
+    pad = (1 << (k - 1).bit_length()) - k
+    if pad == 0:
+        return idx
+    return np.concatenate([idx, np.full(pad, idx[-1], np.int32)])
+
+
+def _buckets(capacity: int) -> list[int]:
+    """Every length ``_bucket`` can return for a table of ``capacity``."""
+    return [1 << b for b in range((capacity - 1).bit_length() + 1)]
+
+
+@jax.jit
+def _set_rows(arr, idx, vals):
+    """``arr.at[idx].set(vals)`` as ONE program per (table, bucket). The
+    eager form dispatches about eight small programs per new shape
+    (index normalisation, broadcasts, the scatter). Not donated: the old
+    array stays valid until the fenced store replaces it."""
+    return arr.at[idx].set(vals)
+
+
 class SpatialEngine:
     def __init__(
         self,
@@ -95,8 +124,11 @@ class SpatialEngine:
             self._entity_ns = NamedSharding(
                 mesh, PartitionSpec(tuple(mesh.axis_names))
             )
+            # Query and sub tables are read whole by every shard.
+            self._replicated_ns = NamedSharding(mesh, PartitionSpec())
         else:
             self._entity_ns = None
+            self._replicated_ns = None
         self.grid = grid
         self.entity_capacity = entity_capacity
         self.query_capacity = query_capacity
@@ -248,6 +280,24 @@ class SpatialEngine:
         from .pallas_kernels import pallas_available
 
         self.use_pallas = pallas_available() and mesh is None
+        # What this engine runs on, said once at construction and served
+        # under /introspect: a gateway on the chip must be tellable from
+        # one that is not (JAX falls back to the CPU with only a warning
+        # when TPU init fails and JAX_PLATFORMS is unset).
+        from ..native import codec as native_codec
+        from ..utils.devices import describe_devices
+
+        self.device_info = {
+            **describe_devices(mesh),
+            "use_pallas": self.use_pallas,
+            "native_codec": native_codec is not None,
+        }
+        logger.info(
+            "spatial engine on platform=%(platform)s "
+            "device_kind=%(device_kind)r device_count=%(device_count)d "
+            "mesh=%(mesh)s use_pallas=%(use_pallas)s "
+            "native_codec=%(native_codec)s", self.device_info,
+        )
 
     # ---- entity slots ----------------------------------------------------
 
@@ -564,6 +614,15 @@ class SpatialEngine:
             return arr
         return jax.device_put(arr, self._entity_ns)
 
+    def _put_replicated(self, arr: np.ndarray):
+        """Upload a whole query/sub column. With a mesh it is committed
+        replicated, matching the sharded step's in_specs; a bare
+        jnp.asarray would sit on device 0 and be re-broadcast into
+        shard_map on every tick."""
+        if self._replicated_ns is None:
+            return jnp.asarray(arr)
+        return jax.device_put(arr, self._replicated_ns)
+
     def _flush_host_state(self, expect_generation: Optional[int] = None) -> None:
         def _fence() -> None:
             # Stale-tick fence (core/device_guard.py): a watchdog-
@@ -578,22 +637,22 @@ class SpatialEngine:
 
         _fence()
         if self._dirty_slots:
-            idx = np.fromiter(self._dirty_slots, np.int32, len(self._dirty_slots))
+            idx = _bucket(self._dirty_slots)
             d_positions = self._keep_entity_sharding(
-                self._d_positions.at[idx].set(self._positions[idx])
+                _set_rows(self._d_positions, idx, self._positions[idx])
             )
             d_valid = self._keep_entity_sharding(
-                self._d_valid.at[idx].set(self._valid[idx])
+                _set_rows(self._d_valid, idx, self._valid[idx])
             )
             _fence()
             self._d_positions = d_positions
             self._d_valid = d_valid
             self._dirty_slots.clear()
         if self._seed_cells:
-            slots = np.fromiter(self._seed_cells.keys(), np.int32, len(self._seed_cells))
-            cells = np.fromiter(self._seed_cells.values(), np.int32, len(self._seed_cells))
+            slots = _bucket(self._seed_cells.keys())
+            cells = _bucket(self._seed_cells.values())
             d_cell = self._keep_entity_sharding(
-                self._d_cell.at[slots].set(cells)
+                _set_rows(self._d_cell, slots, cells)
             )
             _fence()
             self._d_cell = d_cell
@@ -614,14 +673,13 @@ class SpatialEngine:
                 self._d_agent = d_agent
                 self._sim_dirty.clear()
             elif self._sim_dirty:
-                idx = np.fromiter(self._sim_dirty, np.int32,
-                                  len(self._sim_dirty))
-                d_vel = self._d_vel.at[idx].set(self._vel[idx])
-                d_state = self._d_sim_state.at[idx].set(self._sim_state[idx])
-                d_target = self._d_sim_target.at[idx].set(
-                    self._sim_target[idx]
-                )
-                d_agent = self._d_agent.at[idx].set(self._agent_mask[idx])
+                idx = _bucket(self._sim_dirty)
+                d_vel = _set_rows(self._d_vel, idx, self._vel[idx])
+                d_state = _set_rows(self._d_sim_state, idx,
+                                    self._sim_state[idx])
+                d_target = _set_rows(self._d_sim_target, idx,
+                                     self._sim_target[idx])
+                d_agent = _set_rows(self._d_agent, idx, self._agent_mask[idx])
                 _fence()
                 self._d_vel = d_vel
                 self._d_sim_state = d_state
@@ -639,18 +697,15 @@ class SpatialEngine:
         if self._q_spot_dist is not None:
             if self._d_spot_dist is None:
                 # .copy(): async H2D vs later host row writes (below).
-                d_spot = jnp.asarray(self._q_spot_dist.copy())
+                d_spot = self._put_replicated(self._q_spot_dist.copy())
                 _fence()
                 self._d_spot_dist = d_spot
                 self._spot_dirty_rows.clear()
                 spots_changed = True
             elif self._spot_dirty_rows:
-                idx = np.fromiter(
-                    self._spot_dirty_rows, np.int32, len(self._spot_dirty_rows)
-                )
-                d_spot = self._d_spot_dist.at[idx].set(
-                    self._q_spot_dist[idx]
-                )
+                idx = _bucket(self._spot_dirty_rows)
+                d_spot = _set_rows(self._d_spot_dist, idx,
+                                   self._q_spot_dist[idx])
                 _fence()
                 self._d_spot_dist = d_spot
                 self._spot_dirty_rows.clear()
@@ -663,11 +718,11 @@ class SpatialEngine:
             # the deferred copy (observed on a loaded host as a query
             # table whose slot read as cleared one tick early).
             d_queries = QuerySet(
-                jnp.asarray(self._q_kind.copy()),
-                jnp.asarray(self._q_center.copy()),
-                jnp.asarray(self._q_extent.copy()),
-                jnp.asarray(self._q_dir.copy()),
-                jnp.asarray(self._q_angle.copy()),
+                self._put_replicated(self._q_kind.copy()),
+                self._put_replicated(self._q_center.copy()),
+                self._put_replicated(self._q_extent.copy()),
+                self._put_replicated(self._q_dir.copy()),
+                self._put_replicated(self._q_angle.copy()),
                 self._d_spot_dist,
             )
             _fence()
@@ -676,9 +731,9 @@ class SpatialEngine:
         if self._d_sub_state is None:
             # .copy(): async H2D vs later host writes to these mirrors.
             d_sub = (
-                jnp.asarray(self._sub_last.copy()),
-                jnp.asarray(self._sub_interval.copy()),
-                jnp.asarray(self._sub_active.copy()),
+                self._put_replicated(self._sub_last.copy()),
+                self._put_replicated(self._sub_interval.copy()),
+                self._put_replicated(self._sub_active.copy()),
             )
             _fence()
             self._d_sub_state = d_sub
@@ -691,16 +746,13 @@ class SpatialEngine:
             last, interval, active = self._d_sub_state
             last_idx = sub_idx = None
             if self._sub_last_dirty:
-                last_idx = np.fromiter(
-                    self._sub_last_dirty, np.int32, len(self._sub_last_dirty)
-                )
-                last = last.at[last_idx].set(self._sub_last[last_idx])
+                last_idx = _bucket(self._sub_last_dirty)
+                last = _set_rows(last, last_idx, self._sub_last[last_idx])
             if self._sub_dirty_slots:
-                sub_idx = np.fromiter(
-                    self._sub_dirty_slots, np.int32, len(self._sub_dirty_slots)
-                )
-                interval = interval.at[sub_idx].set(self._sub_interval[sub_idx])
-                active = active.at[sub_idx].set(self._sub_active[sub_idx])
+                sub_idx = _bucket(self._sub_dirty_slots)
+                interval = _set_rows(interval, sub_idx,
+                                     self._sub_interval[sub_idx])
+                active = _set_rows(active, sub_idx, self._sub_active[sub_idx])
             _fence()
             self._d_sub_state = (last, interval, active)
             if last_idx is not None:
@@ -715,9 +767,42 @@ class SpatialEngine:
         channel tick, stalling the event loop long enough for the unauth
         reaper to blacklist slow-authing peers (observed end-to-end with
         the meshed cells plane). The warmup tick mutates nothing the
-        serving path reads: tables are empty and inactive."""
-        self.tick(now_ms=0)
+        serving path reads: tables are empty and inactive.
+
+        Then every bucket of every flush scatter (``_bucket``): the
+        guard reads a compile inside its watchdog window as a hang, and
+        on the chip the first 100K-agent flush was one. The results are
+        thrown away, so no table changes. The sim columns share the
+        entity tables' shapes and so their programs; the spots table
+        does not exist before the first spots query, which recompiles
+        the step anyway."""
+        for _ in range(2):
+            # Twice: the second tick takes the first one's outputs as
+            # its inputs, as every serving tick does. Under a mesh their
+            # shardings differ from the freshly made arrays' and the
+            # query diff compiles again.
+            jax.block_until_ready(self.tick(now_ms=0))
         self.last_result = None
+        t0 = time.monotonic()
+        tables = [
+            (self.entity_capacity,
+             (self._d_positions, self._d_valid, self._d_cell)),
+            # last/interval share one i32 program.
+            (self.sub_capacity, self._d_sub_state[::2]),
+        ]
+        for capacity, arrays in tables:
+            for k in _buckets(capacity):
+                idx = np.zeros(k, np.int32)
+                for arr in arrays:
+                    _set_rows(arr, idx,
+                              np.zeros((k,) + arr.shape[1:], arr.dtype))
+        if self._d_q_prev is not None:
+            for k in _buckets(self.query_capacity):
+                idx = np.zeros(k, np.int32)
+                _set_rows(self._d_q_prev[0], idx, np.bool_(False))
+                _set_rows(self._d_q_prev[1], idx, np.int32(0))
+        logger.info("flush scatter buckets warmed in %.1fs",
+                    time.monotonic() - t0)
 
     def sim_warmup(self) -> None:
         """Compile the sim step at plane activation, for the same reason
@@ -761,7 +846,7 @@ class SpatialEngine:
         sim_committed = None
         census_due = False
         positions = self._d_positions
-        if (self.sim_enabled and self.run_sim_pass and self._mesh is None
+        if (self.sim_enabled and self.run_sim_pass
                 and self._d_vel is not None):
             flee = self._d_flee
             if flee is None:
@@ -805,11 +890,9 @@ class SpatialEngine:
             elif self._q_prev_reset_rows:
                 # Reused rows start from an empty baseline (pure compute
                 # on the old arrays; committed only after the gen check).
-                idx = np.fromiter(
-                    self._q_prev_reset_rows, np.int32,
-                    len(self._q_prev_reset_rows),
-                )
-                prev = (prev[0].at[idx].set(False), prev[1].at[idx].set(0))
+                idx = _bucket(self._q_prev_reset_rows)
+                prev = (_set_rows(prev[0], idx, np.bool_(False)),
+                        _set_rows(prev[1], idx, np.int32(0)))
             q_blob, q_prev_i, q_prev_d = diff_query_masks(
                 prev[0], prev[1], out["interest"], out["dist"],
                 self.query_rows_max,
@@ -957,8 +1040,8 @@ class SpatialEngine:
 
         The per-connection form above pulls one row per call — one
         device round-trip per AOI follower per tick, measured at
-        ~330us/follower (BENCH_RESULTS.md round 10, ROADMAP item 1):
-        past ~100 followers that alone blew the 33ms GLOBAL tick. The
+        ~330us/follower on a CPU host (PR 10's own run): past ~100
+        followers that alone blew the 33ms GLOBAL tick. The
         masks already live in two device arrays, so the follower pass
         fetches them once and slices rows on host — O(1) transfers per
         tick regardless of follower count."""

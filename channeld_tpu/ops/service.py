@@ -304,7 +304,7 @@ class SpatialDecisionServicer:
         )
         # Delta interest: AOI masks are a pure function of query geometry,
         # so only changed queries need recomputation/transfer — step cost
-        # is flat in the standing query population (VERDICT r1 weak #4).
+        # is flat in the standing query population.
         if request.fullInterest:
             report_conns = list(eng._q_of_conn.keys())
         else:
@@ -481,6 +481,9 @@ def main() -> None:
     p.add_argument("--auth-token", type=str, default=None,
                    help="shared secret; defaults to $CHTPU_SIDECAR_TOKEN")
     args = p.parse_args()
+    from ..utils.devices import place_compile_cache
+
+    place_compile_cache()
     server, _, bound = create_server(args.port, auth_token=args.auth_token)
     server.start()
     logger.info("spatial decision sidecar listening on :%d", bound)

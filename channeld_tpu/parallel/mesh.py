@@ -21,7 +21,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ._jax_compat import axis_size as _axis_size, shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.spatial_ops import (
@@ -125,7 +125,7 @@ def build_sharded_step(grid: GridSpec, mesh: Mesh, max_handovers_per_shard: int,
         # Local slot indices -> global entity slots (row-major shard order).
         shard_index = jnp.int32(0)
         for axis in axes:
-            shard_index = shard_index * _axis_size(axis) + jax.lax.axis_index(axis)
+            shard_index = shard_index * jax.lax.axis_size(axis) + jax.lax.axis_index(axis)
         shard_size = positions.shape[0]
         offset = (shard_index * shard_size).astype(jnp.int32)
         ho_rows = ho_rows.at[:, 0].set(

@@ -33,7 +33,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from ._jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.spatial_ops import (

@@ -123,6 +123,14 @@ class TPUSpatialController(StaticGrid2DSpatialController):
         )
         if mesh is not None:
             logger.info("spatial engine meshed over %s", mesh)
+            if global_settings.sim_enabled:
+                # The sim kernel is single-device (doc/simulation.md): a
+                # population asked for and never stepped must stop the
+                # boot, not pass for a quiet world.
+                raise ValueError(
+                    "-sim true cannot run on a meshed engine "
+                    f"(mesh {dict(mesh.shape)}): drop -sim or the mesh"
+                )
 
         # Sharding selection: Config {"Sharding": "cells"} serves from the
         # space-partitioned plane (all_to_all redistribution + column-block
@@ -152,13 +160,12 @@ class TPUSpatialController(StaticGrid2DSpatialController):
             # on-device diff/compaction step.
             self.queryplane = QueryPlane(self, self.engine)
         self.engine.warmup()  # compile before listeners open (see warmup)
-        if global_settings.sim_enabled and mesh is None:
+        if global_settings.sim_enabled:
             # On-device world simulation (channeld_tpu/sim;
             # doc/simulation.md): spawn/restore the agent population and
             # pre-compile the sim kernel — after warmup so the spatial
             # step's compile cost is already paid, still before
-            # listeners open. The sim kernel is single-device; a meshed
-            # engine skips the plane (documented in doc/simulation.md).
+            # listeners open.
             from ..sim.plane import SimPlane
 
             self.simplane = SimPlane(self, self.engine)
@@ -888,9 +895,9 @@ class TPUSpatialController(StaticGrid2DSpatialController):
                 self._apply_follow_interests(result)
                 cost = _time.monotonic() - t_fi
                 _trace.stage("follow_interests", int(t_fi * 1e9))
-                # The previously-unmeasured host cost inside the GLOBAL
-                # tick budget (VERDICT weak #5): now a first-class
-                # histogram and a pressure-signal input.
+                # The follower pass's host cost inside the GLOBAL tick
+                # budget: a first-class histogram and a pressure-signal
+                # input.
                 metrics.follower_interest_ms.observe(cost * 1000.0)
                 _governor.note_follower_cost(cost)
 

@@ -44,9 +44,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 if os.environ.get("CHTPU_SOAK_TPU") != "1":
-    from channeld_tpu.utils.devices import pin_cpu_if_virtual_devices
-
-    pin_cpu_if_virtual_devices()
+    os.environ["JAX_PLATFORMS"] = "cpu"  # gateway children inherit it
 
 import argparse
 import asyncio

@@ -1,7 +1,7 @@
-"""Host handover-orchestration bench (VERDICT r4 task 4).
+"""Host handover-orchestration bench.
 
-The device detects ~1,469 crossings per 33ms tick at the flagship load
-(BENCH_r04: handovers_per_step). This measures whether the HOST side —
+bench.py's step detects ~1,469 crossings per 33ms tick at its 100K load
+(handovers_per_step in its output). This measures whether the HOST side —
 owner swap, channel-data remove/add, handover fan-out
 (ref: spatial.go:612-858) — keeps up with that detection rate, and by
 how much, for both the per-crossing path (reference shape) and the

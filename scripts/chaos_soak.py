@@ -52,16 +52,14 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 # 8 virtual CPU devices for the cells-sharded plane (before jax loads);
-# CHTPU_SOAK_TPU=1 skips the pin to soak against a real chip.
+# CHTPU_SOAK_TPU=1 leaves the platform to JAX to soak against a real chip.
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 if os.environ.get("CHTPU_SOAK_TPU") != "1":
-    from channeld_tpu.utils.devices import pin_cpu_if_virtual_devices
-
-    pin_cpu_if_virtual_devices()
+    os.environ["JAX_PLATFORMS"] = "cpu"  # gateway children inherit it
 
 import argparse
 import asyncio

@@ -45,9 +45,7 @@ SCHEMAS: dict[str, set] = {
     "SOAK_FED_*.json": _SOAK_KEYS | {
         "census", "gateway_a", "gateway_b", "redirect", "timeline",
     },
-    # Bench artifacts predate the kind tag; pin the keys their
-    # BENCH_RESULTS.md / README claims actually cite.
-    "BENCH_r*.json": {"cmd", "rc", "parsed"},
+    # Bench artifacts predate the kind tag; pin the keys the docs cite.
     "BENCH_GATEWAY_*.json": {"headline", "runs", "metric"},
     "BENCH_HANDOVER_*.json": {"metric", "crossings_per_tick",
                               "keeps_up_with_detection"},

@@ -56,16 +56,14 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
-# Same device pinning as chaos_soak (must precede any jax import).
+# Same platform choice as chaos_soak (must precede any jax import).
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 if os.environ.get("CHTPU_SOAK_TPU") != "1":
-    from channeld_tpu.utils.devices import pin_cpu_if_virtual_devices
-
-    pin_cpu_if_virtual_devices()
+    os.environ["JAX_PLATFORMS"] = "cpu"  # gateway children inherit it
 
 import argparse
 import asyncio

@@ -72,9 +72,7 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 if os.environ.get("CHTPU_SOAK_TPU") != "1":
-    from channeld_tpu.utils.devices import pin_cpu_if_virtual_devices
-
-    pin_cpu_if_virtual_devices()
+    os.environ["JAX_PLATFORMS"] = "cpu"  # gateway children inherit it
 
 import argparse
 import asyncio

@@ -95,13 +95,10 @@ def config_3_tps(duration, clients=2000):
 def _device_decision_bench(n_entities, steps, handover_heavy=False):
     import numpy as np
 
-    from bench import _preflight_backend
+    from bench import _require_tpu
 
-    backend = _preflight_backend()
+    _require_tpu()
     import jax
-
-    if backend == "cpu-fallback":
-        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
 
     from channeld_tpu.ops.spatial_ops import GridSpec, QuerySet, spatial_step
@@ -174,8 +171,6 @@ def _device_decision_bench(n_entities, steps, handover_heavy=False):
         "handovers_per_step": round(handovers / steps, 1),
         "hz_target_met": steps / dt >= 30,
     }
-    if backend == "cpu-fallback":
-        row["backend"] = backend
     return row
 
 

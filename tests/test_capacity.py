@@ -1,6 +1,6 @@
 """Capacity-overflow policy: a full device table degrades to the host
 path with a metric + security-log line — never an exception inside the
-channel tick (VERDICT r2 weak #5). The reference has no device tables;
+channel tick. The reference has no device tables;
 its analog is that a full world simply keeps running the per-entity host
 loops (spatial.go:612-858), which is exactly the degraded mode here."""
 

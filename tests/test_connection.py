@@ -325,7 +325,7 @@ def test_full_queue_stashes_instead_of_dropping():
     re-dispatches everything in order once the tick drains the queue —
     the asyncio analog of the reference's blocking inMsgQueue send
     (channel.go:295-310). Before this contract, a 40K mps overload
-    dropped >1M messages (BENCH_RESULTS round-3).
+    dropped >1M messages (round 3's own run).
 
     Pinned to the per-message (protobuf) path: the batched native ingest
     coalesces user-space reads into one queue item, so filling the queue

@@ -249,7 +249,7 @@ def test_sub_window_survives_table_churn():
 
 
 def test_follow_interest_reaped_when_entity_destroyed():
-    """(VERDICT r1 weak #7): a follower whose entity was untracked must
+    """A follower whose entity was untracked must
     not keep a stale interest center forever — the follow is dropped and
     the spatial subscriptions cleared."""
 

@@ -179,3 +179,25 @@ def test_debug_get_spatial_regions_dev_mode_only():
     # (ref: spatial.go:319-356 GetRegions).
     assert len(regions) >= 1
     assert {r.serverIndex for r in regions} == {0}
+
+
+def test_introspect_names_the_device_the_engine_holds(runtime):
+    """A gateway on the chip must be tellable from one that fell back to
+    the CPU: /introspect carries what the engine logged at construction."""
+    from channeld_tpu.core.opshttp import introspect
+    from channeld_tpu.spatial.controller import set_spatial_controller
+    from channeld_tpu.spatial.tpu_controller import TPUSpatialController
+
+    assert introspect()["engine"] is None  # no device controller yet
+    ctl = TPUSpatialController()
+    ctl.load_config(dict(
+        WorldOffsetX=0, WorldOffsetZ=0, GridWidth=100, GridHeight=100,
+        GridCols=2, GridRows=2, ServerCols=1, ServerRows=1,
+    ))
+    set_spatial_controller(ctl)
+    engine = introspect()["engine"]
+    assert engine == ctl.engine.device_info
+    assert engine["platform"] == "cpu" and engine["device_count"] >= 1
+    assert engine["mesh"] is None and engine["use_pallas"] is False
+    assert set(engine) == {"platform", "device_kind", "device_count", "mesh",
+                           "use_pallas", "native_codec"}

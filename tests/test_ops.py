@@ -439,11 +439,42 @@ def _drive_engine(eng: SpatialEngine, rng: np.random.Generator) -> list[dict]:
     return results
 
 
+def test_flush_scatters_compile_nothing_after_warmup():
+    """Every dirty count lands in a bucket ``warmup`` compiled: no flush
+    scatter compiles inside the guarded tick, where the watchdog reads a
+    compile as a hang."""
+    from channeld_tpu.ops import engine as engine_mod
+
+    assert [len(engine_mod._bucket(range(k)))
+            for k in (1, 2, 3, 5, 8, 9)] == [1, 2, 4, 8, 8, 16]
+    assert engine_mod._bucket([7, 3, 5]).tolist() == [7, 3, 5, 5]
+    assert engine_mod._buckets(48) == [1, 2, 4, 8, 16, 32, 64]
+
+    eng = SpatialEngine(GRID, entity_capacity=48, query_capacity=24,
+                        sub_capacity=64, max_handovers=16)
+    eng.track_query_changes = True
+    eng.warmup()
+    compiled = engine_mod._set_rows._cache_size()
+    rng = np.random.default_rng(7)
+    subs: list[int] = []
+    for now, n_dirty in enumerate((1, 3, 5, 11, 23, 48), start=1):
+        for s in subs:
+            eng.remove_subscription(s)
+        subs = [eng.add_subscription(interval_ms=50) for _ in range(n_dirty)]
+        for eid in range(n_dirty):
+            eng.update_entity(100 + eid, *rng.uniform(-140, 140, 3))
+        eng.set_query(500 + now, 1, (0.0, 0.0), (100.0, 0.0))
+        if now > 2:
+            eng.remove_query(500 + now - 2)  # a freed row resets its baseline
+        eng.tick(now_ms=now * 33)
+    assert engine_mod._set_rows._cache_size() == compiled
+
+
 def test_engine_mesh_matches_single_device():
     """The serving engine produces identical gateway-visible decisions with
     the entity arrays sharded over an 8-device mesh vs one device — the
     guarantee that lets TPUSpatialController/the sidecar scale onto a
-    slice without behavior drift (VERDICT r1 #2)."""
+    slice without behavior drift."""
     from channeld_tpu.parallel.mesh import make_mesh, make_mesh_2d
 
     for mesh, sharding in ((make_mesh(), "entities"),

@@ -425,7 +425,7 @@ def _move(entity_ch, eid, ctl, x):
 
 
 def test_handover_shares_one_encode_across_recipients():
-    """Satellite (VERDICT weak #1): the per-recipient handover sends are
+    """The per-recipient handover sends are
     batched — src-only observers share one pre-encoded context, and dst
     conns with unchanged subscriptions share one payload."""
     ctl, server_a, server_b = _spatial_world()
@@ -577,7 +577,7 @@ def test_deferred_crossing_chain_settles_correctly():
     assert ctl._deferred_crossings == {}
 
 
-# ---- follower-interest instrumentation (satellite, VERDICT weak #5) -------
+# ---- follower-interest instrumentation -----------------------------------
 
 
 def test_follower_interest_cost_histogram():

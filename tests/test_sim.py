@@ -210,6 +210,26 @@ def test_meshed_engine_refuses_agents():
         eng.seed_agents([(AGENT_BASE, 10.0, 0.0, 10.0)], 1, fast_params())
 
 
+def test_gateway_refuses_sim_on_a_meshed_engine():
+    """``-sim true`` with a mesh stops the boot: a population asked for
+    and silently never stepped would pass for a quiet world."""
+    from channeld_tpu.core.settings import global_settings
+    from channeld_tpu.parallel.mesh import mesh_from_config
+    from channeld_tpu.spatial.tpu_controller import TPUSpatialController
+
+    if mesh_from_config(8, 1) is None:
+        pytest.skip("no virtual device mesh")
+    global_settings.sim_enabled = True
+    ctl = TPUSpatialController()
+    with pytest.raises(ValueError, match="-sim true cannot run on a meshed"):
+        ctl.load_config(dict(
+            WorldOffsetX=0, WorldOffsetZ=0, GridWidth=25, GridHeight=100,
+            GridCols=4, GridRows=1, ServerCols=1, ServerRows=1,
+            MeshDevices=8,
+        ))
+    assert ctl.engine is None and ctl.simplane is None
+
+
 # ---------------------------------------------------------------------------
 # rebuild: bit-identical + the generation fence (torn-batch regression)
 # ---------------------------------------------------------------------------

@@ -49,3 +49,52 @@ def test_hash_string_stable():
 
 def test_difference():
     assert difference([1, 2, 3, 4], [2, 4, 5]) == [1, 3]
+
+
+# ---- utils/devices.py: where the compile cache lives ------------------------
+
+
+def test_compile_cache_sets_no_directory_when_the_variable_is_set(monkeypatch):
+    import os
+    import sys
+    import types
+
+    from channeld_tpu.utils.devices import place_compile_cache
+
+    updates = []
+    fake_jax = types.SimpleNamespace(config=types.SimpleNamespace(
+        update=lambda name, value: updates.append(name)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
+    # (restored at teardown: the not-yet-imported path writes it)
+    monkeypatch.setenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "1")
+    for loaded in (fake_jax, None):  # jax imported already / not yet
+        monkeypatch.setitem(sys.modules, "jax", loaded)
+        place_compile_cache()
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == "/placed/from/outside"
+    assert updates == ["jax_persistent_cache_min_compile_time_secs"]
+
+
+def test_compile_cache_default_is_the_checkout_from_any_directory(tmp_path):
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = (
+        "import os; from channeld_tpu.utils.devices import "
+        "place_compile_cache; place_compile_cache(); "
+        "print(os.environ['JAX_COMPILATION_CACHE_DIR'], "
+        "os.environ['JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS'])"
+    )
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = repo
+    seen = set()
+    for name in ("a", "b"):
+        cwd = tmp_path / name
+        cwd.mkdir()
+        seen.add(subprocess.run(
+            [sys.executable, "-c", code], cwd=cwd, env=env,
+            capture_output=True, text=True, check=True,
+        ).stdout.strip())
+    assert seen == {os.path.join(repo, ".jax_cache") + " 0"}

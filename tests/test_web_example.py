@@ -1,5 +1,5 @@
 """Pin the browser chat example's hand-rolled wire code to the protocol
-(VERDICT r1 weak #8: examples/web was in the parity table with nothing
+(examples/web was in the parity table with nothing
 automated). The JS cannot execute under pytest, so the pin is structural:
 the constants and field numbers the page hand-encodes must match the
 real schema — that is exactly what drifts when the protocol evolves."""
