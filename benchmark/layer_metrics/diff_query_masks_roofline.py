@@ -1,0 +1,6 @@
+"""``diff_query_masks``'s share of its roofline in the traced window."""
+from benchmark.harness.driver import roofline_pct
+
+
+def read(ctx):
+    return roofline_pct(ctx, "diff_query_masks")
