@@ -1,0 +1,7 @@
+"""Share of the traced window in which the device was idle while the
+gateway's loop thread was inside ``channeld/tick.GLOBAL``."""
+from benchmark.harness.host_spans import idle_pct
+
+
+def read(ctx):
+    return idle_pct(ctx, "global_tick_s")

@@ -31,7 +31,8 @@ from ..astutil import dotted, iter_functions, metrics_aliases
 from ..engine import Finding, ModuleInfo, RepoContext, Rule
 
 METRICS_REL = "channeld_tpu/core/metrics.py"
-_METRIC_CTORS = {"Counter", "Gauge", "Histogram", "Summary"}
+_METRIC_CTORS = {"Counter", "Gauge", "Histogram", "Summary",
+                 "SumCount"}  # SumCount: core/metrics.py's batched Summary
 _BUMP_METHODS = {"inc", "dec", "set", "observe"}
 
 

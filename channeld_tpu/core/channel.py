@@ -25,7 +25,7 @@ from ..protocol import control_pb2
 from ..utils.idalloc import IdAllocator
 from ..utils.logger import get_logger
 from . import events, metrics
-from .data import ChannelData, FanOutConnection, tick_data
+from .data import ChannelData, FanOutConnection, tick_data, window_lag_ns
 from .data import (
     reflect_channel_data_message,
     _channel_data_extension_registry,
@@ -55,6 +55,28 @@ _drain_event: Optional[asyncio.Event] = None
 QUEUE_CAPACITY = 4096
 _HIGH_WATERMARK = QUEUE_CAPACITY * 3 // 4
 _LOW_WATERMARK = QUEUE_CAPACITY // 4
+
+
+# How long work waited for the event loop: [seconds late, ticks] by
+# channel type. Every tick loop adds into its type's pair (a subtraction
+# and two adds a tick); the GLOBAL tick carries the pairs, and
+# core/data.py's fan-out window lag, to /metrics.
+_tick_late: dict = {t: [0.0, 0] for t in ChannelType}
+
+
+def _flush_wait_counters() -> None:
+    """``tick_late_ms`` and ``fanout_window_lag_ms``, once per GLOBAL
+    tick: a registry call for each of ~7,000 channel ticks a second would
+    sit on the thread that is the bottleneck."""
+    for pairs, metric, to_ms in (
+        (_tick_late, metrics.tick_late_ms, 1e3),  # kept in seconds
+        (window_lag_ns, metrics.fanout_window_lag_ms, 1e-6),
+    ):
+        for ctype, acc in pairs.items():
+            if acc[1]:
+                metric.labels(channel_type=ctype.name).add(
+                    acc[0] * to_ms, acc[1])
+                acc[0], acc[1] = 0, 0
 
 
 def is_congested() -> bool:
@@ -410,16 +432,32 @@ class Channel:
                 return False
         return True
 
+    def _note_tick_start(self, tick_start: float,
+                         due: Optional[float]) -> None:
+        """Lateness of the tick that starts now against ``due``, the
+        start of the one before it plus the interval (``tick_late_ms``).
+        The first tick and one that follows a park have nothing to be
+        late against (``due`` None)."""
+        if due is not None:
+            late = _tick_late[self.channel_type]
+            if tick_start > due:
+                late[0] += tick_start - due
+            late[1] += 1
+
     async def _tick_loop(self) -> None:
+        due = None  # when this tick was due (loop clock); None after a park
         while not self.is_removing():
             tick_start = time.monotonic()
+            self._note_tick_start(tick_start, due)
             # tick_once observes the duration histogram and feeds the
             # overload governor's budget accounting.
             self.tick_once(self.get_time(), tick_start)
             elapsed = time.monotonic() - tick_start
             if not self._may_park():
+                due = tick_start + self.tick_interval
                 await asyncio.sleep(max(self.tick_interval - elapsed, 0))
             else:
+                due = None
                 # Idle channel: park until a message/subscription arrives
                 # (or a coarse heartbeat) instead of spinning at the tick
                 # cadence — 10K mostly-idle channels would otherwise wake
@@ -459,16 +497,6 @@ class Channel:
             now = self.get_time()
         if tick_start is None:
             tick_start = time.monotonic()
-
-        # Spatial controller ticks with the GLOBAL channel only, to keep a
-        # single writer (ref: channel.go:366-369).
-        if self.channel_type == ChannelType.GLOBAL:
-            from ..spatial.controller import get_spatial_controller
-
-            controller = get_spatial_controller()
-            if controller is not None:
-                controller.tick()
-
         self.tick_frames += 1
         if self.channel_type == ChannelType.GLOBAL:
             # The GLOBAL tick is the authoritative loop-thread anchor:
@@ -477,10 +505,57 @@ class Channel:
             # (doc/concurrency.md; disarmed = one attribute load).
             _affinity.enter("tick-loop")
             # The GLOBAL tick is the recorder's clock: every span this
-            # tick (any channel, any stage) is stamped with this number,
-            # which is what lets a dump say "tick 8041 spent 9.3ms in
-            # fan-out" instead of showing an anonymous timeline.
+            # tick (any channel, any stage, the controller's device step
+            # first) is stamped with this number, which is what lets a
+            # dump say "tick 8041 spent 9.3ms in fan-out" instead of
+            # showing an anonymous timeline. It is also where the
+            # recorder learns of a profiler session, before this tick's
+            # own region opens.
             _trace.set_tick(self.tick_frames)
+            _flush_wait_counters()
+        # The tick span closes after the governor update, so the overload
+        # stage nests inside it (containment is how dumps reconstruct
+        # nesting). The three sites that run every channel tick (this
+        # one, messages, fanout) record after the fact, as they always
+        # did, and are regions only while a profiler session is live:
+        # then the loop thread's line in the trace says whose tick, and
+        # which stage of it, the host was in. Off, that costs each one
+        # attribute load; region objects kept on the channel cost the
+        # loop ~0.8 us a tick on the chip's host (PERF.md, PR 25).
+        profiling = _trace.profiling
+        if profiling:
+            with _trace.region(f"tick.{self.channel_type.name}",
+                               lane=self.id):
+                self._tick_stages(now, tick_start, profiling)
+        else:
+            self._tick_stages(now, tick_start, profiling)
+            if _trace.enabled:
+                _trace.span(
+                    f"tick.{self.channel_type.name}",
+                    int(tick_start * 1e9), lane=self.id,
+                )
+        if _trace.enabled and self.tick_interval > 0:
+            total = time.monotonic() - tick_start
+            if total > self.tick_interval:
+                # A blown tick budget freezes the ring: the dump holds
+                # the very stages that ate it (cooldown-bounded).
+                _trace.note_anomaly(
+                    "tick_budget",
+                    f"{self.channel_type.name} {self.id}: "
+                    f"{total * 1e3:.2f}ms > "
+                    f"{self.tick_interval * 1e3:.0f}ms",
+                )
+
+    def _tick_stages(self, now: int, tick_start: float,
+                     profiling: bool) -> None:
+        # Spatial controller ticks with the GLOBAL channel only, to keep a
+        # single writer (ref: channel.go:366-369).
+        if self.channel_type == ChannelType.GLOBAL:
+            from ..spatial.controller import get_spatial_controller
+
+            controller = get_spatial_controller()
+            if controller is not None:
+                controller.tick()
         # Deferred ingest runs land in the queue before it drains, so a
         # tick never misses traffic the per-read dispatch would have
         # delivered (also what keeps on_bytes + tick_once tests exact).
@@ -488,11 +563,14 @@ class Channel:
         if _connection_mod is None:
             from . import connection as _connection_mod
         _connection_mod.flush_pending_ingest()
-        msg_start = time.monotonic_ns()
-        had_msgs = bool(self.in_msg_queue)
-        self._tick_messages(tick_start)
-        if had_msgs:
-            _trace.stage("messages", msg_start, lane=self.id)
+        if self.in_msg_queue:
+            if profiling:
+                with _trace.region("messages", lane=self.id, stage=True):
+                    self._tick_messages(tick_start)
+            else:
+                msg_start = time.monotonic_ns()
+                self._tick_messages(tick_start)
+                _trace.stage("messages", msg_start, lane=self.id)
             # WAL dirty mark (doc/persistence.md): every channel-data
             # mutation runs through this queue (update merges AND
             # execute closures), so a post-drain mark captures exactly
@@ -501,9 +579,20 @@ class Channel:
             # channel_state records.
             if _wal.enabled and self.data is not None:
                 _wal.note_dirty(self.id)
-        fanout_start = time.monotonic()
-        tick_data(self, now)
-        if self.subscribed_connections:
+        else:
+            self._tick_messages(tick_start)  # still lifts backpressure
+        if not self.subscribed_connections:
+            tick_data(self, now)
+        elif profiling:
+            with _trace.region("fanout", lane=self.id, stage=True):
+                fanout_start = time.monotonic()
+                tick_data(self, now)
+                metrics.fanout_decision_latency.labels(
+                    backend="host"
+                ).observe(time.monotonic() - fanout_start)
+        else:
+            fanout_start = time.monotonic()
+            tick_data(self, now)
             metrics.fanout_decision_latency.labels(backend="host").observe(
                 time.monotonic() - fanout_start
             )
@@ -530,9 +619,8 @@ class Channel:
             if owner is not None:
                 _governor.note_server_cost(owner.id, elapsed)
         if self.channel_type == ChannelType.GLOBAL:
-            gov_start = time.monotonic_ns()
-            _governor.update(self.tick_interval)
-            _trace.stage("overload", gov_start, lane=self.id)
+            with _trace.region("overload", lane=self.id, stage=True):
+                _governor.update(self.tick_interval)
             if _slo.enabled:
                 # Burn-rate evaluation + the round-robin staleness
                 # sample, inside the GLOBAL tick's single-writer
@@ -544,25 +632,6 @@ class Channel:
                 # replica packs cell state in. Enqueue-only: the fsync
                 # lives on the WAL's writer thread.
                 _wal.on_global_tick()
-        if _trace.enabled:
-            # The tick span closes HERE (after the governor update) so
-            # the overload stage nests inside it — containment is how
-            # dumps reconstruct nesting; `elapsed` keeps its historical
-            # pre-governor window for the histogram/governor intake.
-            total = time.monotonic() - tick_start
-            _trace.span(
-                f"tick.{self.channel_type.name}",
-                int(tick_start * 1e9), lane=self.id,
-            )
-            if self.tick_interval > 0 and total > self.tick_interval:
-                # A blown tick budget freezes the ring: the dump holds
-                # the very stages that ate it (cooldown-bounded).
-                _trace.note_anomaly(
-                    "tick_budget",
-                    f"{self.channel_type.name} {self.id}: "
-                    f"{total * 1e3:.2f}ms > "
-                    f"{self.tick_interval * 1e3:.0f}ms",
-                )
 
     def _tick_messages(self, tick_start: float) -> None:
         """Drain the queue within the tick budget (ref: channel.go:389-412).
@@ -916,3 +985,5 @@ def reset_channels() -> None:
             ch._tick_task.cancel()
     _all_channels.clear()
     _global_channel = None
+    for acc in (*_tick_late.values(), *window_lag_ns.values()):
+        acc[0], acc[1] = 0, 0

@@ -236,6 +236,13 @@ class Connection:
             # earned its structured disconnect, and parsing its bytes
             # would keep paying for an abuser (doc/edge_hardening.md).
             return
+        # What one read costs inline: frame decode, parse and enqueue
+        # (or the hand-over to the deferred run, whose dispatch is the
+        # ``ingest`` stage of flush_pending_ingest).
+        with _trace.region("ingest_inline", stage=True):
+            self._ingest(data)
+
+    def _ingest(self, data: bytes) -> None:
         try:
             bodies = self.decoder.feed(data)
         except Exception as e:  # framing violations are connection-fatal

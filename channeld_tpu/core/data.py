@@ -306,6 +306,13 @@ def _device_due_view(channel: "Channel"):
     return (ctl,) + due
 
 
+# How far behind its window a subscription is when it is served:
+# [ns behind, services] by channel type, added to by tick_data and
+# carried to ``fanout_window_lag_ms`` once per GLOBAL tick
+# (core/channel.py ``_flush_wait_counters``).
+window_lag_ns: dict = {t: [0, 0] for t in ChannelType}
+
+
 def tick_data(channel: "Channel", now: int) -> None:
     """The per-tick fan-out decision + send loop (ref: data.go:175-291).
 
@@ -343,6 +350,7 @@ def tick_data(channel: "Channel", now: int) -> None:
     stretch = _governor.fanout_stretch() if _governor.level else 1.0
     shed_floor = _governor.shed_priority_floor() if _governor.level else None
 
+    lag_ns = served_late = 0  # this tick's share of window_lag_ns
     queue = channel.fan_out_queue
     device = _device_due_view(channel)
     if device is not None:
@@ -381,7 +389,7 @@ def tick_data(channel: "Channel", now: int) -> None:
         interval_ns = cs.options.fanOutIntervalMs * NS_PER_MS
         if stretch != 1.0:
             interval_ns = int(interval_ns * stretch)
-        next_fanout_time = foc.last_fanout_time + interval_ns
+        next_fanout_time = window_due = foc.last_fanout_time + interval_ns
         if device is None or foc.device_sub_slot is None:
             # Host time check (no engine, or no device slot for this sub).
             if now < next_fanout_time:
@@ -414,6 +422,14 @@ def tick_data(channel: "Channel", now: int) -> None:
 
         latest_fanout_time = next_fanout_time
 
+        if foc.had_first_fanout:
+            # Served now, ``now - window_due`` after its window closed:
+            # the window moves on one interval a service, so a
+            # subscription served less often than its interval carries
+            # this lag forward and grows it.
+            if now > window_due:
+                lag_ns += now - window_due
+            served_late += 1
         if not foc.had_first_fanout:
             # First fan-out carries the full channel state.
             fan_out_data_update(channel, conn, cs, data.msg, body_cache)
@@ -504,6 +520,10 @@ def tick_data(channel: "Channel", now: int) -> None:
 
         foc.last_fanout_time = latest_fanout_time
 
+    if served_late:
+        lag = window_lag_ns[channel.channel_type]
+        lag[0] += lag_ns
+        lag[1] += served_late
     # Keep the queue ordered by last_fanout_time (the reference maintains
     # this invariant with in-place move-to-back; a stable sort is the same
     # end state). Device mode doesn't iterate the queue, so its order is

@@ -68,6 +68,7 @@ from ..chaos.injector import chaos as _chaos
 from ..utils.logger import get_logger
 from .affinity import affinity as _affinity
 from .settings import global_settings
+from .tracing import recorder as _trace
 
 logger = get_logger("device_guard")
 
@@ -322,16 +323,20 @@ class DeviceGuard:
         # guarded window (a hung transfer is a hang, not a mystery
         # stall in the controller) and handed on as numpy so the
         # controller's handover_list/_publish_due add no new transfers.
-        result["handovers"] = np.asarray(result["handovers"])  # tpulint: disable=hot-readback -- THE designed once-per-tick batched fetch; downstream reuses these arrays
-        result["handover_count"] = int(result["handover_count"])  # tpulint: disable=hot-readback -- rides the same designed per-tick fetch as the rows above
-        result["due_packed"] = np.asarray(result["due_packed"])  # tpulint: disable=hot-readback -- rides the same designed per-tick fetch as the rows above
-        if result.get("query_blob") is not None:
-            result["query_blob"] = np.asarray(result["query_blob"])  # tpulint: disable=hot-readback -- the standing-query plane's ONE changed-rows transfer, pre-fetched inside the guarded window (doc/query_engine.md)
+        # The first of them blocks until the passes have run, so
+        # ``step.fetch`` is the wait for the chip plus the transfer.
+        with _trace.region("step.fetch", stage=True):
+            result["handovers"] = np.asarray(result["handovers"])  # tpulint: disable=hot-readback -- THE designed once-per-tick batched fetch; downstream reuses these arrays
+            result["handover_count"] = int(result["handover_count"])  # tpulint: disable=hot-readback -- rides the same designed per-tick fetch as the rows above
+            result["due_packed"] = np.asarray(result["due_packed"])  # tpulint: disable=hot-readback -- rides the same designed per-tick fetch as the rows above
+            if result.get("query_blob") is not None:
+                result["query_blob"] = np.asarray(result["query_blob"])  # tpulint: disable=hot-readback -- the standing-query plane's ONE changed-rows transfer, pre-fetched inside the guarded window (doc/query_engine.md)
         if result.get("sim_census") is not None:
-            result["sim_census"] = tuple(
-                np.asarray(a)  # tpulint: disable=hot-readback -- the sim plane's census-cadence batched fetch (its ONLY readback, doc/simulation.md), pre-fetched inside the guarded window; NOT per-tick
-                for a in result["sim_census"]
-            )
+            with _trace.region("step.census_fetch", stage=True):
+                result["sim_census"] = tuple(
+                    np.asarray(a)  # tpulint: disable=hot-readback -- the sim plane's census-cadence batched fetch (its ONLY readback, doc/simulation.md), pre-fetched inside the guarded window; NOT per-tick
+                    for a in result["sim_census"]
+                )
         return result
 
     # ---- corruption sentinel ---------------------------------------------
@@ -407,8 +412,6 @@ class DeviceGuard:
         self._retry_count = 0
         self._set_state(DeviceState.REBUILDING)
         self._pin_ladder()
-        from .tracing import recorder as _trace
-
         if _trace.enabled:
             # Freeze the timeline at the failure tick: the dump holds
             # the stages that led into the fault.

@@ -13,10 +13,24 @@ from prometheus_client import (
     Counter,
     Gauge,
     Histogram,
+    Summary,
     start_http_server,
 )
 
 registry = CollectorRegistry()
+
+
+class SumCount(Summary):
+    """A ``_sum`` and a ``_count`` that take a whole batch at once: the
+    hot path adds into plain per-type accumulators and the GLOBAL tick
+    carries them here (core/channel.py ``_flush_wait_counters``), so a
+    wait measured ~7,000 times a second costs the loop thread no
+    registry call of its own."""
+
+    def add(self, amount: float, count: int) -> None:
+        self._count.inc(count)
+        self._sum.inc(amount)
+
 
 msg_received = Counter(
     "messages_in", "Messages received", ["conn_type", "channel_type", "msg_type"],
@@ -69,7 +83,7 @@ log_events = Counter("logs", "Warn+ log records", ["level"], registry=registry)
 tpu_step_latency = Histogram(
     "tpu_spatial_step_seconds",
     "Device AOI/fan-out step latency incl. transfers",
-    buckets=(0.0005, 0.001, 0.002, 0.005, 0.01, 0.02, 0.033, 0.1),
+    buckets=(0.0005, 0.001, 0.002, 0.005, 0.01, 0.02, 0.033, 0.1, 0.2, 0.5),
     registry=registry,
 )
 tpu_entities = Gauge("tpu_entities", "Entities resident on device", registry=registry)
@@ -682,16 +696,42 @@ slo_breaches = Counter(
 tick_stage_ms = Histogram(
     "tick_stage_ms",
     "Host cost of one named per-tick stage, milliseconds (ingest: "
-    "deferred-read drain; stash_retry: backpressure re-dispatch; "
+    "deferred-read drain; ingest_inline: one socket read's decode and "
+    "enqueue; stash_retry: backpressure re-dispatch; "
     "messages: channel queue drain incl. FSM dispatch; fanout: "
     "ChannelData fan-out encode/send; device_step: batched engine "
-    "dispatch+step; readback: device->host interest-mask transfers; "
-    "follow_interests: the full follower pass; handover: crossing "
-    "orchestration; overload: governor update; trunk: trunk ingress "
-    "dispatch). The flight recorder observes these whether or not span "
+    "dispatch+step, and inside it on the device worker step.flush: "
+    "staged host rows to the device; step.dispatch: enqueueing the "
+    "passes; step.fetch: blocked on the chip plus the per-tick "
+    "readback; step.census_fetch: the sim census's transfer; "
+    "sim_census: census absorb, journal and commit; publish_due: due "
+    "decisions to the cell channels; readback: device->host "
+    "interest-mask transfers; follow_interests: the full follower "
+    "pass; query_plane: standing-query consume and apply; handover: "
+    "crossing orchestration; overload: governor update; trunk: trunk "
+    "ingress dispatch; trace_freeze: the ring copy of an anomaly "
+    "dump). The flight recorder observes these whether or not span "
     "recording is enabled",
     ["stage"],
-    buckets=(0.01, 0.05, 0.1, 0.5, 1.0, 2.0, 5.0, 10.0, 33.0, 100.0),
+    buckets=(0.01, 0.05, 0.1, 0.5, 1.0, 2.0, 5.0, 10.0, 33.0, 100.0,
+             200.0, 500.0, 1000.0),
+    registry=registry,
+)
+tick_late_ms = SumCount(
+    "tick_late_ms",
+    "How long after it was due (the tick before it plus the channel's "
+    "tick interval) a channel tick started, milliseconds, by channel "
+    "type: the time work waited for the event loop. A tick that follows "
+    "a park is late against nothing and is not counted",
+    ["channel_type"],
+    registry=registry,
+)
+fanout_window_lag_ms = SumCount(
+    "fanout_window_lag_ms",
+    "How far behind its fan-out window (last fan-out plus its interval) "
+    "a subscription past its first fan-out was when tick_data served "
+    "it, milliseconds, by channel type",
+    ["channel_type"],
     registry=registry,
 )
 trace_dumps = Counter(

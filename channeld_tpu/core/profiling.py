@@ -1,8 +1,9 @@
 """Profiling hooks (ref: pkg/channeld/profiling.go:12-31).
 
 ``-profile cpu`` -> cProfile, ``-profile mem`` -> tracemalloc,
-``-profile tpu`` -> a jax profiler trace (XLA ops, device timelines,
-HLO — viewable in TensorBoard or Perfetto), ``-profile tasks`` -> the
+``-profile tpu`` -> a jax profiler trace over the process's life (XLA
+ops, device timelines and the flight recorder's ``channeld/`` spans on
+the host's lines, core/tracing.py — viewable in TensorBoard or Perfetto), ``-profile tasks`` -> the
 asyncio analog of the reference's "goroutine" mode: a dump of every
 live task (the per-channel tick tasks, listeners, pumps) with its
 current stack, plus every OS thread's stack. Results are written to the
@@ -114,10 +115,10 @@ def start_profiling(kind: str, profile_path: str = "profiles") -> None:
         _mem_tracing = True
         logger.info("memory profiling started")
     elif kind == "tpu":
-        import jax
+        from .tracing import open_device_trace
 
         _tpu_trace_dir = os.path.join(profile_path, "tpu_trace")
-        jax.profiler.start_trace(_tpu_trace_dir)
+        open_device_trace(_tpu_trace_dir)
         logger.info("device trace started -> %s", _tpu_trace_dir)
     elif kind == "tasks":
         _tasks_mode = True
@@ -144,9 +145,9 @@ def stop_profiling() -> Optional[str]:
         logger.info("task dump written to %s", path)
         return path
     if _tpu_trace_dir is not None:
-        import jax
+        from .tracing import close_device_trace
 
-        jax.profiler.stop_trace()
+        close_device_trace()
         path, _tpu_trace_dir = _tpu_trace_dir, None
         logger.info("device trace written to %s", path)
         return path

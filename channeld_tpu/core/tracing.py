@@ -24,6 +24,13 @@ both presuppose correlated, low-overhead telemetry):
   redirect carries its trace id over the trunk (``traceId`` on
   TrunkHandoverPrepare/Ack/StageRedirect), so one id stitches spans
   from both gateways' recorders into a single reconstructible trace.
+- **One clock with the device.** While a ``jax.profiler`` session is
+  live (``recorder.profiling``, refreshed once per GLOBAL tick), every
+  ``region()`` is also a ``jax.profiler.TraceAnnotation`` named
+  ``channeld/<span>``: the profiler stamps it itself, beside the
+  device's XLA ops, on the line of the thread that made it. The ring's
+  own stamps are ``monotonic_ns`` and cannot be laid over a device
+  trace; the annotations can.
 - **Three exits**: ``dump_trace()`` writes Chrome/Perfetto
   ``trace_event`` JSON (open in ui.perfetto.dev or chrome://tracing —
   the same story as ``-profile tpu``); anomalies (tick-budget blow,
@@ -41,6 +48,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Optional
@@ -56,6 +64,22 @@ _INSTANT = "i"
 
 _trace_counter = itertools.count(1)
 _dump_counter = itertools.count(1)
+
+# ``jax.profiler.TraceAnnotation``, bound on first need. It is looked
+# for only in a process that has imported jax already: no other can hold
+# a profiler session, and core/ must load where jax is absent.
+_annotation = None
+
+
+def _profiler_live() -> bool:
+    """Whether a ``jax.profiler`` session is recording right now,
+    whoever opened it (``TraceMe.is_enabled()``, ~20ns)."""
+    global _annotation
+    if _annotation is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation as _annotation
+    return _annotation.is_enabled()
 
 
 def new_trace_id(prefix: str = "") -> str:
@@ -98,12 +122,63 @@ class _Ring:
         return self.buf[self.idx:] + self.buf[: self.idx]
 
 
+class _Region:
+    """One ``recorder.region()``: the block it wraps is a ring span (and
+    a ``tick_stage_ms`` observation when it is a stage) and, while a
+    profiler session is live, a ``channeld/<name>`` annotation around
+    both clock reads, so containment holds in either sink."""
+
+    __slots__ = ("rec", "name", "lane", "stage", "start_ns", "ann", "void")
+
+    def __init__(self, rec: "FlightRecorder", name: str, lane: int,
+                 stage: bool):
+        self.rec = rec
+        self.name = name
+        self.lane = lane
+        self.stage = stage
+        self.ann = None
+        self.void = False
+
+    def discard(self) -> None:
+        """The block found that it had nothing to do (a held device
+        tick): leave no span and no observation, as a site that records
+        after the fact would not have."""
+        self.void = True
+
+    def __enter__(self) -> "_Region":
+        if self.rec.profiling:
+            ann = self.ann = _annotation("channeld/" + self.name)
+            ann.__enter__()
+        self.start_ns = time.monotonic_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        dur_ns = time.monotonic_ns() - self.start_ns
+        if self.void:
+            self.void = False
+        else:
+            if self.stage:
+                _stage_ms(self.name).observe(dur_ns / 1e6)
+            rec = self.rec
+            if rec.enabled:
+                rec._ring().put((
+                    _COMPLETE, self.name, self.lane, self.start_ns, dur_ns,
+                    rec.tick, None,
+                ))
+        ann = self.ann
+        if ann is not None:
+            self.ann = None
+            ann.__exit__(exc_type, exc, tb)
+
+
 class FlightRecorder:
     """Process-wide recorder (one instance: ``recorder``).
 
-    Hot-path contract: call sites guard on ``recorder.enabled`` (one
-    attribute load while disabled) and use ``now()`` + ``span()`` /
-    ``stage()`` / ``instant()``. Entries are tuples
+    Hot-path contract: a site that has a block to wrap uses
+    ``with recorder.region(name)``; one that only learns afterwards that
+    there was something to time uses ``now()`` + ``span()`` /
+    ``stage()`` / ``instant()`` and guards on ``recorder.enabled`` (one
+    attribute load while disabled). Entries are tuples
     ``(kind, name, lane, start_ns, dur_ns, tick, trace_id)``.
     """
 
@@ -131,6 +206,8 @@ class FlightRecorder:
         self.anomaly_cooldown_s = anomaly_cooldown_s
         self.origin = origin
         self.tick = 0
+        # A jax.profiler session is live (set_tick refreshes it).
+        self.profiling = False
         self.anomalies: list[dict] = []
         self._last_dump_at = -1e9
         with self._rings_lock:
@@ -196,11 +273,21 @@ class FlightRecorder:
                 self.tick, trace,
             ))
 
+    def region(self, name: str, lane: int = 0,
+               stage: bool = False) -> _Region:
+        """Context manager around the work itself: on exit it does what
+        :meth:`span` (``stage=True``: :meth:`stage`) does, and while a
+        profiler session is live the block is also the annotation
+        ``channeld/<name>`` in the device trace."""
+        return _Region(self, name, lane, stage)
+
     def set_tick(self, tick: int) -> None:
-        """Stamp subsequent spans with the GLOBAL tick number (called
-        once per GLOBAL tick)."""
+        """Stamp subsequent spans with the GLOBAL tick number, and look
+        whether a profiler session is live (called once per GLOBAL
+        tick, so regions follow any session within one tick)."""
         _affinity.expect("tick-loop")
         self.tick = tick
+        self.profiling = _profiler_live()
 
     # ---- introspection ---------------------------------------------------
 
@@ -216,24 +303,29 @@ class FlightRecorder:
             "anomalies": len(self.anomalies),
         }
 
+    def _freeze(self) -> list:
+        """``[(tid, entries oldest-first)]``: the raw copy of every
+        ring, all an anomaly costs the thread that tripped it."""
+        with self._rings_lock:
+            rings = list(self._rings.values())
+        return [(ring.tid, ring.snapshot()) for ring in rings]
+
     def snapshot(self, last_ticks: Optional[int] = None) -> list[dict]:
         """Freeze every ring and return span dicts (oldest-first per
         ring), optionally restricted to the last N ticks."""
-        with self._rings_lock:
-            rings = list(self._rings.values())
         floor = None
         if last_ticks is not None:
             floor = self.tick - last_ticks + 1
         out: list[dict] = []
-        for ring in rings:
-            for e in ring.snapshot():
+        for tid, entries in self._freeze():
+            for e in entries:
                 kind, name, lane, start_ns, dur_ns, tick, trace = e
                 if floor is not None and tick < floor:
                     continue
                 d = {
                     "kind": kind, "name": name, "lane": lane,
                     "start_ns": start_ns, "dur_ns": dur_ns, "tick": tick,
-                    "tid": ring.tid,
+                    "tid": tid,
                 }
                 if trace is not None:
                     d["trace"] = trace
@@ -257,7 +349,8 @@ class FlightRecorder:
 
     def to_trace_events(self, spans: list[dict]) -> dict:
         """Chrome/Perfetto ``trace_event`` JSON object for ``spans``
-        (as returned by :meth:`snapshot`)."""
+        (as returned by :meth:`snapshot`): the dumps' format, as an
+        object. The dumps themselves are written by :meth:`_render`."""
         pid = os.getpid()
         events = []
         # One timeline row per (thread, lane): channel ticks get their
@@ -299,6 +392,42 @@ class FlightRecorder:
             "otherData": meta,
         }
 
+    def _render(self, frozen: list, floor: Optional[int],
+                extra: dict) -> tuple[str, int]:
+        """``(text, events)``: what ``json.dumps`` makes of
+        :meth:`to_trace_events` over ``frozen``'s entries from tick
+        ``floor`` on, with ``extra`` added to ``otherData`` — written
+        straight from the ring tuples. An anomaly dump is ~9,000 events;
+        as dicts they cost the interpreter ~90 ms and 25,000 objects for
+        the collector to walk, on a loop that has no idle time to lend
+        (PERF.md, PR 25); as text ~15 ms and none."""
+        picked = [(tid, e) for tid, entries in frozen for e in entries
+                  if floor is None or e[5] >= floor]
+        picked.sort(key=lambda te: te[1][3])  # stable, like snapshot()'s
+        pid, epoch = os.getpid(), self._epoch_ns
+        rows: dict[tuple, int] = {}
+        quoted: dict[str, str] = {}
+        events = []
+        for tid, (kind, name, lane, start_ns, dur_ns, tick, trace) in picked:
+            q = quoted.get(name)
+            if q is None:
+                q = quoted[name] = json.dumps(name)
+            row = rows.setdefault((tid, lane), len(rows))
+            args = f'{{"tick": {tick}, "lane": {lane}'
+            if trace is not None:
+                args += f', "trace": {json.dumps(trace)}'
+            last = (f'"dur": {dur_ns / 1e3!r}' if kind == _COMPLETE
+                    else '"s": "t"')
+            events.append(
+                f'{{"name": {q}, "ph": "{kind}", '
+                f'"ts": {(start_ns - epoch) / 1e3!r}, "pid": {pid}, '
+                f'"tid": {row}, "args": {args}}}, {last}}}')
+        meta = self.to_trace_events([])["otherData"]
+        meta.update(extra)
+        return ('{"traceEvents": [' + ", ".join(events)
+                + '], "displayTimeUnit": "ms", "otherData": '
+                + json.dumps(meta) + "}"), len(events)
+
     def dump_trace(self, path: Optional[str] = None,
                    last_ticks: Optional[int] = None,
                    trigger: str = "manual") -> str:
@@ -308,14 +437,15 @@ class FlightRecorder:
         from . import metrics
 
         metrics.trace_dumps.labels(trigger=trigger).inc()
-        doc = self.to_trace_events(self.snapshot(last_ticks))
-        doc["otherData"]["trigger"] = trigger
+        floor = None if last_ticks is None else self.tick - last_ticks + 1
+        text, events = self._render(self._freeze(), floor,
+                                    {"trigger": trigger})
         if path is None:
             path = self._dump_path(trigger)
         with open(path, "w") as f:
-            json.dump(doc, f)
+            f.write(text)
         logger.info("flight-recorder trace (%s, %d events) -> %s",
-                    trigger, len(doc["traceEvents"]), path)
+                    trigger, events, path)
         return path
 
     def note_anomaly(self, trigger: str, detail: str = "",
@@ -330,9 +460,10 @@ class FlightRecorder:
         construction AND must always ship a timeline — an SLO breach
         (core/slo.py: rising-edge + min-events gated) would otherwise
         lose its dump slot to a storm of per-tick tick_budget anomalies
-        on a saturated box. The snapshot is synchronous (a bounded ring
-        copy); the JSON write runs on a daemon thread so the tick that
-        tripped the anomaly is not stalled by disk I/O."""
+        on a saturated box. Only the raw ring copy is synchronous (the
+        ``trace_freeze`` stage); the tick filter, the sort and the JSON
+        (:meth:`_render`) run on a daemon thread, so the tick that
+        tripped the anomaly is not widened by its own dump."""
         if not self.enabled:
             return None
         from . import metrics
@@ -346,24 +477,25 @@ class FlightRecorder:
         if not force and now - self._last_dump_at < self.anomaly_cooldown_s:
             return None
         self._last_dump_at = now
-        # Only the ring freeze (a bounded copy) runs on the tick path;
-        # event formatting + JSON + disk all happen off-thread — an
+        # Only the ring freeze (list slices) runs on the tick path;
+        # filtering, sorting, JSON and disk all happen off-thread — an
         # anomaly dump must never widen the very tick it is recording.
-        spans = self.snapshot(self.dump_ticks)
-        path = self._dump_path(trigger)
+        with self.region("trace_freeze", stage=True):
+            frozen = self._freeze()
+            floor = self.tick - self.dump_ticks + 1
+            path = self._dump_path(trigger)
         record["path"] = path
 
         def _write():
             _affinity.enter("trace-dumper")
             try:
-                doc = self.to_trace_events(spans)
-                doc["otherData"]["trigger"] = trigger
-                doc["otherData"]["detail"] = detail
+                text, events = self._render(
+                    frozen, floor, {"trigger": trigger, "detail": detail})
                 with open(path, "w") as f:
-                    json.dump(doc, f)
+                    f.write(text)
                 logger.warning(
                     "anomaly %s (%s): last %d ticks (%d spans) frozen -> %s",
-                    trigger, detail or "-", self.dump_ticks, len(spans), path,
+                    trigger, detail or "-", self.dump_ticks, events, path,
                 )
             except OSError as e:  # pragma: no cover - disk trouble
                 logger.error("anomaly dump failed: %s", e)
@@ -389,6 +521,27 @@ def _stage_ms(stage: str):
 
 
 recorder = FlightRecorder()
+
+
+def open_device_trace(path: str) -> None:
+    """Start a ``jax.profiler`` trace into ``path``: the device's lines,
+    XLA's host lines and, from the next GLOBAL tick on, every recorder
+    region as ``channeld/<span>``. The one place in the program that
+    knows how a device trace is opened; the Python tracer stays off (it
+    would stamp every call of every tick)."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1  # level-1 TraceMes: the annotations
+    jax.profiler.start_trace(path, profiler_options=options)
+
+
+def close_device_trace() -> None:
+    """Stop the trace :func:`open_device_trace` started and write it."""
+    import jax
+
+    jax.profiler.stop_trace()
 
 
 def configure_from_settings() -> None:

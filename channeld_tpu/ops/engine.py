@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.tracing import recorder as _trace
 from ..utils.logger import get_logger
 from .spatial_ops import (
     AOI_BOX,
@@ -837,7 +838,18 @@ class SpatialEngine:
         # The flush carries the fence too: its staged commits are the
         # other place a watchdog-abandoned worker could write stale
         # arrays over a rebuilt engine (see _flush_host_state).
-        self._flush_host_state(expect_generation=gen)
+        with _trace.region("step.flush", stage=True):
+            self._flush_host_state(expect_generation=gen)
+        # From the first pass's enqueue to the commit of the _d_*
+        # handles. On one device nothing in here waits for the chip (the
+        # waits are the fetches that follow, ``step.fetch``); a mesh
+        # tick merges its shards' handover rows on the host inside it.
+        with _trace.region("step.dispatch", stage=True):
+            out = self._dispatch_passes(now_ms, gen)
+        self.last_result = out
+        return out
+
+    def _dispatch_passes(self, now_ms: int, gen: int) -> dict:
         # Sim pass first (device->device): agents advance, then the
         # spatial pass reads the SAME position array — crossings, AOI,
         # standing queries and fan-out all see the moved agents this
@@ -939,7 +951,6 @@ class SpatialEngine:
         if q_prev is not None:
             self._d_q_prev = q_prev
             self._q_prev_reset_rows.clear()
-        self.last_result = out
         return out
 
     def _mesh_tick(self, now_ms: int) -> dict:
@@ -1013,8 +1024,13 @@ class SpatialEngine:
         permanently lost handover. Mesh ticks can report slightly more
         than max_handovers (per-shard budgets round up); single-device
         counts beyond the row budget re-detect next tick."""
-        count = int(result["handover_count"])
-        rows = np.asarray(result["handovers"])
+        count, rows = result["handover_count"], result["handovers"]
+        if not isinstance(rows, np.ndarray):
+            # Unguarded path: the device guard fetches these inside its
+            # supervised window (core/device_guard.py ``step.fetch``).
+            with _trace.region("step.fetch", stage=True):
+                count = int(count)
+                rows = np.asarray(rows)
         rows = rows[: min(count, len(rows))]
         return [
             (int(self._entity_of_slot[slot]), int(src), int(dst))
@@ -1075,7 +1091,8 @@ class SpatialEngine:
         if blob is None:
             return 0, np.zeros((0, 3), np.int32)
         if not isinstance(blob, np.ndarray):
-            blob = np.asarray(blob)  # tpulint: disable=hot-readback -- the plane's designed once-per-tick changed-rows fetch (unguarded path; cached on the result)
+            with _trace.region("step.fetch", stage=True):
+                blob = np.asarray(blob)  # tpulint: disable=hot-readback -- the plane's designed once-per-tick changed-rows fetch (unguarded path; cached on the result)
             result["query_blob"] = blob
         return parse_query_blob(blob)
 

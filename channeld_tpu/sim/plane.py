@@ -26,6 +26,7 @@ from ..chaos.injector import chaos as _chaos
 from ..core import metrics
 from ..core.overload import governor as _governor
 from ..core.settings import global_settings
+from ..core.tracing import recorder as _trace
 from ..core.wal import wal as _wal
 from ..ops.spatial_ops import SimParams
 from ..spatial.controller import SpatialInfo
@@ -230,10 +231,11 @@ class SimPlane:
     def on_result(self, result: dict) -> None:
         """Post-step absorb: count committed passes; on a census tick,
         fold the fetched kinematic columns into the host shadow, journal
-        them, and commit through the authority's channel path. The
-        census arrays arrive as numpy under the device guard (prefetched
-        inside the supervised window) or as device arrays from a bare
-        ``engine.tick()``."""
+        them, and commit through the authority's channel path (the
+        ``sim_census`` stage; its transfer is the guard's
+        ``step.census_fetch``). The census arrays arrive as numpy under
+        the device guard (prefetched inside the supervised window) or as
+        device arrays from a bare ``engine.tick()``."""
         eng = self.engine
         if not eng.sim_enabled:
             return
@@ -245,6 +247,11 @@ class SimPlane:
         census = result.get("sim_census")
         if census is None:
             return
+        with _trace.region("sim_census", stage=True):
+            self._absorb_census(result, census)
+
+    def _absorb_census(self, result: dict, census) -> None:
+        eng = self.engine
         t0 = time.monotonic()
         pos, vel, state, target = (
             np.asarray(a)  # tpulint: disable=hot-readback -- census-cadence batched fetch (the sim plane's ONLY readback, doc/simulation.md); a no-op under the guard, which already prefetched numpy inside the supervised window

@@ -710,37 +710,41 @@ class TPUSpatialController(StaticGrid2DSpatialController):
 
         import time as _time
 
-        t0 = _time.monotonic()
-        if _chaos.armed:
-            # Chaos: a slow device dispatch (compilation hiccup, busy
-            # chip, thermal step-down). The tick must absorb it —
-            # degradation shows in tpu_step_latency / tick p99, never as
-            # an exception into the channel tick.
-            stall = _chaos.stall_s("device.dispatch_stall")
-            if stall:
-                _time.sleep(stall)  # tpulint: disable=async-blocking -- chaos-injected dispatch stall MODELS a busy chip stalling the tick (doc/chaos.md); blocking is the point
-        if self.simplane is not None:
-            # Sim cadence/chaos decisions for THIS tick (sets the
-            # engine's run_sim_pass/sim_census_due flags; the agent step
-            # itself runs inside the guarded device tick below).
-            self.simplane.pre_step()
-        if _guard.enabled:
-            # Supervised step (doc/device_recovery.md): watchdog +
-            # transient retry + sentinel + in-process rebuild. None =
-            # the engine is down/held this tick — every device-
-            # dependent stage below (due publish, crossing
-            # orchestration, follower pass) waits; host-side work
-            # (server reaping, follower registry upkeep) already ran.
-            result = _guard.run_step(self)
-            if result is None:
-                return
-        else:
-            result = self.engine.tick()
-        handovers = self.engine.handover_list(result)
-        metrics.tpu_step_latency.observe(_time.monotonic() - t0)
         # Same window as tpu_step_latency: dispatch + device step + the
-        # handover-list readback.
-        _trace.stage("device_step", int(t0 * 1e9))
+        # handover-list readback. Inside it, on the device worker, lie
+        # step.flush, step.dispatch, step.fetch and step.census_fetch
+        # (ops/engine.py, core/device_guard.py); what they leave of it
+        # is the thread hop and the guard's own work.
+        with _trace.region("device_step", stage=True) as step:
+            t0 = _time.monotonic()
+            if _chaos.armed:
+                # Chaos: a slow device dispatch (compilation hiccup, busy
+                # chip, thermal step-down). The tick must absorb it —
+                # degradation shows in tpu_step_latency / tick p99, never
+                # as an exception into the channel tick.
+                stall = _chaos.stall_s("device.dispatch_stall")
+                if stall:
+                    _time.sleep(stall)  # tpulint: disable=async-blocking -- chaos-injected dispatch stall MODELS a busy chip stalling the tick (doc/chaos.md); blocking is the point
+            if self.simplane is not None:
+                # Sim cadence/chaos decisions for THIS tick (sets the
+                # engine's run_sim_pass/sim_census_due flags; the agent
+                # step itself runs inside the guarded device tick below).
+                self.simplane.pre_step()
+            if _guard.enabled:
+                # Supervised step (doc/device_recovery.md): watchdog +
+                # transient retry + sentinel + in-process rebuild. None =
+                # the engine is down/held this tick — every device-
+                # dependent stage below (due publish, crossing
+                # orchestration, follower pass) waits; host-side work
+                # (server reaping, follower registry upkeep) already ran.
+                result = _guard.run_step(self)
+                if result is None:
+                    step.discard()  # no step was made: none is counted
+                    return
+            else:
+                result = self.engine.tick()
+            handovers = self.engine.handover_list(result)
+            metrics.tpu_step_latency.observe(_time.monotonic() - t0)
         metrics.tpu_entities.set(self.engine.entity_count())
         if "overflow" in result:
             # Cells-plane bucket overflow: the undelivered entities stay
@@ -765,7 +769,8 @@ class TPUSpatialController(StaticGrid2DSpatialController):
             # Census-cadence absorb/journal/commit (a no-op on every
             # non-census tick beyond one counter diff).
             self.simplane.on_result(result)
-        self._publish_due(result)
+        with _trace.region("publish_due", stage=True):
+            self._publish_due(result)
         if handovers or self._deferred_crossings:
             # Batched orchestration: one owner-swap/remove-add/fan-out
             # pass per (src,dst) cell pair, not per crossing — the device
@@ -848,10 +853,10 @@ class TPUSpatialController(StaticGrid2DSpatialController):
             else:
                 batch = list(pending.values())
                 pending.clear()
-            t_ho = _time.monotonic()
-            StaticGrid2DSpatialController.notify_crossings(self, batch)
-            _governor.note_handover_cost(_time.monotonic() - t_ho)
-            _trace.stage("handover", int(t_ho * 1e9))
+            with _trace.region("handover", stage=True):
+                t_ho = _time.monotonic()
+                StaticGrid2DSpatialController.notify_crossings(self, batch)
+                _governor.note_handover_cost(_time.monotonic() - t_ho)
         if self.queryplane is not None:
             # Standing-query plane (doc/query_engine.md): ONE changed-
             # rows consume per tick, apply O(changed). The CONSUME always
@@ -861,22 +866,22 @@ class TPUSpatialController(StaticGrid2DSpatialController):
             # ticks, halving standing-query cadence exactly as the
             # legacy follower path halves.
             defer = _governor.level >= 2 and not self._follow_skip
-            t_fi = _time.monotonic()
-            if defer:
-                self._follow_skip = True
-                # An empty registry sheds nothing — a zero count would
-                # still create the ledger key and break the soaks'
-                # exact shed accounting.
-                if self.queryplane.count():
-                    _governor.count_shed(
-                        "query_apply_defer", self.queryplane.count()
-                    )
-            else:
-                self._follow_skip = False
-                self._recenter_followers()
-            self.queryplane.pump(result, apply=not defer)
-            cost = _time.monotonic() - t_fi
-            _trace.stage("query_plane", int(t_fi * 1e9))
+            with _trace.region("query_plane", stage=True):
+                t_fi = _time.monotonic()
+                if defer:
+                    self._follow_skip = True
+                    # An empty registry sheds nothing — a zero count would
+                    # still create the ledger key and break the soaks'
+                    # exact shed accounting.
+                    if self.queryplane.count():
+                        _governor.count_shed(
+                            "query_apply_defer", self.queryplane.count()
+                        )
+                else:
+                    self._follow_skip = False
+                    self._recenter_followers()
+                self.queryplane.pump(result, apply=not defer)
+                cost = _time.monotonic() - t_fi
             # Same pressure-signal input the legacy follower pass fed:
             # the plane's host cost is the follower cost now.
             metrics.follower_interest_ms.observe(cost * 1000.0)
@@ -891,10 +896,10 @@ class TPUSpatialController(StaticGrid2DSpatialController):
                 )
             else:
                 self._follow_skip = False
-                t_fi = _time.monotonic()
-                self._apply_follow_interests(result)
-                cost = _time.monotonic() - t_fi
-                _trace.stage("follow_interests", int(t_fi * 1e9))
+                with _trace.region("follow_interests", stage=True):
+                    t_fi = _time.monotonic()
+                    self._apply_follow_interests(result)
+                    cost = _time.monotonic() - t_fi
                 # The follower pass's host cost inside the GLOBAL tick
                 # budget: a first-class histogram and a pressure-signal
                 # input.

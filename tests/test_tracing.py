@@ -1,8 +1,9 @@
 """Flight-recorder tests (core/tracing.py; doc/observability.md):
 ring-overflow semantics with exact drop accounting, span nesting under
 concurrent per-channel tick tasks, trace-id round-trip over a REAL
-trunk pair, the pinned Perfetto trace_event schema, and the anomaly
-auto-dump path."""
+trunk pair, the pinned Perfetto trace_event schema, the anomaly
+auto-dump path, regions as annotations in a ``jax.profiler`` trace, and
+the two wait counters (tick lateness, fan-out window lag)."""
 
 import asyncio
 import json
@@ -176,6 +177,342 @@ def test_anomaly_freezes_last_ticks_and_counts(tmp_path):
     assert recorder.note_anomaly("tick_budget", "again") is None
     assert metrics.trace_dumps.labels(
         trigger="tick_budget")._value.get() == before + 2
+
+
+def test_anomaly_formats_off_thread_and_the_dump_is_unchanged(
+        tmp_path, monkeypatch):
+    """The thread that trips an anomaly pays for the raw ring copy and
+    nothing else (the ``trace_freeze`` stage): the tick filter, the sort
+    and the JSON are the dumper thread's, and no span dict is built on
+    either. The file is byte for byte what ``json.dump`` of the
+    snapshot's ``trace_event`` object gave before."""
+    import threading
+    import time
+
+    from channeld_tpu.core import metrics
+
+    recorder.configure(dump_ticks=4, dump_path=str(tmp_path),
+                       anomaly_cooldown_s=0.0, origin="gw-a")
+    for i in range(10):
+        recorder.set_tick(i)
+        recorder.span("tick", recorder.now(), lane=i % 3)
+        recorder.instant("mark", trace="a-1-1")
+    want = recorder.to_trace_events(recorder.snapshot(4))
+    want["otherData"]["trigger"] = "tick_budget"
+    want["otherData"]["detail"] = "test blow"
+    rendered_on: list = []
+    render, snapshot = recorder._render, recorder.snapshot
+
+    def watched(*args):
+        rendered_on.append(threading.current_thread().name)
+        return render(*args)
+
+    def no_dicts(*args):
+        raise AssertionError("an anomaly builds no span dicts")
+
+    monkeypatch.setattr(recorder, "_render", watched)
+    monkeypatch.setattr(recorder, "snapshot", no_dicts)
+    freezes = metrics.tick_stage_ms.labels(stage="trace_freeze")
+    before = freezes._sum.get()
+    path = recorder.note_anomaly("tick_budget", "test blow")
+    assert freezes._sum.get() > before
+    deadline = time.monotonic() + 5.0
+    while not rendered_on or not os.path.exists(path) \
+            or not os.path.getsize(path):
+        assert time.monotonic() < deadline, f"dump never completed: {path}"
+        time.sleep(0.02)
+    assert rendered_on == ["trace-dump-tick_budget"]
+    assert open(path).read() == json.dumps(want)
+    # The manual dump writes the same format through the same code.
+    monkeypatch.setattr(recorder, "snapshot", snapshot)
+    want = recorder.to_trace_events(recorder.snapshot())
+    want["otherData"]["trigger"] = "manual"
+    manual = recorder.dump_trace(str(tmp_path / "manual.json"))
+    assert open(manual).read() == json.dumps(want)
+
+
+# ---- regions: one API, two sinks ---------------------------------------------
+
+
+def test_region_records_span_and_stage_and_nests_by_containment():
+    from channeld_tpu.core import metrics
+
+    child = metrics.tick_stage_ms.labels(stage="publish_due")
+    count, total = child._buckets[-1].get(), child._sum.get()
+    with recorder.region("tick.GLOBAL", lane=7):
+        with recorder.region("publish_due", stage=True):
+            pass
+        with recorder.region("plain"):
+            pass
+    # A stage observes its histogram; a plain region is a span only.
+    assert sum(b.get() for b in child._buckets) >= count + 1
+    assert child._sum.get() >= total
+    spans = {s["name"]: s for s in recorder.snapshot()}
+    assert set(spans) == {"tick.GLOBAL", "publish_due", "plain"}
+    outer = spans["tick.GLOBAL"]
+    assert outer["lane"] == 7
+    for inner in (spans["publish_due"], spans["plain"]):
+        assert outer["start_ns"] <= inner["start_ns"]
+        assert (inner["start_ns"] + inner["dur_ns"]
+                <= outer["start_ns"] + outer["dur_ns"])
+    # A discarded region leaves neither span nor observation.
+    held = metrics.tick_stage_ms.labels(stage="device_step")
+    before = sum(b.get() for b in held._buckets)
+    with recorder.region("device_step", stage=True) as step:
+        step.discard()
+    assert sum(b.get() for b in held._buckets) == before
+    assert "device_step" not in {s["name"] for s in recorder.snapshot()}
+
+
+def _tpu_world_with_entity():
+    """2x1 TPU world, two spatial servers, one entity (the device
+    guard's own test world)."""
+    import test_device_guard as tdg
+
+    tdg.register_sim_types()
+    ctl, (sa, _sb) = tdg.make_tpu_world()
+    tdg.add_entity(ctl, sa, tdg.ENTITY_START + 1, 50, 50)
+    return ctl
+
+
+def _host_lines(trace_dir) -> list:
+    """``[{annotation name: [(start_ns, end_ns)]}]``, one for each line
+    of the trace's host plane that holds a ``channeld/`` event."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"))
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            found: dict = {}
+            for e in line.events:
+                if e.name.startswith("channeld/"):
+                    found.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+            if found:
+                lines.append(found)
+    return lines
+
+
+def test_profile_tpu_lays_the_spans_beside_the_device_on_one_clock(tmp_path):
+    """``-profile tpu`` opens its trace through core/tracing.py, and
+    while it is live the recorder's regions are ``channeld/<span>``
+    annotations stamped by the profiler itself: the GLOBAL tick and its
+    device step on the loop thread's line, the step's flush, dispatch
+    and fetch on the device worker's, inside the step's interval."""
+    import signal
+
+    from channeld_tpu.core import profiling
+    from helpers import fresh_runtime
+
+    gch = fresh_runtime()
+    _tpu_world_with_entity()
+    handlers = {s: signal.getsignal(s) for s in (signal.SIGINT,
+                                                 signal.SIGTERM)}
+    try:
+        profiling.start_profiling("tpu", str(tmp_path))
+        for _ in range(4):
+            gch.tick_once(gch.get_time())
+        assert recorder.profiling
+        trace_dir = profiling.stop_profiling()
+    finally:
+        for sig, handler in handlers.items():
+            signal.signal(sig, handler)
+    assert trace_dir == os.path.join(str(tmp_path), "tpu_trace")
+    gch.tick_once(gch.get_time())
+    assert not recorder.profiling  # follows the session, whoever holds it
+
+    lines = _host_lines(trace_dir)
+    (loop,) = [ln for ln in lines if "channeld/tick.GLOBAL" in ln]
+    (worker,) = [ln for ln in lines if "channeld/step.flush" in ln]
+    assert loop is not worker
+    # The GLOBAL tick learns of the session before its own region
+    # opens: the first traced tick is in the trace whole.
+    assert len(loop["channeld/tick.GLOBAL"]) == 4
+    steps = loop["channeld/device_step"]
+    assert len(steps) == 4
+    for s0, s1 in steps:
+        assert any(t0 <= s0 and s1 <= t1
+                   for t0, t1 in loop["channeld/tick.GLOBAL"])
+    assert "channeld/publish_due" in loop
+    for name in ("channeld/step.flush", "channeld/step.dispatch",
+                 "channeld/step.fetch"):
+        assert name not in loop
+        assert len(worker[name]) == 4
+        for w0, w1 in worker[name]:
+            assert any(s0 <= w0 and w1 <= s1 for s0, s1 in steps), name
+    for (f0, f1), (d0, d1), (r0, r1) in zip(
+            worker["channeld/step.flush"], worker["channeld/step.dispatch"],
+            worker["channeld/step.fetch"]):
+        assert f1 <= d0 and d1 <= r0  # flush, then dispatch, then fetch
+
+
+def test_no_annotation_is_made_without_a_profiler_session(monkeypatch):
+    from helpers import fresh_runtime
+
+    made: list = []
+
+    class Watched:
+        def __init__(self, name):
+            made.append(name)
+
+        @staticmethod
+        def is_enabled():
+            return False
+
+    monkeypatch.setattr(tracing, "_annotation", Watched)
+    gch = fresh_runtime()
+    _tpu_world_with_entity()
+    for _ in range(3):
+        gch.tick_once(gch.get_time())
+    with recorder.region("publish_due", stage=True):
+        pass
+    assert not recorder.profiling
+    assert made == []
+    names = {s["name"] for s in recorder.snapshot()}
+    assert {"tick.GLOBAL", "device_step", "step.flush", "step.dispatch",
+            "step.fetch", "publish_due"} <= names
+
+
+def test_one_place_opens_a_device_trace():
+    """``grep -rn start_trace channeld_tpu`` finds one call."""
+    import glob
+
+    hits = []
+    for path in glob.glob(os.path.join(REPO, "channeld_tpu", "**", "*.py"),
+                          recursive=True):
+        with open(path) as f:
+            hits += [path for line in f if "start_trace" in line]
+    assert [os.path.relpath(p, REPO) for p in hits] == [
+        os.path.join("channeld_tpu", "core", "tracing.py")]
+
+
+# ---- the wait counters -------------------------------------------------------
+
+
+def _sum_count(metric, channel_type: str) -> tuple:
+    child = metric.labels(channel_type=channel_type)
+    return child._sum.get(), child._count.get()
+
+
+def test_tick_lateness_from_an_injected_clock():
+    """A tick is late by its start minus the tick before it plus the
+    interval, never by less than 0; the first tick and one that follows
+    a park are late against nothing."""
+    from channeld_tpu.core import channel as channel_mod
+    from channeld_tpu.core import metrics
+    from helpers import fresh_runtime
+
+    gch = fresh_runtime()
+    interval = gch.tick_interval
+    assert interval > 0
+    sum0, count0 = _sum_count(metrics.tick_late_ms, "GLOBAL")
+    clock = 100.0
+    gch._note_tick_start(clock, None)  # the first tick: late against nothing
+    for late in (0.0, 0.030, -0.010):  # on time, 30 ms late, early
+        due = clock + interval
+        clock += interval + late
+        gch._note_tick_start(clock, due)
+    gch._note_tick_start(clock + 10.0, None)  # the loop had parked
+    assert channel_mod._tick_late[gch.channel_type][1] == 3
+    gch.tick_once(gch.get_time())  # the GLOBAL tick carries it to /metrics
+    total, count = _sum_count(metrics.tick_late_ms, "GLOBAL")
+    assert count - count0 == 3
+    assert total - sum0 == pytest.approx(30.0, abs=1e-6)
+    assert channel_mod._tick_late[gch.channel_type] == [0, 0]
+
+
+def test_tick_loop_counts_running_ticks_and_no_parked_ones():
+    """The real loop: a channel that may park adds no lateness sample, a
+    channel held awake by a subscriber adds one a tick after its first."""
+    from channeld_tpu.core import channel as channel_mod
+    from channeld_tpu.core.channel import create_channel
+    from channeld_tpu.core.subscription import subscribe_to_channel
+    from channeld_tpu.core.types import ChannelType
+    from helpers import StubConnection, fresh_runtime
+
+    async def scenario():
+        fresh_runtime()
+        idle = create_channel(ChannelType.SUBWORLD, None)
+        busy = create_channel(ChannelType.PRIVATE, None)
+        subscribe_to_channel(StubConnection(1), busy, None)
+        await asyncio.sleep(busy.tick_interval * 4 + 0.05)
+        late = dict(channel_mod._tick_late)
+        assert idle._may_park() and not busy._may_park()
+        return late[ChannelType.SUBWORLD][1], late[ChannelType.PRIVATE][1]
+
+    parked, running = asyncio.run(scenario())
+    assert parked == 0
+    assert running >= 2
+
+
+def test_fanout_window_lag_of_a_subscription_served_late():
+    """A subscription served two intervals after its window closed adds
+    two intervals of lag; its first fan-out adds none."""
+    from channeld_tpu.core import metrics
+    from channeld_tpu.core.channel import create_channel
+    from channeld_tpu.core.data import tick_data, window_lag_ns
+    from channeld_tpu.core.subscription import subscribe_to_channel
+    from channeld_tpu.core.types import ChannelType
+    from channeld_tpu.models import sim_pb2
+    from channeld_tpu.protocol import control_pb2
+    from helpers import StubConnection, fresh_runtime
+
+    gch = fresh_runtime()
+    ch = create_channel(ChannelType.SUBWORLD, None)
+    ch.init_data(sim_pb2.SimEntityChannelData(), None)
+    opts = control_pb2.ChannelSubscriptionOptions(
+        fanOutIntervalMs=100, fanOutDelayMs=0)
+    conn = StubConnection(1)
+    subscribe_to_channel(conn, ch, opts)
+    (foc,) = ch.fan_out_queue
+    ms = 1_000_000
+    t0 = foc.last_fanout_time + 100 * ms
+    tick_data(ch, t0)  # the first fan-out: the full state, no lag sample
+    assert foc.had_first_fanout
+    assert window_lag_ns[ChannelType.SUBWORLD] == [0, 0]
+    tick_data(ch, foc.last_fanout_time + 50 * ms)  # not due: not served
+    assert window_lag_ns[ChannelType.SUBWORLD] == [0, 0]
+    due = foc.last_fanout_time + 100 * ms
+    tick_data(ch, due + 200 * ms)  # served two intervals late
+    assert window_lag_ns[ChannelType.SUBWORLD] == [200 * ms, 1]
+    # The window moved on by one interval, so it is still one behind.
+    assert foc.last_fanout_time == due
+    sum0, count0 = _sum_count(metrics.fanout_window_lag_ms, "SUBWORLD")
+    gch.tick_once(gch.get_time())
+    total, count = _sum_count(metrics.fanout_window_lag_ms, "SUBWORLD")
+    assert (total - sum0, count - count0) == (pytest.approx(200.0), 1)
+
+
+def test_hot_objects_keep_their_shared_keys():
+    """CPython shares the attribute keys of a class's instances, and
+    keeps their values inline, only up to 30 attributes. Past that every
+    ``self.x`` of every tick is a full dict lookup on a dict six times
+    the size: PR 25's first cut took Channel from 26 to 31 and the GLOBAL
+    tick rate fell 4% on the chip (PERF.md). Bundle, do not add."""
+    from channeld_tpu.core.connection import add_connection
+    from channeld_tpu.core.types import ConnectionType
+    from helpers import FakeTransport, fresh_runtime
+
+    gch = fresh_runtime()
+    assert len(vars(gch)) <= 30, sorted(vars(gch))
+    conn = add_connection(FakeTransport(), ConnectionType.CLIENT)
+    assert len(vars(conn)) <= 30, sorted(vars(conn))
+
+
+def test_census_ticks_fit_the_histograms_buckets():
+    """The census ticks (68-158 ms on the chip) sat past the last finite
+    bucket of both families."""
+    from channeld_tpu.core import metrics
+
+    assert metrics.tick_stage_ms._kwargs["buckets"][-3:] == (
+        200.0, 500.0, 1000.0)
+    assert metrics.tpu_step_latency._upper_bounds[-3:-1] == [0.2, 0.5]
 
 
 # ---- tick stamping from the channel plane ----------------------------------
