@@ -27,7 +27,8 @@ from ..engine import Finding, ModuleInfo, RepoContext, Rule, match_scope
 # (module glob, function-name regex): the tick/trunk/adoption hot paths.
 HOT_PATHS: tuple[tuple[str, str], ...] = (
     ("channeld_tpu/spatial/tpu_controller.py",
-     r"^(tick|_apply_follow_interests|_publish_due|_reap_followers|"
+     r"^(tick|begin_tick|_begin_tick|finish_tick|await_step|"
+     r"_apply_follow_interests|_publish_due|_reap_followers|"
      r"device_due|_recenter_followers|collapse_micro_cells)$"),
     # The standing-query plane consumes its ONE pre-fetched changed-rows
     # blob per tick (doc/query_engine.md); every function that runs on
@@ -50,11 +51,13 @@ HOT_PATHS: tuple[tuple[str, str], ...] = (
     # designed batched fetch (worker-thread _step_body) carries reasoned
     # disables, everything else in the guard must stay transfer-free.
     ("channeld_tpu/core/device_guard.py",
-     r"^(run_step|_step_body|_sentinel|_dispatch)$"),
+     r"^(run_step|begin_step|wait_step|await_step|finish_step|"
+     r"_step_body|_sentinel)$"),
     ("channeld_tpu/spatial/grid.py", r"^_orchestrate"),
     ("channeld_tpu/spatial/controller.py", r"^tick$"),
     ("channeld_tpu/core/channel.py",
-     r"^(tick_once|_tick_messages|_tick_connections|"
+     r"^(tick_once|_tick_global|_tick_stages|_tick_messages|"
+     r"_tick_connections|"
      r"_tick_recoverable_subscriptions)$"),
     ("channeld_tpu/federation/trunk.py",
      r"^(send|_dispatch|_read_loop|_heartbeat_loop|_on_heartbeat)$"),

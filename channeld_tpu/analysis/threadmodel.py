@@ -91,9 +91,13 @@ DOMAINS: tuple[Domain, ...] = (
     Domain(
         "tick-loop", thread="loop", steady=True,
         seeds=(
-            ("channeld_tpu/core/channel.py", r"^Channel\.tick_once$"),
+            ("channeld_tpu/core/channel.py",
+             r"^Channel\.(tick_once|_tick_global)$"),
+            # The controller's tick and its two halves: the GLOBAL tick
+            # task calls the halves itself, around its await of the
+            # device worker (core/channel.py _tick_global).
             ("channeld_tpu/spatial/tpu_controller.py",
-             r"^TPUSpatialController\.tick$"),
+             r"^TPUSpatialController\.(tick|begin_tick|finish_tick)$"),
             # Standing-query plane (doc/query_engine.md): consume/apply
             # runs inside the controller tick; seeded explicitly because
             # the attribute hop (self.queryplane.pump) is not a

@@ -23,6 +23,14 @@ Semantics:
   ``strict``.  An unbound domain auto-binds (the checker observes
   reality before it enforces it).
 
+- A third hook covers what thread identity cannot: while a device
+  step is **in flight** (the guard's worker runs it and the GLOBAL tick
+  task awaits it, so the loop thread is free to run anything), whatever
+  swaps the engine's device handles wholesale must wait for the step's
+  finish.  The guard brackets the flight with :func:`step_flight`; the
+  swappers call :func:`expect_no_step`, and one that runs inside the
+  bracket is a violation like any other.
+
 Disarmed (the default in production) every hook is ONE attribute load.
 Tier-1 arms the checker for the whole run (tests/conftest.py) and
 fails any test that produced a violation; ``-debug-affinity`` arms it
@@ -71,6 +79,7 @@ class AffinityChecker:
         self._bound: dict[str, int] = {}
         self.violations: list[dict] = []
         self._warned: set[tuple] = set()
+        self._step_in_flight = False
 
     def arm(self, strict: bool = False) -> None:
         self.reset()
@@ -105,6 +114,25 @@ class AffinityChecker:
             return
         if bound != ident:
             self._violate(domain, key, bound, ident)
+
+    def step_flight(self, in_flight: bool) -> None:
+        """The device guard's bracket around a step in flight: on at the
+        submit to the worker, off when the loop takes the result (or
+        gives the step up)."""
+        if not self.armed:
+            return
+        self._step_in_flight = in_flight
+
+    def expect_no_step(self, what: str) -> None:
+        """Assert no device step is in flight: ``what`` replaces device
+        handles the step's worker is reading and committing."""
+        if not self.armed or not self._step_in_flight:
+            return
+        ident = threading.get_ident()
+        self._violate(f"{what} during a device step",
+                      DOMAIN_THREADS["device-worker"],
+                      self._bound.get(DOMAIN_THREADS["device-worker"], 0),
+                      ident)
 
     # ---- violation plumbing ----------------------------------------------
 

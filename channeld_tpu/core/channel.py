@@ -446,12 +446,16 @@ class Channel:
 
     async def _tick_loop(self) -> None:
         due = None  # when this tick was due (loop clock); None after a park
+        is_global = self.channel_type == ChannelType.GLOBAL
         while not self.is_removing():
             tick_start = time.monotonic()
             self._note_tick_start(tick_start, due)
             # tick_once observes the duration histogram and feeds the
             # overload governor's budget accounting.
-            self.tick_once(self.get_time(), tick_start)
+            if is_global:
+                await self._tick_global(self.get_time(), tick_start)
+            else:
+                self.tick_once(self.get_time(), tick_start)
             elapsed = time.monotonic() - tick_start
             if not self._may_park():
                 due = tick_start + self.tick_interval
@@ -477,6 +481,68 @@ class Channel:
     def tick_once(self, now: Optional[int] = None, tick_start: Optional[float] = None) -> None:
         """One synchronous tick; ``now`` is channel time, injectable for
         tests (ref: channel.go:358-387)."""
+        now, tick_start = self._tick_prologue(now, tick_start)
+        # The tick span closes after the governor update, so the overload
+        # stage nests inside it (containment is how dumps reconstruct
+        # nesting). The three sites that run every channel tick (this
+        # one, messages, fanout) record after the fact, as they always
+        # did, and are regions only while a profiler session is live:
+        # then the loop thread's line in the trace says whose tick, and
+        # which stage of it, the host was in. Off, that costs each one
+        # attribute load; region objects kept on the channel cost the
+        # loop ~0.8 us a tick on the chip's host (PERF.md, PR 25).
+        profiling = _trace.profiling
+        if profiling:
+            with _trace.region(f"tick.{self.channel_type.name}",
+                               lane=self.id):
+                self._tick_stages(now, tick_start, profiling)
+        else:
+            self._tick_stages(now, tick_start, profiling)
+            if _trace.enabled:
+                _trace.span(
+                    f"tick.{self.channel_type.name}",
+                    int(tick_start * 1e9), lane=self.id,
+                )
+        if _trace.enabled and self.tick_interval > 0:
+            self._note_tick_budget(tick_start)
+
+    async def _tick_global(self, now: int, tick_start: float) -> None:
+        """The GLOBAL channel's tick from its own tick task: what
+        ``tick_once`` does, with the spatial controller's tick split at
+        the wait for the device step. The task awaits the worker's
+        future there and the loop serves the other channels meanwhile;
+        the step's result is consumed in this same tick. No region is
+        open across the await (annotations nest per thread by
+        containment: one held here would swallow every other channel's
+        ``tick.*`` span), so the tick shows as two ``tick.GLOBAL``
+        spans, before and after. Everything that reads this tick's COST
+        (the duration histogram, the governor, the tick_budget SLO and
+        anomaly) takes its loop-thread time: ``tick_start`` moves on by
+        the awaited seconds, which were other channels' ticks."""
+        from ..spatial.controller import get_spatial_controller
+
+        controller = get_spatial_controller()
+        if controller is None:
+            self.tick_once(now, tick_start)
+            return
+        now, tick_start = self._tick_prologue(now, tick_start)
+        # Regions whether or not a profiler session is live: what that
+        # costs every channel's tick (tick_once) is nothing at one
+        # channel's 20 ticks a second.
+        with _trace.region("tick.GLOBAL", lane=self.id):
+            step = controller.begin_tick()
+        if step is not None:
+            tick_start += await controller.await_step(step)
+        with _trace.region("tick.GLOBAL", lane=self.id):
+            if step is not None:
+                controller.finish_tick(step)
+            self._tick_stages(now, tick_start, _trace.profiling,
+                              controller=False)
+        if _trace.enabled and self.tick_interval > 0:
+            self._note_tick_budget(tick_start)
+
+    def _tick_prologue(self, now: Optional[int],
+                       tick_start: Optional[float]) -> tuple[int, float]:
         if global_settings.development:
             # Race detection (the analog of the reference's go test -race
             # discipline, SURVEY §5): channel state must only ever be
@@ -513,49 +579,31 @@ class Channel:
             # own region opens.
             _trace.set_tick(self.tick_frames)
             _flush_wait_counters()
-        # The tick span closes after the governor update, so the overload
-        # stage nests inside it (containment is how dumps reconstruct
-        # nesting). The three sites that run every channel tick (this
-        # one, messages, fanout) record after the fact, as they always
-        # did, and are regions only while a profiler session is live:
-        # then the loop thread's line in the trace says whose tick, and
-        # which stage of it, the host was in. Off, that costs each one
-        # attribute load; region objects kept on the channel cost the
-        # loop ~0.8 us a tick on the chip's host (PERF.md, PR 25).
-        profiling = _trace.profiling
-        if profiling:
-            with _trace.region(f"tick.{self.channel_type.name}",
-                               lane=self.id):
-                self._tick_stages(now, tick_start, profiling)
-        else:
-            self._tick_stages(now, tick_start, profiling)
-            if _trace.enabled:
-                _trace.span(
-                    f"tick.{self.channel_type.name}",
-                    int(tick_start * 1e9), lane=self.id,
-                )
-        if _trace.enabled and self.tick_interval > 0:
-            total = time.monotonic() - tick_start
-            if total > self.tick_interval:
-                # A blown tick budget freezes the ring: the dump holds
-                # the very stages that ate it (cooldown-bounded).
-                _trace.note_anomaly(
-                    "tick_budget",
-                    f"{self.channel_type.name} {self.id}: "
-                    f"{total * 1e3:.2f}ms > "
-                    f"{self.tick_interval * 1e3:.0f}ms",
-                )
+        return now, tick_start
+
+    def _note_tick_budget(self, tick_start: float) -> None:
+        total = time.monotonic() - tick_start
+        if total > self.tick_interval:
+            # A blown tick budget freezes the ring: the dump holds
+            # the very stages that ate it (cooldown-bounded).
+            _trace.note_anomaly(
+                "tick_budget",
+                f"{self.channel_type.name} {self.id}: "
+                f"{total * 1e3:.2f}ms > "
+                f"{self.tick_interval * 1e3:.0f}ms",
+            )
 
     def _tick_stages(self, now: int, tick_start: float,
-                     profiling: bool) -> None:
-        # Spatial controller ticks with the GLOBAL channel only, to keep a
-        # single writer (ref: channel.go:366-369).
-        if self.channel_type == ChannelType.GLOBAL:
+                     profiling: bool, controller: bool = True) -> None:
+        if controller and self.channel_type == ChannelType.GLOBAL:
+            # Spatial controller ticks with the GLOBAL channel only, to
+            # keep a single writer (ref: channel.go:366-369). The tick
+            # task ran it already, in two halves (``_tick_global``).
             from ..spatial.controller import get_spatial_controller
 
-            controller = get_spatial_controller()
-            if controller is not None:
-                controller.tick()
+            spatial = get_spatial_controller()
+            if spatial is not None:
+                spatial.tick()
         # Deferred ingest runs land in the queue before it drains, so a
         # tick never misses traffic the per-read dispatch would have
         # delivered (also what keeps on_bytes + tick_once tests exact).

@@ -10,7 +10,13 @@ single-chip fault a local, bounded event instead:
 
 - **Watchdog.** The guarded step runs on a dedicated worker thread and
   the tick waits at most ``device_step_deadline_s`` (the jax call
-  blocks, so hang detection must be off-thread). A timed-out step is
+  blocks, so hang detection must be off-thread). The step is two halves
+  around that wait: ``begin_step`` (state checks, staging, submit) and
+  ``finish_step`` (classification, sentinel, recovery). The GLOBAL
+  channel's tick task *awaits* between them (``await_step``), so the
+  loop serves every other channel while the worker and the chip run the
+  step; a direct caller blocks (``wait_step``; ``run_step`` is the three
+  in a row). A timed-out step is
   abandoned: the engine's generation fence is bumped so the zombie
   worker can never commit its tail state over a rebuilt engine, the
   worker pool is discarded, and the failure is FATAL (a wedged chip
@@ -57,6 +63,7 @@ ledger — so ``scripts/device_soak.py`` proves the accounting exact.
 
 from __future__ import annotations
 
+import asyncio
 import concurrent.futures
 import time
 from enum import IntEnum
@@ -89,8 +96,16 @@ class DeviceStepError(RuntimeError):
         self.transient = transient
 
 
-class _StepHang(RuntimeError):
-    pass
+class StepInFlight:
+    """One guarded step between its halves: the staged batch the worker
+    runs and the future it answers on."""
+
+    __slots__ = ("engine", "batch", "fut")
+
+    def __init__(self, engine, batch, fut):
+        self.engine = engine
+        self.batch = batch
+        self.fut = fut
 
 
 # Substrings of the retryable XLA/jax status families. Real runtime
@@ -210,12 +225,25 @@ class DeviceGuard:
 
     def run_step(self, controller) -> Optional[dict]:
         """Run one supervised engine step for ``controller``
-        (TPUSpatialController). Returns the step result with the batched
-        readback arrays already materialized on host — or None while the
-        engine is down/held (the controller must hold all
-        device-dependent work for that tick)."""
+        (TPUSpatialController) and block for it: the two halves in a
+        row, for callers that are not the GLOBAL channel's tick task.
+        Returns the step result with the batched readback arrays already
+        materialized on host — or None while the engine is down/held
+        (the controller must hold all device-dependent work for that
+        tick)."""
+        step = self.begin_step(controller)
+        if step is None:
+            return None
+        self.wait_step(step)
+        return self.finish_step(controller, step)
+
+    def begin_step(self, controller) -> Optional[StepInFlight]:
+        """First half, on the loop thread: the state machine's checks,
+        the chaos gate, staging (``engine.stage_step``: dirty sets taken,
+        rows gathered) and the submit to the worker. None = no step was
+        made this tick (engine down/held, or a rebuild was driven)."""
         # Affinity: the guard's state machine is loop-thread-only; all
-        # device waits happen on the worker via _dispatch.
+        # device waits happen on the worker via _step_body.
         _affinity.expect("tick-loop")
         now = time.monotonic()
         if self.state != DeviceState.ACTIVE:
@@ -227,15 +255,69 @@ class DeviceGuard:
                 self.held_ticks += 1
                 return None  # serve again from the NEXT tick
             # DEGRADED: backoff elapsed — retry the step below.
+        engine = controller.engine
         if _chaos.armed and _chaos.fire("device.nan"):
             # Chaos: silent device-state rot (NaN positions + garbage
             # cell baselines). Planted BEFORE the step so the sentinel
             # must catch it from the ordinary readback, exactly like a
             # real bit-flip would have to be caught.
-            controller.engine.corrupt_device_state_for_chaos()
+            engine.corrupt_device_state_for_chaos()
+        batch = engine.stage_step()
+        fut = self._executor().submit(self._step_body, engine, batch)
+        _affinity.step_flight(True)
+        return StepInFlight(engine, batch, fut)
+
+    def wait_step(self, step: StepInFlight) -> None:
+        """Block the calling thread until the step is done or its
+        deadline passes (``finish_step`` tells which)."""
+        concurrent.futures.wait(
+            [step.fut],
+            timeout=max(global_settings.device_step_deadline_s, 0.001),
+        )
+
+    async def await_step(self, step: StepInFlight) -> None:
+        """The same wait for the GLOBAL channel's tick task: the loop
+        runs the other channels' ticks, reads and writes meanwhile. On
+        the deadline the worker's future stays as it is (a running step
+        cannot be cancelled): ``finish_step`` fences and abandons it.
+        Awaited bare under ``wait_for``, not through ``asyncio.wait``:
+        each future between the worker and this task is one more trip
+        through the loop's ready queue, and on a saturated loop a trip
+        is a whole round of callbacks (PERF.md, PR 26)."""
         try:
-            result = self._dispatch(controller.engine)
-        except _StepHang:
+            await asyncio.wait_for(
+                asyncio.wrap_future(step.fut),
+                max(global_settings.device_step_deadline_s, 0.001),
+            )
+        except asyncio.CancelledError:
+            _affinity.step_flight(False)  # nobody finishes it
+            step.engine.end_flight(step.batch)
+            raise
+        except Exception:
+            # The deadline (the step still runs: a hang) or the step's
+            # own error: finish_step tells them apart and classifies.
+            pass
+
+    def finish_step(self, controller, step: StepInFlight) -> Optional[dict]:
+        """Second half, on the loop thread, once the wait is over: a
+        step still running is a hang; one that raised is retried or
+        rebuilt; one that answered passes the sentinel."""
+        _affinity.expect("tick-loop")
+        _affinity.step_flight(False)
+        engine, fut = step.engine, step.fut
+        churn = engine.end_flight(step.batch)
+        if not fut.done() or fut.cancelled():
+            # (Cancelled: the deadline passed with the step still queued
+            # behind the worker.) Fence first, then abandon: the zombie
+            # re-checks the generation before touching the engine and
+            # before committing its tail state (ops/engine.py
+            # run_staged). Its batch is handed back like a failed
+            # step's: the rebuild uploads every table whole from the
+            # host mirrors anyway, and nothing depends on that here.
+            engine.bump_generation()
+            self._abandon_executor()
+            engine.restage(step.batch)
+            fut.add_done_callback(_log_zombie)
             self._count_failure("hang")
             logger.error(
                 "device step exceeded the %.2fs watchdog deadline; "
@@ -244,7 +326,12 @@ class DeviceGuard:
             )
             self._enter_fatal(controller, "hang")
             return None
+        try:
+            result = fut.result(timeout=0)  # done: checked above
         except Exception as exc:
+            # What the failed step never committed goes back to the
+            # dirty sets: the retry (or the rebuild) carries it.
+            engine.restage(step.batch)
             self._count_failure("step_error")
             if (
                 classify_failure(exc) == "transient"
@@ -254,7 +341,8 @@ class DeviceGuard:
                 backoff = (
                     global_settings.device_retry_backoff_ms / 1000.0
                 ) * (2 ** (self._retry_count - 1))
-                self._not_before = time.monotonic() + backoff
+                now = time.monotonic()
+                self._not_before = now + backoff
                 if self._failed_at is None:
                     self._failed_at = now
                 logger.warning(
@@ -267,7 +355,7 @@ class DeviceGuard:
                 return None
             self._enter_fatal(controller, "step_error")
             return None
-        corrupt = self._sentinel(controller.engine, result)
+        corrupt = self._sentinel(engine, result)
         if corrupt:
             self._count_failure("corruption")
             logger.error("device readback sentinel: %s; rebuilding",
@@ -279,29 +367,19 @@ class DeviceGuard:
             # no rebuild needed.
             self._finish_recovery("transient")
         self._retry_count = 0
+        if churn is not None:
+            # Slots and rows that changed owner during the flight: the
+            # result's consumers leave them out (ops/engine.py
+            # StepChurn).
+            result["churn"] = churn
         return result
 
-    def _dispatch(self, engine) -> dict:
-        gen = engine.generation
-        fut = self._executor().submit(self._step_body, engine, gen)
-        try:
-            return fut.result(
-                timeout=max(global_settings.device_step_deadline_s, 0.001)
-            )
-        except concurrent.futures.TimeoutError:
-            # Fence first, then abandon: the zombie re-checks the
-            # generation before touching the engine and before
-            # committing its tail state (ops/engine.py tick()).
-            engine.bump_generation()
-            self._abandon_executor()
-            fut.add_done_callback(_log_zombie)
-            raise _StepHang()
-
     @staticmethod
-    def _step_body(engine, gen: int) -> dict:
-        """Worker-thread body: chaos gates, the engine step, and the
-        batched readback fetch — ALL device waits happen here so the
-        watchdog deadline covers dispatch and transfer alike."""
+    def _step_body(engine, batch) -> dict:
+        """Worker-thread body: chaos gates, the engine step from its
+        staged batch, and the batched readback fetch — ALL device waits
+        happen here so the watchdog deadline covers dispatch and
+        transfer alike."""
         _affinity.enter("device-worker")
         if _chaos.armed:
             stall = _chaos.stall_s("device.step_hang")
@@ -314,11 +392,11 @@ class DeviceGuard:
                     "chaos: injected device step error "
                     "(RESOURCE_EXHAUSTED)", transient=True,
                 )
-        if gen != engine.generation:
+        if batch.gen != engine.generation:
             # This step was abandoned while the chaos stall (or a real
             # queue wait) held the worker: never touch the engine.
             raise RuntimeError("stale device tick abandoned by watchdog")
-        result = engine.tick()
+        result = engine.run_staged(batch)
         # The per-tick batched readbacks, fetched ONCE inside the
         # guarded window (a hung transfer is a hang, not a mystery
         # stall in the controller) and handed on as numpy so the

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.affinity import affinity as _affinity
 from ..core.tracing import recorder as _trace
 from ..utils.logger import get_logger
 from .spatial_ops import (
@@ -71,6 +72,58 @@ def _set_rows(arr, idx, vals):
     return arr.at[idx].set(vals)
 
 
+class StepBatch:
+    """What one device step takes from the host, gathered by
+    ``SpatialEngine.stage_step`` on the thread that owns the host
+    mirrors (the tick loop) and consumed by ``run_staged`` on whichever
+    thread makes the device calls (the guard's worker). The dirty sets
+    are TAKEN at staging (swapped for empty ones) and the rows to upload
+    are gathered into arrays of the batch's own, so a mutator that runs
+    while the step is in flight marks dirty for the NEXT flush and never
+    tears this one (doc/concurrency.md#the-step-in-flight).
+
+    A ``*_rows`` field is ``(taken, idx, values...)``: the taken dirty
+    collection (what ``restage`` hands back), its bucketed index vector
+    and the gathered rows. The worker sets a field to None as it commits
+    that block, so what is left after a failed step is exactly what
+    never reached the device.
+
+    ``churn`` is the other direction of the same flight: what the loop
+    did to the registries while the step ran (see ``StepChurn``)."""
+
+    __slots__ = (
+        "gen", "now_ms", "run_sim", "census_due", "sim_tick",
+        "entity", "seed", "sim_full", "sim_rows", "flee", "spots_full",
+        "spots_rows", "queries", "sub_full", "sub_last", "sub_rows",
+        "q_reset", "churn",
+    )
+
+    def __init__(self):
+        for name in self.__slots__:
+            setattr(self, name, None)
+
+
+class StepChurn:
+    """The entity slots, query rows and sub slots that changed owner
+    (were freed or allocated) while one step was in flight. The step's
+    result cites slots and rows, and is read through registries that
+    have moved on: what it says of a churned slot belongs to an owner
+    who left (or to nobody yet), so every consumer of the result leaves
+    those out (``handover_list``, ``interested_cells_batch``, the
+    controller's due publish, the query plane's consume, the census
+    absorb). Nothing is lost by it: the departed owner was unsubscribed
+    or untracked synchronously, and the new owner's rows went dirty for
+    the next flush, which also resets the slot's device baseline
+    (doc/concurrency.md#the-step-in-flight)."""
+
+    __slots__ = ("entities", "queries", "subs")
+
+    def __init__(self):
+        self.entities: set[int] = set()
+        self.queries: set[int] = set()
+        self.subs: set[int] = set()
+
+
 class SpatialEngine:
     def __init__(
         self,
@@ -105,14 +158,20 @@ class SpatialEngine:
         self._sharding = sharding
         self._cell_bucket = cell_bucket
         # shared=fence declarations (doc/concurrency.md#fences): engine
-        # state is written from the tick-loop (mutators; the unguarded
-        # step) AND the device-guard worker (the guarded step + the
-        # in-process rebuild). The loop BLOCKS on the worker inside
-        # run_step, so the only true concurrency is a watchdog-abandoned
-        # zombie worker unwedging late — which the generation fence
-        # makes safe: every engine-visible store re-checks the
-        # generation between staging and store (machine-checked by
-        # tpulint's fence-discipline rule).
+        # state is written from the tick-loop (mutators, staging; the
+        # unguarded step) AND the device-guard worker (the guarded step
+        # + the in-process rebuild). While a step is in flight the loop
+        # RUNS (the GLOBAL tick task awaits the worker), under a split
+        # by ownership: the loop owns the host mirrors and the dirty
+        # collections, the worker owns the ``_d_*`` handles and reads
+        # the staged batch alone (``stage_step`` / ``run_staged``);
+        # whatever swaps handles wholesale waits for the step's finish
+        # (``_affinity.expect_no_step``). Past that the one true
+        # concurrency is a watchdog-abandoned zombie worker unwedging
+        # late — which the generation fence makes safe: every
+        # engine-visible store re-checks the generation between staging
+        # and store (machine-checked by tpulint's fence-discipline
+        # rule).
         self._mesh_step = None  # tpulint: shared=fence
         # Cells-plane shed diagnostics, refreshed each mesh tick.
         self.last_overflow = 0  # tpulint: shared=fence
@@ -190,7 +249,10 @@ class SpatialEngine:
         # host mirror only carries *explicit* writes (add/reset/interval),
         # applied as row scatters — a full rebuild from the mirror would
         # snap every sub's window start back to stale values.
-        self._sub_last = np.zeros(sub_capacity, np.int32)
+        # (Written by the rebuild too, which snaps the active rows to
+        # now: on the worker for the guard, on the loop for a geometry
+        # epoch, never while a step or another rebuild runs.)
+        self._sub_last = np.zeros(sub_capacity, np.int32)  # tpulint: shared=fence
         self._sub_interval = np.zeros(sub_capacity, np.int32)
         self._sub_active = np.zeros(sub_capacity, bool)
         self._sub_free = list(range(sub_capacity - 1, -1, -1))
@@ -204,7 +266,7 @@ class SpatialEngine:
         # .copy(): jax's H2D transfer is async and may read the numpy
         # buffer after this call; _positions/_valid are mutated by
         # add/update_entity before the first tick, so the live buffers
-        # must never be handed to the transfer (see _flush_host_state).
+        # must never be handed to the transfer (see _stage_flush).
         if self._entity_ns is not None:
             self._d_positions = jax.device_put(
                 self._positions.copy(), self._entity_ns
@@ -237,10 +299,10 @@ class SpatialEngine:
         self.sim_seed = 0
         self.sim_params: Optional[SimParams] = None
         self.sim_tick = 0  # counter-based RNG cursor  # tpulint: shared=fence
-        # Per-tick scheduling flags, staged by the controller on the
-        # tick loop before the step is dispatched (same handoff as the
-        # dirty staging sets: the loop blocks on the worker, and a
-        # zombie worker's commit is generation-fenced).
+        # Per-tick scheduling flags, set by the controller on the tick
+        # loop before the step is staged; ``stage_step`` copies them
+        # into the batch (same handoff as the dirty collections), so the
+        # worker never reads these two.
         self.run_sim_pass = False  # tpulint: shared=fence
         self.sim_census_due = False  # tpulint: shared=fence
         self._agent_mask = np.zeros(entity_capacity, bool)
@@ -258,9 +320,16 @@ class SpatialEngine:
         self._d_sim_state = None  # tpulint: shared=fence
         self._d_sim_target = None  # tpulint: shared=fence
         self._d_flee = None  # tpulint: shared=fence
+        # (key, seed, empty danger mask): see _sim_constants.
+        self._sim_consts = None  # tpulint: shared=atomic
         # Double-entry ledger mirroring sim_device_rebuilds_total{result}
         # (scripts/sim_soak.py cross-checks both sides).
-        self.sim_rebuild_counts: dict[str, int] = {}
+        self.sim_rebuild_counts: dict[str, int] = {}  # tpulint: shared=fence
+
+        # The churn of the step in flight, None when none is: opened by
+        # ``stage_step``, closed by ``end_flight``, written by the
+        # allocators and the removers in between. Loop thread only.
+        self._flight: Optional[StepChurn] = None
 
         self._start = time.monotonic()
         self.last_result: Optional[dict] = None  # tpulint: shared=fence
@@ -311,6 +380,8 @@ class SpatialEngine:
             if not self._free:
                 raise RuntimeError("entity capacity exhausted")
             slot = self._free.pop()
+            if self._flight is not None:
+                self._flight.entities.add(slot)
             self._slot_of_entity[entity_id] = slot
             self._entity_of_slot[slot] = entity_id
             # Fresh slot: clear any previous occupant's cell so reuse can't
@@ -346,6 +417,8 @@ class SpatialEngine:
             self._agent_mask[slot] = False
             self._sim_dirty.add(slot)
         self._free.append(slot)
+        if self._flight is not None:
+            self._flight.entities.add(slot)
 
     def entity_count(self) -> int:
         return len(self._slot_of_entity)
@@ -364,6 +437,8 @@ class SpatialEngine:
             if not self._q_free:
                 raise RuntimeError("query capacity exhausted")
             q = self._q_free.pop()
+            if self._flight is not None:
+                self._flight.queries.add(q)
             self._q_of_conn[conn_id] = q
             # Fresh owner for this row: zero its diff baseline before the
             # next tick so the previous occupant's mask can't swallow the
@@ -444,6 +519,8 @@ class SpatialEngine:
                 self._q_spot_dist[q] = -1
                 self._spot_dirty_rows.add(q)
             self._q_free.append(q)
+            if self._flight is not None:
+                self._flight.queries.add(q)
             # A freed row emits no removal rows (the plane unsubscribes
             # synchronously at deregistration) and must hand its next
             # owner a clean diff baseline.
@@ -459,6 +536,8 @@ class SpatialEngine:
         if not self._sub_free:
             raise RuntimeError("subscription capacity exhausted")
         s = self._sub_free.pop()
+        if self._flight is not None:
+            self._flight.subs.add(s)
         self._sub_last[s] = first_due_ms
         self._sub_interval[s] = interval_ms
         self._sub_active[s] = True
@@ -470,6 +549,8 @@ class SpatialEngine:
         self._sub_active[s] = False
         self._sub_free.append(s)
         self._sub_dirty_slots.add(s)
+        if self._flight is not None:
+            self._flight.subs.add(s)
 
     def set_sub_interval(self, s: int, interval_ms: int) -> None:
         """Re-subscription merged new options (ref: subscription.go:34-60)."""
@@ -581,6 +662,7 @@ class SpatialEngine:
         readback sentinel detects (same detection path as ``device.nan``;
         the triggered rebuild re-seeds the rotted rows from the host
         shadow and the population resumes its replayable trajectory)."""
+        _affinity.expect_no_step("corrupt_sim_state_for_chaos")
         live = self.agent_slots()
         n = max(1, len(live) // 4)
         rows = live[:n].astype(np.int32)
@@ -624,7 +706,112 @@ class SpatialEngine:
             return jnp.asarray(arr)
         return jax.device_put(arr, self._replicated_ns)
 
-    def _flush_host_state(self, expect_generation: Optional[int] = None) -> None:
+    def _stage_flush(self, b: StepBatch) -> None:
+        """Take every dirty collection and gather its rows into ``b``:
+        the host half of the flush, on the thread that owns the host
+        mirrors. The fancy-index gathers and ``.copy()`` calls are what
+        keeps a later host write (and jax's asynchronous H2D copy) away
+        from the arrays the device calls read. Which handles are still
+        None is read here: they change only inside a step or a rebuild,
+        and neither is under way while the loop stages."""
+        if self._dirty_slots:
+            taken, self._dirty_slots = self._dirty_slots, set()
+            idx = _bucket(taken)
+            b.entity = (taken, idx, self._positions[idx], self._valid[idx])
+        if self._seed_cells:
+            seeds, self._seed_cells = self._seed_cells, {}
+            b.seed = (seeds, _bucket(seeds.keys()), _bucket(seeds.values()))
+        if self.sim_enabled:
+            if self._d_vel is None:
+                # First upload (or post-rebuild re-upload) of the whole
+                # kinematic column set.
+                b.sim_full = (self._vel.copy(), self._sim_state.copy(),
+                              self._sim_target.copy(),
+                              self._agent_mask.copy())
+                self._sim_dirty = set()
+            elif self._sim_dirty:
+                taken, self._sim_dirty = self._sim_dirty, set()
+                idx = _bucket(taken)
+                b.sim_rows = (taken, idx, self._vel[idx],
+                              self._sim_state[idx], self._sim_target[idx],
+                              self._agent_mask[idx])
+            if self._flee_cells is not None and (
+                self._d_flee is None or self._flee_dirty
+            ):
+                b.flee = self._flee_cells.copy()
+                self._flee_dirty = False
+        if self._q_spot_dist is not None:
+            if self._d_spot_dist is None:
+                b.spots_full = self._q_spot_dist.copy()
+                self._spot_dirty_rows = set()
+            elif self._spot_dirty_rows:
+                taken, self._spot_dirty_rows = self._spot_dirty_rows, set()
+                idx = _bucket(taken)
+                b.spots_rows = (taken, idx, self._q_spot_dist[idx])
+        if (self._d_queries is None or self._queries_dirty
+                or b.spots_full is not None or b.spots_rows is not None):
+            b.queries = (self._q_kind.copy(), self._q_center.copy(),
+                         self._q_extent.copy(), self._q_dir.copy(),
+                         self._q_angle.copy())
+            self._queries_dirty = False
+        if self._d_sub_state is None:
+            b.sub_full = (self._sub_last.copy(), self._sub_interval.copy(),
+                          self._sub_active.copy())
+            self._sub_dirty_slots = set()
+            self._sub_last_dirty = set()
+        else:
+            # Per-column rows of explicit host writes only — the
+            # device's last-fan-out values for untouched slots stay
+            # authoritative (fanout_due advances them device-side).
+            if self._sub_last_dirty:
+                taken, self._sub_last_dirty = self._sub_last_dirty, set()
+                idx = _bucket(taken)
+                b.sub_last = (taken, idx, self._sub_last[idx])
+            if self._sub_dirty_slots:
+                taken, self._sub_dirty_slots = self._sub_dirty_slots, set()
+                idx = _bucket(taken)
+                b.sub_rows = (taken, idx, self._sub_interval[idx],
+                              self._sub_active[idx])
+
+    def restage(self, b: StepBatch) -> None:
+        """Hand back what a failed step never committed: the taken
+        indices rejoin the dirty collections (the next staging gathers
+        their rows afresh from the host mirrors, so a newer write wins).
+        Same thread as ``stage_step``. A whole-table upload that did not
+        commit needs nothing: its handle is still None and the next
+        staging takes the whole table again. After a hang the worker may
+        still hold ``b`` (fenced: it commits nothing more, but it can be
+        between a fence and the ``= None`` of a block it did commit), so
+        each field is read once; a row handed back that had reached the
+        device is uploaded again, which is harmless."""
+        taken = {name: getattr(b, name) for name in (
+            "entity", "seed", "sim_rows", "flee", "spots_rows", "queries",
+            "sub_last", "sub_rows", "q_reset",
+        )}
+        if taken["entity"] is not None:
+            self._dirty_slots |= taken["entity"][0]
+        if taken["seed"] is not None:
+            for slot, cell in taken["seed"][0].items():
+                self._seed_cells.setdefault(slot, cell)
+        if taken["sim_rows"] is not None:
+            self._sim_dirty |= taken["sim_rows"][0]
+        if taken["flee"] is not None:
+            self._flee_dirty = True
+        if taken["spots_rows"] is not None:
+            self._spot_dirty_rows |= taken["spots_rows"][0]
+        if taken["queries"] is not None:
+            self._queries_dirty = True
+        if taken["sub_last"] is not None:
+            self._sub_last_dirty |= taken["sub_last"][0]
+        if taken["sub_rows"] is not None:
+            self._sub_dirty_slots |= taken["sub_rows"][0]
+        if taken["q_reset"] is not None:
+            self._q_prev_reset_rows |= taken["q_reset"][0]
+
+    def _apply_staged(self, b: StepBatch) -> None:
+        """The device half of the flush: every upload and scatter of
+        ``b``, on the thread that may wait for the chip. It reads the
+        batch and the ``_d_*`` handles and no host mirror."""
         def _fence() -> None:
             # Stale-tick fence (core/device_guard.py): a watchdog-
             # abandoned worker that unwedges mid-flush must not commit
@@ -632,134 +819,96 @@ class SpatialEngine:
             # its device work into locals and re-checks the generation
             # immediately before the engine-visible assignment, so the
             # exposure shrinks from the whole flush to one store.
-            if (expect_generation is not None
-                    and expect_generation != self.generation):
+            if b.gen != self.generation:
                 raise RuntimeError("stale device tick abandoned by watchdog")
 
         _fence()
-        if self._dirty_slots:
-            idx = _bucket(self._dirty_slots)
+        if b.entity is not None:
+            _, idx, positions, valid = b.entity
             d_positions = self._keep_entity_sharding(
-                _set_rows(self._d_positions, idx, self._positions[idx])
+                _set_rows(self._d_positions, idx, positions)
             )
             d_valid = self._keep_entity_sharding(
-                _set_rows(self._d_valid, idx, self._valid[idx])
+                _set_rows(self._d_valid, idx, valid)
             )
             _fence()
             self._d_positions = d_positions
             self._d_valid = d_valid
-            self._dirty_slots.clear()
-        if self._seed_cells:
-            slots = _bucket(self._seed_cells.keys())
-            cells = _bucket(self._seed_cells.values())
+            b.entity = None
+        if b.seed is not None:
+            _, slots, cells = b.seed
             d_cell = self._keep_entity_sharding(
                 _set_rows(self._d_cell, slots, cells)
             )
             _fence()
             self._d_cell = d_cell
-            self._seed_cells.clear()
-        if self.sim_enabled:
-            if self._d_vel is None:
-                # First upload (or post-rebuild re-upload) of the whole
-                # kinematic column set. .copy(): async H2D vs later host
-                # writes, same contract as every other mirror.
-                d_vel = jnp.asarray(self._vel.copy())
-                d_state = jnp.asarray(self._sim_state.copy())
-                d_target = jnp.asarray(self._sim_target.copy())
-                d_agent = jnp.asarray(self._agent_mask.copy())
-                _fence()
-                self._d_vel = d_vel
-                self._d_sim_state = d_state
-                self._d_sim_target = d_target
-                self._d_agent = d_agent
-                self._sim_dirty.clear()
-            elif self._sim_dirty:
-                idx = _bucket(self._sim_dirty)
-                d_vel = _set_rows(self._d_vel, idx, self._vel[idx])
-                d_state = _set_rows(self._d_sim_state, idx,
-                                    self._sim_state[idx])
-                d_target = _set_rows(self._d_sim_target, idx,
-                                     self._sim_target[idx])
-                d_agent = _set_rows(self._d_agent, idx, self._agent_mask[idx])
-                _fence()
-                self._d_vel = d_vel
-                self._d_sim_state = d_state
-                self._d_sim_target = d_target
-                self._d_agent = d_agent
-                self._sim_dirty.clear()
-            if self._flee_cells is not None and (
-                self._d_flee is None or self._flee_dirty
-            ):
-                d_flee = jnp.asarray(self._flee_cells.copy())
-                _fence()
-                self._d_flee = d_flee
-                self._flee_dirty = False
-        spots_changed = False
-        if self._q_spot_dist is not None:
-            if self._d_spot_dist is None:
-                # .copy(): async H2D vs later host row writes (below).
-                d_spot = self._put_replicated(self._q_spot_dist.copy())
-                _fence()
-                self._d_spot_dist = d_spot
-                self._spot_dirty_rows.clear()
-                spots_changed = True
-            elif self._spot_dirty_rows:
-                idx = _bucket(self._spot_dirty_rows)
-                d_spot = _set_rows(self._d_spot_dist, idx,
-                                   self._q_spot_dist[idx])
-                _fence()
-                self._d_spot_dist = d_spot
-                self._spot_dirty_rows.clear()
-                spots_changed = True
-        if self._d_queries is None or self._queries_dirty or spots_changed:
-            # .copy(): jax's H2D transfer of a numpy array is async and
-            # may read the buffer AFTER this call returns; these staging
-            # arrays are mutated by later set_query/remove_query calls,
-            # so handing jax the live buffer races host writes against
-            # the deferred copy (observed on a loaded host as a query
-            # table whose slot read as cleared one tick early).
+            b.seed = None
+        if b.sim_full is not None:
+            vel, state, target, agent = b.sim_full
+            d_vel = jnp.asarray(vel)
+            d_state = jnp.asarray(state)
+            d_target = jnp.asarray(target)
+            d_agent = jnp.asarray(agent)
+            _fence()
+            self._d_vel = d_vel
+            self._d_sim_state = d_state
+            self._d_sim_target = d_target
+            self._d_agent = d_agent
+            b.sim_full = None
+        elif b.sim_rows is not None:
+            _, idx, vel, state, target, agent = b.sim_rows
+            d_vel = _set_rows(self._d_vel, idx, vel)
+            d_state = _set_rows(self._d_sim_state, idx, state)
+            d_target = _set_rows(self._d_sim_target, idx, target)
+            d_agent = _set_rows(self._d_agent, idx, agent)
+            _fence()
+            self._d_vel = d_vel
+            self._d_sim_state = d_state
+            self._d_sim_target = d_target
+            self._d_agent = d_agent
+            b.sim_rows = None
+        if b.flee is not None:
+            d_flee = jnp.asarray(b.flee)
+            _fence()
+            self._d_flee = d_flee
+            b.flee = None
+        if b.spots_full is not None:
+            d_spot = self._put_replicated(b.spots_full)
+            _fence()
+            self._d_spot_dist = d_spot
+            b.spots_full = None
+        elif b.spots_rows is not None:
+            _, idx, rows = b.spots_rows
+            d_spot = _set_rows(self._d_spot_dist, idx, rows)
+            _fence()
+            self._d_spot_dist = d_spot
+            b.spots_rows = None
+        if b.queries is not None:
             d_queries = QuerySet(
-                self._put_replicated(self._q_kind.copy()),
-                self._put_replicated(self._q_center.copy()),
-                self._put_replicated(self._q_extent.copy()),
-                self._put_replicated(self._q_dir.copy()),
-                self._put_replicated(self._q_angle.copy()),
+                *(self._put_replicated(col) for col in b.queries),
                 self._d_spot_dist,
             )
             _fence()
             self._d_queries = d_queries
-            self._queries_dirty = False
-        if self._d_sub_state is None:
-            # .copy(): async H2D vs later host writes to these mirrors.
-            d_sub = (
-                self._put_replicated(self._sub_last.copy()),
-                self._put_replicated(self._sub_interval.copy()),
-                self._put_replicated(self._sub_active.copy()),
-            )
+            b.queries = None
+        if b.sub_full is not None:
+            d_sub = tuple(self._put_replicated(col) for col in b.sub_full)
             _fence()
             self._d_sub_state = d_sub
-            self._sub_dirty_slots.clear()
-            self._sub_last_dirty.clear()
-        elif self._sub_dirty_slots or self._sub_last_dirty:
-            # Per-column row scatters of explicit host writes only — the
-            # device's last-fan-out values for untouched slots stay
-            # authoritative (fanout_due advances them device-side).
+            b.sub_full = None
+        elif b.sub_last is not None or b.sub_rows is not None:
             last, interval, active = self._d_sub_state
-            last_idx = sub_idx = None
-            if self._sub_last_dirty:
-                last_idx = _bucket(self._sub_last_dirty)
-                last = _set_rows(last, last_idx, self._sub_last[last_idx])
-            if self._sub_dirty_slots:
-                sub_idx = _bucket(self._sub_dirty_slots)
-                interval = _set_rows(interval, sub_idx,
-                                     self._sub_interval[sub_idx])
-                active = _set_rows(active, sub_idx, self._sub_active[sub_idx])
+            if b.sub_last is not None:
+                _, idx, values = b.sub_last
+                last = _set_rows(last, idx, values)
+            if b.sub_rows is not None:
+                _, idx, intervals, actives = b.sub_rows
+                interval = _set_rows(interval, idx, intervals)
+                active = _set_rows(active, idx, actives)
             _fence()
             self._d_sub_state = (last, interval, active)
-            if last_idx is not None:
-                self._sub_last_dirty.clear()
-            if sub_idx is not None:
-                self._sub_dirty_slots.clear()
+            b.sub_last = None
+            b.sub_rows = None
 
     def warmup(self) -> None:
         """Compile the tick's common (no-spots) step on empty tables —
@@ -805,6 +954,23 @@ class SpatialEngine:
         logger.info("flush scatter buckets warmed in %.1fs",
                     time.monotonic() - t0)
 
+    def _sim_constants(self):
+        """``(seed, empty danger mask)`` as device arrays, made once for
+        the engine's seed and grid. Made anew in every tick they were
+        two more programs to dispatch, and every dispatch is a turn at
+        the interpreter lock, which the device worker shares with the
+        loop thread since the GLOBAL tick awaits the step (PERF.md,
+        PR 26): the tick counter and the clock, which do change, go in
+        as numpy scalars with the pass's own arguments."""
+        key = (self.sim_seed, self.grid.num_cells)
+        held = self._sim_consts
+        if held is None or held[0] != key:
+            held = self._sim_consts = (
+                key, jnp.uint32(self.sim_seed),
+                jnp.zeros(self.grid.num_cells, bool),
+            )
+        return held[1], held[2]
+
     def sim_warmup(self) -> None:
         """Compile the sim step at plane activation, for the same reason
         ``warmup`` exists: the first live sim tick must not pay XLA
@@ -815,6 +981,7 @@ class SpatialEngine:
         if self.sim_params is None:
             return
         n = self.entity_capacity
+        seed, no_flee = self._sim_constants()
         jax.block_until_ready(
             sim_step(
                 self.grid,
@@ -823,46 +990,95 @@ class SpatialEngine:
                 jnp.zeros(n, jnp.int32),
                 jnp.zeros((n, 3), jnp.float32),
                 jnp.zeros(n, bool),
-                jnp.zeros(self.grid.num_cells, bool),
+                no_flee,
                 self.sim_params,
-                jnp.uint32(self.sim_seed),
-                jnp.int32(0),
+                seed,
+                np.int32(0),
             )
         )
 
-    def tick(self, now_ms: Optional[int] = None) -> dict:
-        """Run one device decision pass; returns numpy-backed results."""
-        if now_ms is None:
-            now_ms = self.now_ms()
-        gen = self.generation
-        # The flush carries the fence too: its staged commits are the
-        # other place a watchdog-abandoned worker could write stale
-        # arrays over a rebuilt engine (see _flush_host_state).
+    def stage_step(self, now_ms: Optional[int] = None) -> StepBatch:
+        """The host half of one step, on the thread that owns the host
+        mirrors: take the dirty collections, gather their rows, and fix
+        the step's scheduling inputs (the sim flags the controller set
+        for this tick, the RNG cursor, the diff rows to reset). From
+        here until the step's result is in hand the mutators may run:
+        they write mirrors and dirty sets this batch no longer reads."""
+        b = StepBatch()
+        b.churn = self._flight = StepChurn()
+        b.gen = self.generation
+        b.now_ms = self.now_ms() if now_ms is None else now_ms
+        self._stage_flush(b)
+        b.run_sim = self.sim_enabled and self.run_sim_pass
+        b.census_due = self.sim_census_due
+        b.sim_tick = self.sim_tick
+        if self._q_prev_reset_rows:
+            if self.track_query_changes:
+                taken = self._q_prev_reset_rows
+                self._q_prev_reset_rows = set()
+                b.q_reset = (taken, _bucket(taken))
+            else:
+                # No baseline while tracking is off — when it turns on,
+                # the None baseline full-emits anyway.
+                self._q_prev_reset_rows.clear()
+        return b
+
+    def run_staged(self, b: StepBatch) -> dict:
+        """The device half: uploads, the passes, the commit of the
+        ``_d_*`` handles, all behind ``b``'s generation. Every call that
+        can wait on the chip is in here, so the guard's watchdog covers
+        them by covering this."""
         with _trace.region("step.flush", stage=True):
-            self._flush_host_state(expect_generation=gen)
+            self._apply_staged(b)
         # From the first pass's enqueue to the commit of the _d_*
         # handles. On one device nothing in here waits for the chip (the
         # waits are the fetches that follow, ``step.fetch``); a mesh
         # tick merges its shards' handover rows on the host inside it.
         with _trace.region("step.dispatch", stage=True):
-            out = self._dispatch_passes(now_ms, gen)
+            out = self._dispatch_passes(b)
         self.last_result = out
         return out
 
-    def _dispatch_passes(self, now_ms: int, gen: int) -> dict:
+    def tick(self, now_ms: Optional[int] = None) -> dict:
+        """Run one device decision pass on the calling thread; returns
+        numpy-backed results."""
+        batch = self.stage_step(now_ms)
+        try:
+            return self.run_staged(batch)
+        except BaseException:
+            self.restage(batch)
+            raise
+        finally:
+            self.end_flight(batch)
+
+    def end_flight(self, b: StepBatch) -> Optional[StepChurn]:
+        """The step staged as ``b`` is over for the loop (answered,
+        failed or given up): owner changes are no longer recorded.
+        Returns those the flight saw, or None when it saw none, for the
+        step's result to carry as ``result["churn"]``. Same thread as
+        ``stage_step``."""
+        self._flight = None
+        churn = b.churn
+        if churn.entities or churn.queries or churn.subs:
+            return churn
+        return None
+
+    def _dispatch_passes(self, b: StepBatch) -> dict:
         # Sim pass first (device->device): agents advance, then the
         # spatial pass reads the SAME position array — crossings, AOI,
         # standing queries and fan-out all see the moved agents this
-        # very tick, with zero extra transfers. The committed flags were
-        # staged by the controller on the loop thread before dispatch.
+        # very tick, with zero extra transfers. The flags came with the
+        # batch: the controller set them on the loop thread before it
+        # staged, and may set the next tick's while this one runs.
+        now_ms = b.now_ms
         sim_committed = None
         census_due = False
         positions = self._d_positions
-        if (self.sim_enabled and self.run_sim_pass
-                and self._d_vel is not None):
+        if b.run_sim and self._d_vel is not None:
+            seed, no_flee = self._sim_constants()
             flee = self._d_flee
             if flee is None:
-                flee = jnp.zeros(self.grid.num_cells, bool)
+                flee = no_flee
             sim_committed = sim_step(
                 self.grid,
                 positions,
@@ -872,11 +1088,11 @@ class SpatialEngine:
                 self._d_agent,
                 flee,
                 self.sim_params,
-                jnp.uint32(self.sim_seed),
-                jnp.int32(self.sim_tick),
+                seed,
+                np.int32(b.sim_tick),
             )
             positions = sim_committed[0]
-            census_due = self.sim_census_due
+            census_due = b.census_due
         if self._mesh is not None:
             out = self._mesh_tick(now_ms)
         else:
@@ -888,7 +1104,7 @@ class SpatialEngine:
                 self._d_queries,
                 self._d_sub_state,
                 self.max_handovers,
-                jnp.int32(now_ms),
+                np.int32(now_ms),
                 use_pallas=self.use_pallas,
             )
         q_prev = None
@@ -899,10 +1115,10 @@ class SpatialEngine:
                     jnp.zeros(out["interest"].shape, bool),
                     jnp.zeros(out["interest"].shape, jnp.int32),
                 )
-            elif self._q_prev_reset_rows:
+            elif b.q_reset is not None:
                 # Reused rows start from an empty baseline (pure compute
                 # on the old arrays; committed only after the gen check).
-                idx = _bucket(self._q_prev_reset_rows)
+                idx = b.q_reset[1]
                 prev = (_set_rows(prev[0], idx, np.bool_(False)),
                         _set_rows(prev[1], idx, np.int32(0)))
             q_blob, q_prev_i, q_prev_d = diff_query_masks(
@@ -912,11 +1128,7 @@ class SpatialEngine:
             out["query_blob"] = q_blob
             out["query_epoch"] = self.query_epoch
             q_prev = (q_prev_i, q_prev_d)
-        else:
-            # No baseline while tracking is off — when it turns on, the
-            # None baseline full-emits anyway, so pending resets are moot.
-            self._q_prev_reset_rows.clear()
-        if gen != self.generation:
+        if b.gen != self.generation:
             # The watchdog abandoned this step (device_guard): the
             # engine may already be rebuilt — committing this tick's
             # tail state would corrupt the fresh baseline.
@@ -932,7 +1144,7 @@ class SpatialEngine:
             # re-uploads every column from the host shadow.
             (self._d_positions, self._d_vel, self._d_sim_state,
              self._d_sim_target) = sim_committed
-            self.sim_tick += 1
+            self.sim_tick = b.sim_tick + 1
             if census_due:
                 # Device handles for the census columns; the guard
                 # pre-fetches them to numpy inside the guarded window
@@ -950,7 +1162,7 @@ class SpatialEngine:
         )
         if q_prev is not None:
             self._d_q_prev = q_prev
-            self._q_prev_reset_rows.clear()
+            b.q_reset = None
         return out
 
     def _mesh_tick(self, now_ms: int) -> dict:
@@ -1032,10 +1244,12 @@ class SpatialEngine:
                 count = int(count)
                 rows = np.asarray(rows)
         rows = rows[: min(count, len(rows))]
+        churn = result.get("churn")
+        gone = churn.entities if churn is not None else ()
         return [
             (int(self._entity_of_slot[slot]), int(src), int(dst))
             for slot, src, dst in rows
-            if slot >= 0
+            if slot >= 0 and slot not in gone
         ]
 
     def interested_cells(self, result: dict, conn_id: int) -> dict[int, int]:
@@ -1060,10 +1274,15 @@ class SpatialEngine:
         followers that alone blew the 33ms GLOBAL tick. The
         masks already live in two device arrays, so the follower pass
         fetches them once and slices rows on host — O(1) transfers per
-        tick regardless of follower count."""
+        tick regardless of follower count.
+
+        A connection whose row changed owner during the step's flight
+        is left out: the row's mask is its last owner's."""
+        churn = result.get("churn")
+        gone = churn.queries if churn is not None else ()
         rows = [
             (cid, q) for cid in conn_ids
-            if (q := self._q_of_conn.get(cid)) is not None
+            if (q := self._q_of_conn.get(cid)) is not None and q not in gone
         ]
         if not rows:
             return {}
@@ -1135,6 +1354,7 @@ class SpatialEngine:
         generation still matches. A rebuild the watchdog abandoned
         (which bumped the generation) raises here when it unwedges
         instead of committing stale state over a later verified one."""
+        _affinity.expect_no_step("rebuild_device_state")
         if now_ms is None:
             now_ms = self.now_ms()
         if expect_generation is None:
@@ -1193,7 +1413,10 @@ class SpatialEngine:
         self._d_sub_state = None
         self._sub_dirty_slots.clear()
         self._sub_last_dirty.clear()
-        self._flush_host_state()
+        batch = StepBatch()
+        batch.gen = self.generation
+        self._stage_flush(batch)
+        self._apply_staged(batch)
         self.last_result = None
 
     def apply_grid(self, grid, slot_cells: dict[int, int],
@@ -1211,6 +1434,7 @@ class SpatialEngine:
         be carried over is rebuilt from world-space sources: the spots
         dist table re-rasterizes from ``_spot_sources``; the compiled
         (mesh) step re-traces lazily on the next tick."""
+        _affinity.expect_no_step("apply_grid")
         self.grid = grid
         # The grid is baked into the compiled mesh step: force a
         # re-build/re-trace on the next tick.
@@ -1302,6 +1526,7 @@ class SpatialEngine:
         outside the world); the garbage baselines surface as impossible
         src cells in the next tick's handover rows, which is exactly the
         signature the readback sentinel checks for."""
+        _affinity.expect_no_step("corrupt_device_state_for_chaos")
         live = list(self._slot_of_entity.values())
         n = max(1, len(live) // 4)
         # Garbage baselines on one subset: their (still-valid) positions

@@ -258,6 +258,12 @@ class SimPlane:
             for a in census
         )
         slots = eng.agent_slots()
+        churn = result.get("churn")
+        if churn is not None and churn.entities:
+            # A slot that changed owner while the step was in flight
+            # holds another entity's row in this census: the new agent's
+            # host shadow is the truth until its first upload.
+            slots = slots[~np.isin(slots, list(churn.entities))]
         eng.absorb_census(slots, pos, vel, state, target)
         ids = eng.agent_ids(slots)
         self._since_census = 0

@@ -42,6 +42,12 @@ class SpatialController(Protocol):
     def get_channel_id_with_offset(self, info: SpatialInfo, dx: float, dy: float, dz: float) -> int: ...
     def create_channels(self, ctx) -> list: ...
     def tick(self) -> None: ...
+    # The GLOBAL channel's tick task calls ``begin_tick`` instead: the
+    # tick up to a wait the loop should not block in. None = the tick is
+    # done; else a step for ``await await_step(step)`` (which returns
+    # the seconds awaited) and then ``finish_tick(step)``, as
+    # spatial/tpu_controller.py has them.
+    def begin_tick(self): ...
     def notify(self, old_info: SpatialInfo, new_info: SpatialInfo, handover_data_provider) -> None: ...
 
 

@@ -579,6 +579,13 @@ class StaticGrid2DSpatialController:
         _balancer.update(self)
         _partition.update(self)
 
+    def begin_tick(self):
+        """The tick as the GLOBAL channel's tick task asks for it
+        (core/channel.py ``_tick_global``): nothing here waits, so the
+        whole tick, and no step to await."""
+        self.tick()
+        return None
+
     # ---- live geometry (doc/partitioning.md) -----------------------------
 
     @property

@@ -318,9 +318,19 @@ class QueryPlane:
         self.ledgers["transfers"] += 1
         metrics.query_plane_transfers.inc()
         consumed = 0
+        churn = result.get("churn")
+        gone = churn.queries if churn is not None else ()
         for q, c, d in rows[: min(count, len(rows))].tolist():
             if q < 0:
                 continue  # compaction discard lane
+            if q in gone:
+                # The row changed owner while this step was in flight
+                # (the GLOBAL tick awaits it): the delta is its last
+                # owner's, whose mirror died at deregistration. The
+                # engine resets the row's device baseline before the
+                # next diff, which then emits the new owner's mask
+                # whole.
+                continue
             mirror = self._mirror.setdefault(q, {})
             if d < 0:
                 mirror.pop(c, None)

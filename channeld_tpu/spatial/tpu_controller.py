@@ -17,10 +17,16 @@ and moves the per-tick *decision plane* onto the device:
   assignment for every entity and compacts boundary crossings; each
   crossing then runs the exact same handover orchestration as the host
   path (owner swap -> entity-table move -> handover fan-out).
+- That tick is two halves around the wait for the device:
+  ``begin_tick`` stages and submits the step, ``finish_tick`` consumes
+  its result. The GLOBAL channel's tick task awaits between them
+  (``await_step``) and the loop serves the other channels meanwhile;
+  ``tick()`` blocks between them, for every direct caller.
 """
 
 from __future__ import annotations
 
+import asyncio
 from typing import Callable, Optional
 
 from ..chaos.injector import chaos as _chaos
@@ -35,6 +41,19 @@ from .controller import SpatialInfo, register_spatial_controller_type
 from .grid import StaticGrid2DSpatialController
 
 logger = get_logger("spatial.tpu")
+
+
+class _TickStep:
+    """One controller tick between ``begin_tick`` and ``finish_tick``:
+    where its ``device_step`` window began, and either the guard's step
+    in flight (to wait for) or, unguarded, the result itself."""
+
+    __slots__ = ("start_ns", "guarded", "result")
+
+    def __init__(self, start_ns: int):
+        self.start_ns = start_ns
+        self.guarded = None
+        self.result = None
 
 
 class TPUSpatialController(StaticGrid2DSpatialController):
@@ -100,6 +119,11 @@ class TPUSpatialController(StaticGrid2DSpatialController):
         # disabled, no agent population, every hook below is one None
         # check.
         self.simplane = None
+        # The guarded step between begin_tick and finish_tick, and
+        # whether a geometry epoch landed meanwhile (on_geometry_changed
+        # swaps device handles: it waits for the finish).
+        self._in_flight = None
+        self._geometry_deferred = False
 
     def load_config(self, config: dict) -> None:
         super().load_config(config)
@@ -385,19 +409,29 @@ class TPUSpatialController(StaticGrid2DSpatialController):
             ch_id - global_settings.spatial_channel_id_start
         )
 
-    def on_geometry_changed(self) -> None:
+    def on_geometry_changed(self, rebuild: bool = False) -> None:
         """A geometry epoch committed (spatial/partition.py apply path or
         WAL/snapshot restore): re-mirror the cell tree onto the device.
         A same-depth change only swaps the host-side micro->leaf map; a
         depth change rebuilds the device arrays onto the new micro grid
         through the supervised-rebuild machinery (generation-fenced
         against watchdog-abandoned steps) and verifies the rebuilt
-        arrays bit-identical to the host shadow."""
+        arrays bit-identical to the host shadow.
+
+        An epoch that lands while a device step is in flight (a trunk's
+        geometry sync during the GLOBAL tick's await) waits for
+        ``finish_tick``: the worker is reading and committing the very
+        handles the rebuild replaces, and the step's result is in the
+        old grid's cell indices. The finish drops that result and
+        rebuilds (``rebuild=True``) whatever the depth did."""
+        if self._in_flight is not None:
+            self._geometry_deferred = True
+            return
         old = (self._mcols, self._mrows)
         self._refresh_micro()
         if self.engine is None:
             return
-        if (self._mcols, self._mrows) == old:
+        if (self._mcols, self._mrows) == old and not rebuild:
             # Same micro grid; only the leaf mapping moved — but that
             # remap still invalidates the sim plane's FLEE mask (it is
             # keyed by micro index via leaf hits).
@@ -545,7 +579,13 @@ class TPUSpatialController(StaticGrid2DSpatialController):
 
         self._due_seq += 1
         due = np.unpackbits(np.asarray(result["due_packed"]))
+        churn = result.get("churn")
+        gone = churn.subs if churn is not None else ()
         for slot in np.nonzero(due)[0].tolist():
+            if slot in gone:
+                # Freed (and perhaps taken again) while the step ran:
+                # the bit is its last owner's decision.
+                continue
             ch_id = self._slot_channel.get(slot)
             if ch_id is not None:
                 self._due_pending.setdefault(ch_id, {})[slot] = self._due_seq
@@ -687,15 +727,59 @@ class TPUSpatialController(StaticGrid2DSpatialController):
         _trace.stage("readback", rb0, end_ns=rb0 + readback_ns)
         for conn_id in live:
             entry = self._followers.get(conn_id)
-            if entry is None:
-                continue
-            wanted = self.collapse_micro_cells(desired_all.get(conn_id, {}))
+            desired = desired_all.get(conn_id)
+            if entry is None or desired is None:
+                continue  # (no mask of its own in this result yet)
+            wanted = self.collapse_micro_cells(desired)
             apply_interest_diff(entry["conn"], wanted)
 
     def tick(self) -> None:
+        """One controller tick for a direct caller (``tick_once`` from
+        tests, soaks and benches): the two halves with a blocking wait
+        between them. The GLOBAL channel's own tick task runs the same
+        halves and awaits instead (core/channel.py ``_tick_global``)."""
+        step = self.begin_tick()
+        if step is not None:
+            if step.guarded is not None:
+                _guard.wait_step(step.guarded)
+            self.finish_tick(step)
+
+    async def await_step(self, step) -> float:
+        """The wait between the halves for the GLOBAL tick task: the
+        loop runs everything else until the worker answers or the
+        watchdog deadline passes (``finish_tick`` tells which). The
+        ``step.await`` stage, recorded after the fact; returns its
+        seconds, which are the other channels' and not this tick's."""
+        if step.guarded is None:
+            return 0.0
+        start_ns = _trace.now()
+        try:
+            await _guard.await_step(step.guarded)
+        except asyncio.CancelledError:
+            # The tick task was cancelled (shutdown): the worker ends
+            # the step by itself and nobody reads its result.
+            self._in_flight = None
+            raise
+        end_ns = _trace.now()
+        _trace.stage("step.await", start_ns, end_ns=end_ns)
+        return (end_ns - start_ns) / 1e9
+
+    def begin_tick(self):
+        """First half: host upkeep, the early returns, and the device
+        step staged and handed to the guard's worker. Returns the step
+        to wait for and finish, or None when this tick makes none. The
+        ``step.begin`` stage: the loop thread's cost of a step up to
+        the submit, counted when one was made."""
+        with _trace.region("step.begin", stage=True) as begin:
+            step = self._begin_tick()
+            if step is None:
+                begin.discard()
+        return step
+
+    def _begin_tick(self):
         super().tick()  # reap closed server connections
         if self.engine is None:
-            return
+            return None
         self._reap_followers()  # even with no entities tracked
         # A tick is needed when entities move OR device-registered fan-out
         # subscriptions exist (due decisions come from the engine even for
@@ -705,46 +789,78 @@ class TPUSpatialController(StaticGrid2DSpatialController):
         if (self.engine.entity_count() == 0 and self._device_sub_count == 0
                 and (self.queryplane is None
                      or self.queryplane.count() == 0)):
-            return
+            return None
+        import time as _time
+
+        # Same window as tpu_step_latency: dispatch + device step + the
+        # handover-list readback, from here to the result in hand in
+        # finish_tick, recorded there after the fact: no region may be
+        # open across the await between the halves (an annotation held
+        # there would swallow every other channel's tick.* span). Inside
+        # it, on the device worker, lie step.flush, step.dispatch,
+        # step.fetch and step.census_fetch (ops/engine.py,
+        # core/device_guard.py); on the loop thread step.begin before
+        # it is submitted and, for the tick task, step.await.
+        step = _TickStep(_trace.now())
+        if _chaos.armed:
+            # Chaos: a slow device dispatch (compilation hiccup, busy
+            # chip, thermal step-down). The tick must absorb it —
+            # degradation shows in tpu_step_latency / tick p99, never
+            # as an exception into the channel tick.
+            stall = _chaos.stall_s("device.dispatch_stall")
+            if stall:
+                _time.sleep(stall)  # tpulint: disable=async-blocking -- chaos-injected dispatch stall MODELS a busy chip stalling the tick (doc/chaos.md); blocking is the point
+        if self.simplane is not None:
+            # Sim cadence/chaos decisions for THIS tick (sets the
+            # engine's run_sim_pass/sim_census_due flags; the agent
+            # step itself runs inside the guarded device tick).
+            self.simplane.pre_step()
+        if _guard.enabled:
+            # Supervised step (doc/device_recovery.md): watchdog +
+            # transient retry + sentinel + in-process rebuild. None =
+            # the engine is down/held this tick — every device-
+            # dependent stage of finish_tick (due publish, crossing
+            # orchestration, follower pass) waits; host-side work
+            # (server reaping, follower registry upkeep) already ran.
+            step.guarded = _guard.begin_step(self)
+            if step.guarded is None:
+                return None  # no step was made: none is counted
+            self._in_flight = step
+        else:
+            # Unguarded: the step stays on the calling thread.
+            step.result = self.engine.tick()
+        return step
+
+    def finish_tick(self, step) -> None:
+        """Second half, with the wait over: take the step's result from
+        the guard and run everything that depends on it, in the tick
+        that dispatched it."""
         from ..core import metrics
 
         import time as _time
 
-        # Same window as tpu_step_latency: dispatch + device step + the
-        # handover-list readback. Inside it, on the device worker, lie
-        # step.flush, step.dispatch, step.fetch and step.census_fetch
-        # (ops/engine.py, core/device_guard.py); what they leave of it
-        # is the thread hop and the guard's own work.
-        with _trace.region("device_step", stage=True) as step:
-            t0 = _time.monotonic()
-            if _chaos.armed:
-                # Chaos: a slow device dispatch (compilation hiccup, busy
-                # chip, thermal step-down). The tick must absorb it —
-                # degradation shows in tpu_step_latency / tick p99, never
-                # as an exception into the channel tick.
-                stall = _chaos.stall_s("device.dispatch_stall")
-                if stall:
-                    _time.sleep(stall)  # tpulint: disable=async-blocking -- chaos-injected dispatch stall MODELS a busy chip stalling the tick (doc/chaos.md); blocking is the point
-            if self.simplane is not None:
-                # Sim cadence/chaos decisions for THIS tick (sets the
-                # engine's run_sim_pass/sim_census_due flags; the agent
-                # step itself runs inside the guarded device tick below).
-                self.simplane.pre_step()
-            if _guard.enabled:
-                # Supervised step (doc/device_recovery.md): watchdog +
-                # transient retry + sentinel + in-process rebuild. None =
-                # the engine is down/held this tick — every device-
-                # dependent stage below (due publish, crossing
-                # orchestration, follower pass) waits; host-side work
-                # (server reaping, follower registry upkeep) already ran.
-                result = _guard.run_step(self)
-                if result is None:
-                    step.discard()  # no step was made: none is counted
-                    return
-            else:
-                result = self.engine.tick()
-            handovers = self.engine.handover_list(result)
-            metrics.tpu_step_latency.observe(_time.monotonic() - t0)
+        if step.guarded is not None:
+            self._in_flight = None
+            result = _guard.finish_step(self, step.guarded)
+        else:
+            result = step.result
+        if self._geometry_deferred:
+            # A geometry epoch landed while the step ran: its rows are
+            # in the old grid's indices and the tree has moved on.
+            # Nothing of it is consumed; the rebuild re-seeds every
+            # baseline from the placement ledger, restarts every sub's
+            # window and bumps the query epoch, so crossings are
+            # re-detected, fan-out resumes an interval on and the query
+            # plane resyncs — as after a fatal step (on_device_fatal).
+            self._geometry_deferred = False
+            self.on_geometry_changed(rebuild=True)
+            return
+        if result is None:
+            return  # held, retried or rebuilding: no step is counted
+        handovers = self.engine.handover_list(result)
+        end_ns = _trace.now()
+        metrics.tpu_step_latency.observe((end_ns - step.start_ns) / 1e9)
+        _trace.stage("device_step", step.start_ns, end_ns=end_ns)
         metrics.tpu_entities.set(self.engine.entity_count())
         if "overflow" in result:
             # Cells-plane bucket overflow: the undelivered entities stay

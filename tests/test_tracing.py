@@ -302,9 +302,11 @@ def _host_lines(trace_dir) -> list:
 def test_profile_tpu_lays_the_spans_beside_the_device_on_one_clock(tmp_path):
     """``-profile tpu`` opens its trace through core/tracing.py, and
     while it is live the recorder's regions are ``channeld/<span>``
-    annotations stamped by the profiler itself: the GLOBAL tick and its
-    device step on the loop thread's line, the step's flush, dispatch
-    and fetch on the device worker's, inside the step's interval."""
+    annotations stamped by the profiler itself: the GLOBAL tick and the
+    step's begin half on the loop thread's line, the step's flush,
+    dispatch and fetch on the device worker's, inside the tick's
+    interval. ``device_step`` itself spans the wait between the halves
+    and is recorded after the fact: no annotation carries it."""
     import signal
 
     from channeld_tpu.core import profiling
@@ -334,11 +336,14 @@ def test_profile_tpu_lays_the_spans_beside_the_device_on_one_clock(tmp_path):
     # The GLOBAL tick learns of the session before its own region
     # opens: the first traced tick is in the trace whole.
     assert len(loop["channeld/tick.GLOBAL"]) == 4
-    steps = loop["channeld/device_step"]
-    assert len(steps) == 4
-    for s0, s1 in steps:
+    assert "channeld/device_step" not in loop
+    assert len(loop["channeld/step.begin"]) == 4
+    for s0, s1 in loop["channeld/step.begin"]:
         assert any(t0 <= s0 and s1 <= t1
                    for t0, t1 in loop["channeld/tick.GLOBAL"])
+    # A direct tick_once() blocks between the halves: one tick.GLOBAL
+    # holds the whole step.
+    steps = loop["channeld/tick.GLOBAL"]
     assert "channeld/publish_due" in loop
     for name in ("channeld/step.flush", "channeld/step.dispatch",
                  "channeld/step.fetch"):
@@ -377,6 +382,78 @@ def test_no_annotation_is_made_without_a_profiler_session(monkeypatch):
     names = {s["name"] for s in recorder.snapshot()}
     assert {"tick.GLOBAL", "device_step", "step.flush", "step.dispatch",
             "step.fetch", "publish_due"} <= names
+
+
+def test_no_annotation_is_open_while_the_global_task_awaits(monkeypatch):
+    """Annotations are per thread and nest by containment: one held
+    across the GLOBAL tick's await would take every other channel's
+    ``tick.*`` span for its own and spoil the device's idle shares
+    (benchmark/harness/host_spans.py). With a profiler session faked
+    live: nothing is open on the loop thread while the step is in
+    flight, the tick shows as two ``tick.GLOBAL`` spans around the
+    await, and every thread exits what it entered last."""
+    import threading
+
+    import test_step_await as tsa
+
+    stacks: dict = {}
+    closed: list = []
+    torn: list = []
+
+    class Recorded:
+        def __init__(self, name):
+            self.name = name
+
+        @staticmethod
+        def is_enabled():
+            return True
+
+        def __enter__(self):
+            stacks.setdefault(threading.get_ident(), []).append(self.name)
+            return self
+
+        def __exit__(self, *exc):
+            stack = stacks[threading.get_ident()]
+            if stack[-1] != self.name:
+                torn.append((self.name, list(stack)))
+            stack.remove(self.name)
+            closed.append((threading.get_ident(), self.name))
+
+    monkeypatch.setattr(tracing, "_annotation", Recorded)
+    loop_thread = threading.get_ident()
+
+    async def scenario():
+        gch = tsa.new_runtime()
+        ctl, _servers = tsa.world_with_entity()
+        held = tsa.Held(ctl.engine)
+        await tsa.until(held.entered.is_set)
+        assert recorder.profiling
+        ticks = sum(1 for t, n in closed
+                    if t == loop_thread and n == "channeld/tick.GLOBAL")
+        in_flight = []
+        for _ in range(5):  # other tasks run; GLOBAL's is parked
+            in_flight.append(list(stacks.get(loop_thread, ())))
+            await asyncio.sleep(0.005)
+        assert ctl._in_flight is not None
+        assert in_flight == [[]] * 5
+        assert "channeld/step.begin" in {n for _, n in closed}
+        held.release.set()
+        await tsa.until(lambda: tsa.stage_count("step.await") >= 1)
+        await tsa.until(lambda: gch.tick_frames >= 3)
+        return ticks
+
+    before = asyncio.run(scenario())
+    assert torn == []
+    assert all(not stack for stack in stacks.values())
+    names = [n for t, n in closed if t == loop_thread]
+    # The tick in flight had closed its first tick.GLOBAL before the
+    # await; each awaited tick closes two.
+    assert names.count("channeld/tick.GLOBAL") >= before + 2
+    assert "channeld/device_step" not in names
+    assert "channeld/step.await" not in names
+    worker = {n for t, n in closed if t != loop_thread}
+    assert {"channeld/step.flush", "channeld/step.dispatch",
+            "channeld/step.fetch"} <= worker
 
 
 def test_one_place_opens_a_device_trace():
