@@ -35,7 +35,7 @@ from .affinity import affinity as _affinity
 from .overload import governor as _governor
 from .settings import global_settings
 from .slo import slo as _slo
-from .tracing import recorder as _trace
+from .tracing import flush_gc_pauses, recorder as _trace
 from .wal import wal as _wal
 from .types import BroadcastType, ChannelType, ConnectionType, GLOBAL_CHANNEL_ID, MessageType
 
@@ -60,14 +60,17 @@ _LOW_WATERMARK = QUEUE_CAPACITY // 4
 # How long work waited for the event loop: [seconds late, ticks] by
 # channel type. Every tick loop adds into its type's pair (a subtraction
 # and two adds a tick); the GLOBAL tick carries the pairs, and
-# core/data.py's fan-out window lag, to /metrics.
+# core/data.py's fan-out window lag and core/tracing.py's collector
+# pauses, to /metrics.
 _tick_late: dict = {t: [0.0, 0] for t in ChannelType}
 
 
 def _flush_wait_counters() -> None:
-    """``tick_late_ms`` and ``fanout_window_lag_ms``, once per GLOBAL
-    tick: a registry call for each of ~7,000 channel ticks a second would
-    sit on the thread that is the bottleneck."""
+    """``tick_late_ms``, ``fanout_window_lag_ms`` and ``gc_pause_ms``,
+    once per GLOBAL tick: a registry call for each of ~7,000 channel
+    ticks a second would sit on the thread that is the bottleneck, and
+    one from inside the collector could wait for a lock its own thread
+    holds."""
     for pairs, metric, to_ms in (
         (_tick_late, metrics.tick_late_ms, 1e3),  # kept in seconds
         (window_lag_ns, metrics.fanout_window_lag_ms, 1e-6),
@@ -77,6 +80,7 @@ def _flush_wait_counters() -> None:
                 metric.labels(channel_type=ctype.name).add(
                     acc[0] * to_ms, acc[1])
                 acc[0], acc[1] = 0, 0
+    flush_gc_pauses()
 
 
 def is_congested() -> bool:
@@ -667,6 +671,12 @@ class Channel:
             if owner is not None:
                 _governor.note_server_cost(owner.id, elapsed)
         if self.channel_type == ChannelType.GLOBAL:
+            from ..spatial.controller import get_spatial_controller
+
+            sim = getattr(get_spatial_controller(), "simplane", None)
+            if sim is not None and sim.census_in_tick:
+                sim.census_in_tick = False
+                metrics.census_tick_ms.add(elapsed * 1e3, 1)
             with _trace.region("overload", lane=self.id, stage=True):
                 _governor.update(self.tick_interval)
             if _slo.enabled:

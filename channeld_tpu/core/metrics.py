@@ -704,7 +704,10 @@ tick_stage_ms = Histogram(
     "staged host rows to the device; step.dispatch: enqueueing the "
     "passes; step.fetch: blocked on the chip plus the per-tick "
     "readback; step.census_fetch: the sim census's transfer; "
-    "sim_census: census absorb, journal and commit; publish_due: due "
+    "sim_census: census absorb, journal and commit, and inside it "
+    "sim_census.absorb: host shadow and last-known rows; "
+    "sim_census.journal: the WAL record; sim_census.commit: the "
+    "authority's walk of its channel-backed agents; publish_due: due "
     "decisions to the cell channels; readback: device->host "
     "interest-mask transfers; follow_interests: the full follower "
     "pass; query_plane: standing-query consume and apply; handover: "
@@ -732,6 +735,22 @@ fanout_window_lag_ms = SumCount(
     "a subscription past its first fan-out was when tick_data served "
     "it, milliseconds, by channel type",
     ["channel_type"],
+    registry=registry,
+)
+census_tick_ms = SumCount(
+    "census_tick_ms",
+    "Whole duration, milliseconds, of each GLOBAL tick that carried a "
+    "sim census: what the overload ladder read against the tick "
+    "interval for that tick (the awaited device step left out, as in "
+    "channel_tick_duration)",
+    registry=registry,
+)
+gc_pause_ms = SumCount(
+    "gc_pause_ms",
+    "Pauses of the interpreter's cyclic collector, milliseconds, by "
+    "generation (1 and 2; generation 0 is not timed), on whichever "
+    "thread tripped them",
+    ["generation"],
     registry=registry,
 )
 trace_dumps = Counter(

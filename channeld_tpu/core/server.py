@@ -15,6 +15,7 @@ connection; a shared pump is the asyncio-idiomatic equivalent).
 from __future__ import annotations
 
 import asyncio
+import gc
 import time
 from collections import deque
 from typing import Optional
@@ -622,6 +623,7 @@ async def run_server(argv: Optional[list[str]] = None) -> None:
     tracing.configure_from_settings()
     install_task_dump_signal(global_settings.profile_path)
     tracing.install_trace_dump_signal()
+    tracing.install_gc_callback()
     if global_settings.trace_enabled:
         tracing.register_shutdown_dump()
         logger.info(
@@ -796,6 +798,14 @@ async def run_server(argv: Optional[list[str]] = None) -> None:
             global_settings.client_network,
             global_settings.client_address,
         ))
+        # Start-up ends here. What the heap holds now (the interpreter's
+        # and jax's modules, the compiled programs, the settings) lives
+        # as long as the process: moved to the permanent generation, it
+        # is never walked again, and a full collection costs what the
+        # gateway allocated since, not ~90 ms (gc_pause_ms; PERF.md,
+        # PR 27).
+        gc.collect()
+        gc.freeze()
         await asyncio.gather(*tasks)
     except asyncio.CancelledError:
         logger.info("serve tasks cancelled; gateway exiting")

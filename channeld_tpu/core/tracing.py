@@ -45,6 +45,7 @@ See doc/observability.md.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
@@ -576,6 +577,61 @@ def install_trace_dump_signal() -> bool:
     except ValueError:
         return False  # not the main thread
     return True
+
+
+# The collector's pauses, by generation: [milliseconds, pauses, start and
+# end of the newest, monotonic ns]. ``_on_gc`` writes them and nothing
+# else does; ``flush_gc_pauses`` keeps what it has carried to /metrics
+# in ``_gc_carried``, so neither side zeroes what the other adds to.
+_gc_pauses = {1: [0.0, 0, 0, 0], 2: [0.0, 0, 0, 0]}
+_gc_carried = {1: [0.0, 0], 2: [0.0, 0]}
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """``gc.callbacks`` entry. It runs inside the collector, on
+    whichever thread tripped it, maybe under a lock that thread holds
+    (the /metrics thread allocates under the registry's): so it touches
+    no registry and no ring, it adds into plain numbers. Generation 0
+    runs hundreds of times a second and takes microseconds: for it this
+    returns at once."""
+    generation = info["generation"]
+    if generation == 0:
+        return
+    pauses = _gc_pauses[generation]
+    if phase == "start":
+        pauses[2] = time.monotonic_ns()
+    elif pauses[2]:
+        pauses[3] = time.monotonic_ns()
+        pauses[0] += (pauses[3] - pauses[2]) / 1e6
+        pauses[1] += 1
+
+
+def flush_gc_pauses() -> None:
+    """Carry the collector's pauses to ``gc_pause_ms{generation}``, and
+    the newest of each generation to the ring as a ``gc.gen<n>`` span
+    (the GLOBAL tick, core/channel.py ``_flush_wait_counters``)."""
+    from . import metrics
+
+    for generation, pauses in _gc_pauses.items():
+        carried = _gc_carried[generation]
+        total_ms, count = pauses[0], pauses[1]
+        if count == carried[1]:
+            continue
+        metrics.gc_pause_ms.labels(generation=str(generation)).add(
+            total_ms - carried[0], count - carried[1])
+        carried[0], carried[1] = total_ms, count
+        recorder.span(f"gc.gen{generation}", pauses[2], end_ns=pauses[3])
+
+
+def install_gc_callback() -> None:
+    """Put the collector's pauses on the record (run_server boot path;
+    idempotent)."""
+    from . import metrics
+
+    for generation in _gc_pauses:  # on /metrics before the first pause
+        metrics.gc_pause_ms.labels(generation=str(generation))
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 def register_shutdown_dump() -> None:

@@ -22,6 +22,8 @@ from __future__ import annotations
 
 from typing import Optional
 
+import numpy as np
+
 from ..core.settings import global_settings
 from ..utils.logger import get_logger
 
@@ -172,28 +174,29 @@ class SimAuthority:
         update via ``on_update`` — the same seam a remote server's
         movement updates flow through, so handover triggers, fan-out and
         the placement ledger behave identically for agents and humans.
-        ``positions`` is a host list of [x, y, z] rows (the plane
-        converts the census before calling). Returns the number of
-        updates committed."""
+        ``ids`` and ``positions`` are the census's host arrays (``[n]``
+        and ``[n, 3]``, row for row); only the rows of channel-backed agents
+        become Python objects, in the census's order. Returns the number
+        of updates committed."""
         from ..core.channel import get_channel
         from ..models import sim_pb2
 
         if not self._backed:
             return 0
         ctl = self.controller
+        backed = np.fromiter(self._backed, np.int64, len(self._backed))
+        rows = np.nonzero(np.isin(ids, backed))[0]
         n = 0
-        for i, eid in enumerate(ids):
-            eid = int(eid)
-            if eid not in self._backed:
-                continue
+        for eid, (x, _y, z) in zip(ids[rows].tolist(),
+                                   positions[rows].tolist()):
             ch = get_channel(eid)
             if ch is None or ch.is_removing():
                 self._backed.discard(eid)
                 continue
             upd = sim_pb2.SimEntityChannelData()
             upd.state.entityId = eid
-            upd.state.transform.position.x = positions[i][0]
-            upd.state.transform.position.z = positions[i][2]
+            upd.state.transform.position.x = x
+            upd.state.transform.position.z = z
 
             def _apply(c, u=upd):
                 owner = c.get_owner()

@@ -113,6 +113,10 @@ class SimPlane:
         self._since_census = 0    # scheduled sim passes since last census
         self._sim_skip = False    # L2+ cadence-halving flip-flop
         self._last_sim_tick = 0   # for the committed-pass counter
+        # This GLOBAL tick absorbed a census: core/channel.py charges
+        # the tick's whole duration to census_tick_ms, the number the
+        # ladder reads against the interval, and takes the flag down.
+        self.census_in_tick = False
         self._danger_key: Optional[int] = None
         # Double-entry ledgers (scripts/sim_soak.py asserts these match
         # the prometheus side exactly).
@@ -132,7 +136,7 @@ class SimPlane:
                 (int(eid), float(p[0]), float(p[1]), float(p[2]))
                 for eid, p in zip(pending["ids"], pending["pos"])
             ]
-            eng.seed_agents(
+            slots = eng.seed_agents(
                 entries, pending["seed"], params,
                 vels=pending["vel"], states=pending["state"],
                 targets=pending["target"],
@@ -147,7 +151,7 @@ class SimPlane:
             )
         else:
             entries = self._fresh_entries()
-            eng.seed_agents(entries, global_settings.sim_seed, params)
+            slots = eng.seed_agents(entries, global_settings.sim_seed, params)
             self._count("agents_spawned", len(entries))
             logger.info(
                 "sim population spawned: %d agents (seed %d)",
@@ -160,6 +164,14 @@ class SimPlane:
         for eid, x, y, z in entries:
             self.controller.track_entity(eid, SpatialInfo(x, y, z))
         self.authority.adopt(eid for eid, *_ in entries)
+        # From here on the population's last-known positions are rows,
+        # as after a census: the objects track_entity made are dropped
+        # now and not in the first census's tick.
+        self.controller._last_positions.absorb_census(
+            np.asarray(slots, np.int64),
+            np.array([e[0] for e in entries], np.int64),
+            np.array([e[1:] for e in entries], np.float32).reshape(-1, 3),
+        )
         eng.sim_warmup()  # compile OUTSIDE the guarded window (watchdog)
         metrics.sim_agents_num.set(eng.agent_count())
 
@@ -249,45 +261,51 @@ class SimPlane:
             return
         with _trace.region("sim_census", stage=True):
             self._absorb_census(result, census)
+        self.census_in_tick = True
 
     def _absorb_census(self, result: dict, census) -> None:
+        """A census costs a fixed number of Python objects, whatever the
+        population: every step below is array work over the census's
+        columns, except the authority's walk of its channel-backed set.
+        Three sub-stages of ``sim_census`` say where its time went."""
         eng = self.engine
         t0 = time.monotonic()
-        pos, vel, state, target = (
-            np.asarray(a)  # tpulint: disable=hot-readback -- census-cadence batched fetch (the sim plane's ONLY readback, doc/simulation.md); a no-op under the guard, which already prefetched numpy inside the supervised window
-            for a in census
-        )
-        slots = eng.agent_slots()
-        churn = result.get("churn")
-        if churn is not None and churn.entities:
-            # A slot that changed owner while the step was in flight
-            # holds another entity's row in this census: the new agent's
-            # host shadow is the truth until its first upload.
-            slots = slots[~np.isin(slots, list(churn.entities))]
-        eng.absorb_census(slots, pos, vel, state, target)
-        ids = eng.agent_ids(slots)
+        with _trace.region("sim_census.absorb", stage=True):
+            pos, vel, state, target = (
+                np.asarray(a)  # tpulint: disable=hot-readback -- census-cadence batched fetch (the sim plane's ONLY readback, doc/simulation.md); a no-op under the guard, which already prefetched numpy inside the supervised window
+                for a in census
+            )
+            slots = eng.agent_slots()
+            churn = result.get("churn")
+            if churn is not None and churn.entities:
+                # A slot that changed owner while the step was in flight
+                # holds another entity's row in this census: the new
+                # agent's host shadow is the truth until its first
+                # upload.
+                slots = slots[~np.isin(slots, list(churn.entities))]
+            eng.absorb_census(slots, pos, vel, state, target)
+            ids = eng.agent_ids(slots)
+            # Last-known positions of EVERY agent, as the rows they are
+            # (engine-only agents have no channel path to refresh
+            # them); the authority commit below re-walks channel-backed
+            # ones through the ordinary update path, which keeps the
+            # same rows authoritative.
+            agent_pos = pos[slots]
+            self.controller._last_positions.absorb_census(
+                slots, ids, agent_pos)
         self._since_census = 0
         metrics.sim_census_transfers.inc()
         self._count("census_transfers", 1)
         sim_tick = int(result.get("sim_tick", eng.sim_tick))
         if _wal.enabled:
-            _wal.log_sim_census(
-                sim_tick, eng.sim_seed, ids, pos[slots], vel[slots],
-                state[slots], target[slots],
-            )
+            with _trace.region("sim_census.journal", stage=True):
+                _wal.log_sim_census(
+                    sim_tick, eng.sim_seed, ids, agent_pos, vel[slots],
+                    state[slots], target[slots],
+                )
             self._count("censuses_journaled", 1)
-        # Refresh last-known positions for EVERY agent (engine-only
-        # agents have no channel path to do it); the authority commit
-        # below re-walks channel-backed ones through the ordinary
-        # update path, which keeps the same rows authoritative. The
-        # arrays are host numpy at this point — tolist() shapes, it
-        # does not transfer.
-        ctl = self.controller
-        agent_pos = pos[slots].tolist()
-        for i, eid in enumerate(ids):
-            px, py, pz = agent_pos[i]
-            ctl._last_positions[int(eid)] = SpatialInfo(px, py, pz)
-        committed = self.authority.commit(ids, agent_pos)
+        with _trace.region("sim_census.commit", stage=True):
+            committed = self.authority.commit(ids, agent_pos)
         self._count("census_commits", committed)
         metrics.sim_agents_num.set(eng.agent_count())
         metrics.sim_pass_ms.observe((time.monotonic() - t0) * 1000.0)

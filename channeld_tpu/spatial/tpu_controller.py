@@ -39,6 +39,7 @@ from ..core.tracing import recorder as _trace
 from ..utils.logger import get_logger
 from .controller import SpatialInfo, register_spatial_controller_type
 from .grid import StaticGrid2DSpatialController
+from .last_positions import LastPositions
 
 logger = get_logger("spatial.tpu")
 
@@ -63,7 +64,9 @@ class TPUSpatialController(StaticGrid2DSpatialController):
         # entity id -> provider returning the notifying entity id, captured
         # from the most recent position update (used at batch-detect time).
         self._providers: dict[int, Callable[[int, int], Optional[int]]] = {}
-        self._last_positions: dict[int, SpatialInfo] = {}
+        # Wire entities as dict entries, simulated agents as the rows of
+        # their last census (spatial/last_positions.py).
+        self._last_positions = LastPositions()
         # Position before the latest update — the TRUE old position for
         # handover orchestration (logic like the reference's position-delta
         # check, pkg/unreal/handover.go:8-47, needs real coordinates, not
@@ -177,6 +180,7 @@ class TPUSpatialController(StaticGrid2DSpatialController):
             cell_bucket=int(config.get("CellBucket", 0)),
             query_rows_max=global_settings.queryplane_rows_max,
         )
+        self._last_positions.bind(self.engine)
         if global_settings.queryplane_enabled:
             from .queryplane import QueryPlane
 
@@ -322,8 +326,10 @@ class TPUSpatialController(StaticGrid2DSpatialController):
         self._last_positions[entity_id] = info
 
     def untrack_entity(self, entity_id: int) -> None:
-        self.engine.remove_entity(entity_id)
+        # Before the engine forgets the slot: an agent's row is found
+        # through it.
         self._last_positions.pop(entity_id, None)
+        self.engine.remove_entity(entity_id)
         self._prev_positions.pop(entity_id, None)
         self._providers.pop(entity_id, None)
         self._deferred_crossings.pop(entity_id, None)

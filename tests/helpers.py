@@ -118,3 +118,11 @@ def fresh_runtime():
     init_message_map()
     channel_mod.init_channels()
     return channel_mod.get_global_channel()
+
+
+def stage_count(stage: str) -> float:
+    """Observations of one ``tick_stage_ms`` stage so far."""
+    from channeld_tpu.core import metrics
+
+    child = metrics.tick_stage_ms.labels(stage=stage)
+    return sum(b.get() for b in child._buckets)

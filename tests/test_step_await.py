@@ -42,7 +42,7 @@ from channeld_tpu.spatial.controller import (
 from channeld_tpu.spatial.tpu_controller import TPUSpatialController
 
 import test_device_guard as tdg
-from helpers import StubConnection, fresh_runtime
+from helpers import StubConnection, fresh_runtime, stage_count
 
 E = tdg.ENTITY_START
 
@@ -78,11 +78,6 @@ class Held:
         self.entered.set()
         assert self.release.wait(10.0)
         return self._run(batch)
-
-
-def stage_count(stage: str) -> float:
-    child = metrics.tick_stage_ms.labels(stage=stage)
-    return sum(b.get() for b in child._buckets)
 
 
 def world_with_entity():
@@ -607,6 +602,10 @@ def test_the_task_path_and_the_direct_path_decide_alike():
 
 
 def test_the_awaited_interval_is_not_the_global_ticks_cost():
+    """Held by what is charged against what was awaited, on one clock:
+    the tick's wall time is its loop-thread time plus the await, and
+    only the first is the tick's cost. No absolute time is held: under
+    a loaded machine the loop-thread part is as long as it is."""
     async def scenario():
         gch = new_runtime()
         gch._tick_task.cancel()
@@ -619,7 +618,10 @@ def test_the_awaited_interval_is_not_the_global_ticks_cost():
         governor.note_tick = lambda elapsed, interval: (
             utils.append(elapsed), note_tick(elapsed, interval))[1]
         duration = metrics.channel_tick_duration.labels(channel_type="GLOBAL")
+        step_ms = metrics.tick_stage_ms.labels(stage="device_step")
+        await_ms = metrics.tick_stage_ms.labels(stage="step.await")
         sum0, count0 = duration._sum.get(), stage_count("device_step")
+        step0, await0 = step_ms._sum.get(), await_ms._sum.get()
         anomalies0 = len(recorder.anomalies)
         held = Held(ctl.engine)
         asyncio.get_running_loop().call_later(0.2, held.release.set)
@@ -629,16 +631,24 @@ def test_the_awaited_interval_is_not_the_global_ticks_cost():
         governor.note_tick = note_tick
         assert wall >= 0.2
         assert stage_count("device_step") == count0 + 1
-        assert duration._sum.get() - sum0 < 0.1
-        assert utils and max(utils) < 0.1
-        assert [a for a in recorder.anomalies[anomalies0:]
-                if a["trigger"] == "tick_budget"] == []
         # device_step is the step from outside, the wait included; the
         # await is on the record beside it.
-        step_ms = metrics.tick_stage_ms.labels(stage="device_step")
-        await_ms = metrics.tick_stage_ms.labels(stage="step.await")
-        assert await_ms._sum.get() >= 190.0
-        assert step_ms._sum.get() >= await_ms._sum.get()
+        awaited = (await_ms._sum.get() - await0) / 1e3
+        assert awaited >= 0.19
+        assert step_ms._sum.get() - step0 >= awaited * 1e3
+        # What the tick is charged is what is left of its wall time once
+        # the await is taken out, to the histogram and to the governor
+        # alike: the awaited seconds were other channels' ticks.
+        on_loop = wall - awaited
+        charged = duration._sum.get() - sum0
+        assert 0 < charged <= on_loop + 1e-6
+        # (The notes before the last are the other channels' ticks, made
+        # during the await.)
+        assert utils and utils[-1] == pytest.approx(charged)
+        assert charged + awaited <= wall + 1e-6
+        if on_loop < gch.tick_interval:
+            assert [a for a in recorder.anomalies[anomalies0:]
+                    if a["trigger"] == "tick_budget"] == []
 
     asyncio.run(scenario())
 
