@@ -1,10 +1,14 @@
 """Channel runtime: registry, id spaces, tick loop, broadcast.
 
 Capability parity with the reference channel layer (ref: pkg/channeld/channel.go).
-Where the reference runs a goroutine per channel, we run an asyncio task per
-channel; all channel state is only touched from that task (or from the
-synchronous ``tick_once`` used by tests with a synthetic clock), preserving
-the reference's single-writer discipline without locks.
+Where the reference runs a goroutine per channel that ticks every
+interval, one asyncio task (``TickScheduler``) ticks every channel but
+GLOBAL when, and only when, it has work: a message, a fan-out window
+that holds an owed update and has closed, a duty listed there. GLOBAL
+keeps a task of its own, whose tick awaits the device step. All channel
+state is only touched from the task that ticks it (or from the
+synchronous ``tick_once`` used by tests with a synthetic clock),
+preserving the reference's single-writer discipline without locks.
 
 Id spaces (ref: settings.go:94-95, channel.go:218-253): GLOBAL = 0,
 non-spatial 1..spatial_start-1, spatial spatial_start..entity_start-1,
@@ -16,6 +20,7 @@ from __future__ import annotations
 import asyncio
 import time
 from collections import deque
+from heapq import heappop, heappush
 from dataclasses import dataclass
 from enum import IntEnum
 from typing import Callable, Optional
@@ -25,7 +30,13 @@ from ..protocol import control_pb2
 from ..utils.idalloc import IdAllocator
 from ..utils.logger import get_logger
 from . import events, metrics
-from .data import ChannelData, FanOutConnection, tick_data, window_lag_ns
+from .data import (
+    ChannelData,
+    FanOutConnection,
+    tick_data,
+    window_lag_ns,
+    windows_skipped,
+)
 from .data import (
     reflect_channel_data_message,
     _channel_data_extension_registry,
@@ -45,6 +56,14 @@ logger = get_logger("channel")
 _MessageContext = None
 _connection_mod = None
 
+
+def _connection():
+    global _connection_mod
+    if _connection_mod is None:
+        from . import connection as _connection_mod
+    return _connection_mod
+
+
 # Channels whose in-queues are above the high watermark. A reactor pauses
 # reading from a connection only while a channel *that connection* fed is
 # congested — the asyncio analog of the reference's blocking
@@ -57,20 +76,24 @@ _HIGH_WATERMARK = QUEUE_CAPACITY * 3 // 4
 _LOW_WATERMARK = QUEUE_CAPACITY // 4
 
 
+# Labels never change: resolved once, not once a tick.
+_m_fanout_decision = metrics.fanout_decision_latency.labels(backend="host")
+
 # How long work waited for the event loop: [seconds late, ticks] by
-# channel type. Every tick loop adds into its type's pair (a subtraction
-# and two adds a tick); the GLOBAL tick carries the pairs, and
-# core/data.py's fan-out window lag and core/tracing.py's collector
-# pauses, to /metrics.
+# channel type. GLOBAL's tick loop and the scheduler add into the type's
+# pair (a subtraction and two adds a tick); the GLOBAL tick carries the
+# pairs, the scheduler's ticks by cause, core/data.py's fan-out window
+# lag and skipped windows and core/tracing.py's collector pauses to
+# /metrics.
 _tick_late: dict = {t: [0.0, 0] for t in ChannelType}
 
 
 def _flush_wait_counters() -> None:
-    """``tick_late_ms``, ``fanout_window_lag_ms`` and ``gc_pause_ms``,
-    once per GLOBAL tick: a registry call for each of ~7,000 channel
-    ticks a second would sit on the thread that is the bottleneck, and
-    one from inside the collector could wait for a lock its own thread
-    holds."""
+    """``tick_late_ms``, ``fanout_window_lag_ms``, ``channel_ticks``,
+    ``fanout_windows_skipped`` and ``gc_pause_ms``, once per GLOBAL
+    tick: a registry call for each of some thousand channel ticks a
+    second would sit on the thread that is the bottleneck, and one from
+    inside the collector could wait for a lock its own thread holds."""
     for pairs, metric, to_ms in (
         (_tick_late, metrics.tick_late_ms, 1e3),  # kept in seconds
         (window_lag_ns, metrics.fanout_window_lag_ms, 1e-6),
@@ -80,6 +103,16 @@ def _flush_wait_counters() -> None:
                 metric.labels(channel_type=ctype.name).add(
                     acc[0] * to_ms, acc[1])
                 acc[0], acc[1] = 0, 0
+    for ctype, n in windows_skipped.items():
+        if n:
+            metrics.fanout_windows_skipped.labels(
+                channel_type=ctype.name).inc(n)
+            windows_skipped[ctype] = 0
+    ticks = scheduler.ticks
+    for (ctype, cause), n in ticks.items():
+        metrics.channel_ticks.labels(
+            channel_type=ctype.name, cause=cause).inc(n)
+    ticks.clear()
     flush_gc_pauses()
 
 
@@ -154,7 +187,7 @@ class Channel:
         # Unbounded deque with the asyncio.Queue method surface; the
         # external-put bound (QUEUE_CAPACITY) is enforced in _enqueue so
         # internal puts keep a reserve. A plain deque because nothing ever
-        # awaits it (the tick loop wakes via _wake) and asyncio.Queue's
+        # awaits it (_enqueue tells the scheduler) and asyncio.Queue's
         # put/get bookkeeping was measurable at load-test rates.
         self.in_msg_queue: _MsgQueue = _MsgQueue()
         self.fan_out_queue: list[FanOutConnection] = []
@@ -180,8 +213,11 @@ class Channel:
         self._m_tick_duration = metrics.channel_tick_duration.labels(
             channel_type=self.channel_type.name
         )
+        # GLOBAL alone owns a tick task and the event that ends its
+        # park; every other channel is the scheduler's.
         self._tick_task: Optional[asyncio.Task] = None
-        self._wake = asyncio.Event()
+        self._wake = (asyncio.Event()
+                      if self.channel_type == ChannelType.GLOBAL else None)
         self._writer_task = None  # single-writer affinity (dev assertion)
         self.state = ChannelState.OPEN if self.has_owner() else ChannelState.INIT
 
@@ -240,6 +276,10 @@ class Channel:
             # Direct init_data callers (entity spawn paths, federation
             # adoption) bypass the message queue: mark here too.
             _wal.note_dirty(self.id)
+        if self.subscribed_connections:
+            # Subscribers who came before the data await their first
+            # fan-out, and no message says so.
+            self.note_work(self.get_time())
 
     def get_data_message(self):
         return self.data.msg if self.data else None
@@ -309,12 +349,9 @@ class Channel:
                 # (ref: message.go:72-80).
                 return
             owner.send_queue.extend(entries)
-            global _connection_mod
-            if _connection_mod is None:
-                from . import connection as _connection_mod
             # Resolve the set through the module: drain_pending_flush
             # swaps in a fresh set every pump cycle.
-            _connection_mod._pending_flush.add(owner)
+            _connection()._pending_flush.add(owner)
             if _slo.enabled and ingest_ns:
                 # The batched fast path's delivery point: the run just
                 # landed on the owner's send queue (flushed this pump
@@ -386,7 +423,10 @@ class Channel:
             self._mark_congested(qm)
             return False
         self.in_msg_queue.append(qm)
-        self._wake.set()
+        if self._wake is None:
+            scheduler.note_message(self)
+        else:
+            self._wake.set()
         if size + 1 >= _HIGH_WATERMARK:
             self._mark_congested(qm)
         return True
@@ -401,47 +441,54 @@ class Channel:
             if pending is None:
                 pending = conn.backpressure_channels = set()
             pending.add(self.id)
+        # Lifted by a tick (``_tick_messages``), so the channel has work
+        # whether or not anything was queued.
+        self.note_work()
 
     # ---- tick ------------------------------------------------------------
 
     def start_ticking(self) -> None:
-        if self._tick_task is None:
+        """GLOBAL's own tick task; the one scheduler task for the rest."""
+        scheduler.start()
+        if self._wake is not None and self._tick_task is None:
             self._tick_task = asyncio.ensure_future(self._tick_loop())
-            self._tick_task.add_done_callback(self._on_tick_task_done)
+            self._tick_task.add_done_callback(_on_tick_task_done)
 
-    def _on_tick_task_done(self, task: asyncio.Task) -> None:
-        if task.cancelled():
-            return
-        exc = task.exception()
-        if exc is not None:
-            self.logger.error("channel tick task died: %r", exc)
-
-    def wake(self) -> None:
-        """Wake a parked tick loop (new message, subscription, ...)."""
-        self._wake.set()
+    def note_work(self, due_ns: Optional[int] = None) -> None:
+        """Something done outside this channel's own tick gave it work:
+        a fan-out that falls due at ``due_ns`` (channel time: a
+        subscription made or changed, data set where subscribers wait),
+        or a duty from now on (None: a recoverable subscription
+        staged)."""
+        if self._wake is not None:
+            self._wake.set()
+        elif due_ns is None:
+            scheduler.note(self, time.monotonic(), _HOUSEKEEPING)
+        else:
+            scheduler.note(
+                self, (self.start_ns + due_ns) * 1e-9 + _TIMER_SLACK_S,
+                _WINDOW)
 
     def _may_park(self) -> bool:
+        """GLOBAL's task, the only one there is."""
         if (
             self.subscribed_connections
             or self.recoverable_subs
             or not self.in_msg_queue.empty()
         ):
             return False
-        if self.channel_type == ChannelType.GLOBAL:
-            # The GLOBAL tick drives the spatial controller (handover
-            # detection, server reaping): never park while one exists.
-            from ..spatial.controller import get_spatial_controller
+        # The GLOBAL tick drives the spatial controller (handover
+        # detection, server reaping): never park while one exists.
+        from ..spatial.controller import get_spatial_controller
 
-            if get_spatial_controller() is not None:
-                return False
-        return True
+        return get_spatial_controller() is None
 
     def _note_tick_start(self, tick_start: float,
                          due: Optional[float]) -> None:
         """Lateness of the tick that starts now against ``due``, the
-        start of the one before it plus the interval (``tick_late_ms``).
-        The first tick and one that follows a park have nothing to be
-        late against (``due`` None)."""
+        instant its work was ready (``tick_late_ms``): for GLOBAL the
+        start of the tick before it plus the interval, and nothing
+        (None) for its first tick and one that follows a park."""
         if due is not None:
             late = _tick_late[self.channel_type]
             if tick_start > due:
@@ -449,42 +496,43 @@ class Channel:
             late[1] += 1
 
     async def _tick_loop(self) -> None:
+        """GLOBAL's tick task: a tick every interval, because its tick
+        steps the device and has work every interval by definition."""
         due = None  # when this tick was due (loop clock); None after a park
-        is_global = self.channel_type == ChannelType.GLOBAL
         while not self.is_removing():
             tick_start = time.monotonic()
             self._note_tick_start(tick_start, due)
-            # tick_once observes the duration histogram and feeds the
+            # The tick observes the duration histogram and feeds the
             # overload governor's budget accounting.
-            if is_global:
-                await self._tick_global(self.get_time(), tick_start)
-            else:
-                self.tick_once(self.get_time(), tick_start)
+            await self._tick_global(self.get_time(), tick_start)
             elapsed = time.monotonic() - tick_start
             if not self._may_park():
                 due = tick_start + self.tick_interval
                 await asyncio.sleep(max(self.tick_interval - elapsed, 0))
             else:
                 due = None
-                # Idle channel: park until a message/subscription arrives
-                # (or a coarse heartbeat) instead of spinning at the tick
-                # cadence — 10K mostly-idle channels would otherwise wake
-                # 500K times per second.
+                # Nobody subscribed and no controller: park until a
+                # message/subscription arrives (or a coarse heartbeat).
                 self._wake.clear()
                 if self.in_msg_queue.empty() and self._may_park():
                     try:
                         await asyncio.wait_for(self._wake.wait(), timeout=0.5)
                     except asyncio.TimeoutError:
                         pass
-                # Pace even after a wake so a message stream to an idle
-                # channel can't drive ticks above 1/tick_interval.
+                # Pace even after a wake so a message stream can't drive
+                # ticks above 1/tick_interval.
                 await asyncio.sleep(
                     max(self.tick_interval - (time.monotonic() - tick_start), 0)
                 )
 
-    def tick_once(self, now: Optional[int] = None, tick_start: Optional[float] = None) -> None:
+    def tick_once(self, now: Optional[int] = None,
+                  tick_start: Optional[float] = None,
+                  ingest: bool = True) -> Optional[int]:
         """One synchronous tick; ``now`` is channel time, injectable for
-        tests (ref: channel.go:358-387)."""
+        tests (ref: channel.go:358-387). Returns ``tick_data``'s answer:
+        the channel time at which fan-out work falls due next, None for
+        none (the scheduler's timer). The scheduler flushes the deferred
+        ingest once a pass and passes ``ingest`` False."""
         now, tick_start = self._tick_prologue(now, tick_start)
         # The tick span closes after the governor update, so the overload
         # stage nests inside it (containment is how dumps reconstruct
@@ -499,9 +547,11 @@ class Channel:
         if profiling:
             with _trace.region(f"tick.{self.channel_type.name}",
                                lane=self.id):
-                self._tick_stages(now, tick_start, profiling)
+                next_due = self._tick_stages(now, tick_start, profiling,
+                                             ingest=ingest)
         else:
-            self._tick_stages(now, tick_start, profiling)
+            next_due = self._tick_stages(now, tick_start, profiling,
+                                         ingest=ingest)
             if _trace.enabled:
                 _trace.span(
                     f"tick.{self.channel_type.name}",
@@ -509,6 +559,7 @@ class Channel:
                 )
         if _trace.enabled and self.tick_interval > 0:
             self._note_tick_budget(tick_start)
+        return next_due
 
     async def _tick_global(self, now: int, tick_start: float) -> None:
         """The GLOBAL channel's tick from its own tick task: what
@@ -598,7 +649,8 @@ class Channel:
             )
 
     def _tick_stages(self, now: int, tick_start: float,
-                     profiling: bool, controller: bool = True) -> None:
+                     profiling: bool, controller: bool = True,
+                     ingest: bool = True) -> Optional[int]:
         if controller and self.channel_type == ChannelType.GLOBAL:
             # Spatial controller ticks with the GLOBAL channel only, to
             # keep a single writer (ref: channel.go:366-369). The tick
@@ -611,10 +663,8 @@ class Channel:
         # Deferred ingest runs land in the queue before it drains, so a
         # tick never misses traffic the per-read dispatch would have
         # delivered (also what keeps on_bytes + tick_once tests exact).
-        global _connection_mod
-        if _connection_mod is None:
-            from . import connection as _connection_mod
-        _connection_mod.flush_pending_ingest()
+        if ingest:
+            _connection().flush_pending_ingest()
         if self.in_msg_queue:
             if profiling:
                 with _trace.region("messages", lane=self.id, stage=True):
@@ -634,20 +684,16 @@ class Channel:
         else:
             self._tick_messages(tick_start)  # still lifts backpressure
         if not self.subscribed_connections:
-            tick_data(self, now)
+            next_due = tick_data(self, now)
         elif profiling:
             with _trace.region("fanout", lane=self.id, stage=True):
                 fanout_start = time.monotonic()
-                tick_data(self, now)
-                metrics.fanout_decision_latency.labels(
-                    backend="host"
-                ).observe(time.monotonic() - fanout_start)
+                next_due = tick_data(self, now)
+                _m_fanout_decision.observe(time.monotonic() - fanout_start)
         else:
             fanout_start = time.monotonic()
-            tick_data(self, now)
-            metrics.fanout_decision_latency.labels(backend="host").observe(
-                time.monotonic() - fanout_start
-            )
+            next_due = tick_data(self, now)
+            _m_fanout_decision.observe(time.monotonic() - fanout_start)
             _trace.stage("fanout", int(fanout_start * 1e9), lane=self.id)
         self._tick_connections()
         self._tick_recoverable_subscriptions()
@@ -690,6 +736,7 @@ class Channel:
                 # replica packs cell state in. Enqueue-only: the fsync
                 # lives on the WAL's writer thread.
                 _wal.on_global_tick()
+        return next_due
 
     def _tick_messages(self, tick_start: float) -> None:
         """Drain the queue within the tick budget (ref: channel.go:389-412).
@@ -748,10 +795,7 @@ class Channel:
         connection anywhere has closed since this channel's last scan
         (closes bump connection.close_epoch): the scan is idempotent and
         a 10K-subscriber sweep at the tick rate was pure fixed cost."""
-        global _connection_mod
-        if _connection_mod is None:
-            from . import connection as _connection_mod
-        epoch = _connection_mod.close_epoch
+        epoch = _connection().close_epoch
         if epoch == self._seen_close_epoch:
             return
         self._seen_close_epoch = epoch
@@ -866,6 +910,227 @@ class Channel:
         from ..spatial.entity import get_handover_entities
 
         return get_handover_entities(self, entity_id)
+
+
+# ---- the scheduler ----------------------------------------------------------
+
+# What made a channel ready (``channel_ticks{cause}``).
+_MESSAGE, _WINDOW, _HOUSEKEEPING = "message", "window", "housekeeping"
+# The pass yields to the loop after this much ticking, so that no
+# callback (GLOBAL's resume from the device step first) waits on it.
+_SLICE_S = 0.002
+# A timer aims this far past its instant: seconds are floats, channel
+# time is integer nanoseconds, and a tick that starts a rounding error
+# before its window's close finds it open and waits a whole interval.
+_TIMER_SLACK_S = 1e-6
+_NEVER = float("inf")
+
+
+def _on_tick_task_done(task: asyncio.Task) -> None:
+    if not task.cancelled() and task.exception() is not None:
+        logger.error("tick task died: %r", task.exception())
+
+
+class TickScheduler:
+    """One task ticks every channel but GLOBAL, when it has work.
+
+    A channel has work when its queue holds a message, when a fan-out
+    window that holds an owed update (or a first fan-out) has closed or
+    the device marked it due, when backpressure is to be lifted, a
+    closed subscriber pruned or a recoverable subscription served. Work
+    is one entry ``channel -> (ready_at, cause)``; an entry whose
+    instant lies ahead (a window's close, the pacing) waits in one heap
+    behind one ``call_at``. A channel with no work has no task, no
+    timer and no visit. Pacing: never more often than the channel's
+    tick interval, and at once where it has been idle longer.
+
+    Each visit is ``tick_once()``. ``tick_late_ms`` reads how long
+    ready work waited: the tick's start less ``ready_at``.
+    """
+
+    def __init__(self):
+        self.ticks: dict = {}  # (channel type, cause) -> ticks, to /metrics
+        self._work: dict = {}  # channel -> (ready_at, cause)
+        self._heap: list = []  # (ready_at, n, channel); stale entries linger
+        self._n = 0
+        self._last: dict = {}  # channel -> start of its last tick (pacing)
+        self._seen_close_epoch = 0
+        self._task: Optional[asyncio.Task] = None
+        self._wakeup: Optional[asyncio.Event] = None
+        self._timer: Optional[asyncio.TimerHandle] = None
+        # The instant the sleeping task's timer aims at: work noted for
+        # sooner re-arms it. Below every instant while a pass runs.
+        self._sleep_until = -_NEVER
+
+    # ---- intake (hot: every enqueue comes through note_message) ----------
+
+    def note_message(self, ch: "Channel") -> None:
+        entry = self._work.get(ch)
+        if entry is None or entry[1] is not _MESSAGE:
+            self.note(ch, time.monotonic(), _MESSAGE)
+
+    def note(self, ch: "Channel", at: float, cause: str) -> None:
+        """``ch`` has work from ``at`` on (loop clock), or from one tick
+        interval after its last tick if that is later."""
+        last = self._last.get(ch)
+        if last is not None:
+            at = max(at, last + ch.tick_interval)
+        entry = self._work.get(ch)
+        if entry is not None and entry[0] <= at:
+            return
+        self._work[ch] = (at, cause)
+        self._n += 1
+        heappush(self._heap, (at, self._n, ch))
+        if at < self._sleep_until:
+            self._arm(at)
+
+    def note_device_due(self, ch: "Channel") -> None:
+        """The device marked a subscription of ``ch`` due, and it is
+        owed something (spatial/tpu_controller.py ``_publish_due``)."""
+        self.note(ch, time.monotonic(), _WINDOW)
+
+    def wake(self) -> None:
+        """Something the next pass looks at moved (a connection closed)."""
+        if self._wakeup is not None:
+            self._wakeup.set()
+
+    def forget(self, ch: "Channel") -> None:
+        self._work.pop(ch, None)
+        self._last.pop(ch, None)
+
+    # ---- the pass --------------------------------------------------------
+
+    def next_at(self) -> float:
+        """The instant of the earliest work, ``inf`` for none."""
+        heap, work = self._heap, self._work
+        while heap:
+            at, _, ch = heap[0]
+            entry = work.get(ch)
+            if entry is not None and entry[0] == at:
+                return at
+            heappop(heap)  # superseded by an earlier instant, or forgotten
+        return _NEVER
+
+    async def run_due(self) -> int:
+        """Tick every channel whose work is ready, the longest ready
+        first, yielding to the loop after each ``_SLICE_S`` of ticking.
+        Returns the ticks made."""
+        conn_mod = _connection()
+        conn_mod.flush_pending_ingest()  # once a pass, not once a channel
+        now = time.monotonic()
+        if conn_mod.close_epoch != self._seen_close_epoch:
+            # A connection closed somewhere: every channel with
+            # subscribers has one to prune, perhaps (_tick_connections).
+            self._seen_close_epoch = conn_mod.close_epoch
+            for ch in _all_channels.values():
+                if ch.subscribed_connections and ch._wake is None:
+                    self.note(ch, now, _HOUSEKEEPING)
+        ticks = 0
+        slice_end = now + _SLICE_S
+        while self.next_at() <= now:
+            at, _, ch = heappop(self._heap)
+            cause = self._work.pop(ch)[1]
+            if ch.removing:
+                self.forget(ch)
+                continue
+            tick_start = time.monotonic()
+            if tick_start >= slice_end:
+                await asyncio.sleep(0)
+                now = tick_start = time.monotonic()
+                slice_end = now + _SLICE_S
+            self._tick(ch, at, cause, tick_start)
+            ticks += 1
+        return ticks
+
+    def _tick(self, ch: "Channel", ready_at: float, cause: str,
+              tick_start: float) -> None:
+        late = _tick_late[ch.channel_type]
+        if tick_start > ready_at:
+            late[0] += tick_start - ready_at
+        late[1] += 1
+        if ch.in_msg_queue:
+            cause = _MESSAGE
+        key = (ch.channel_type, cause)
+        self.ticks[key] = self.ticks.get(key, 0) + 1
+        self._last[ch] = tick_start
+        try:
+            next_due = ch.tick_once(ch.get_time(), tick_start, ingest=False)
+        except Exception:
+            # One channel's fault must not stop every channel's ticks.
+            ch.logger.exception("channel tick failed")
+            next_due = None
+        if ch.removing:
+            self.forget(ch)
+            return
+        # What is left, or lies ahead; ``note`` paces it.
+        if ch.in_msg_queue:
+            self.note(ch, tick_start, _MESSAGE)
+        elif ch.id in _congested_channels or ch.recoverable_subs:
+            self.note(ch, tick_start, _HOUSEKEEPING)
+        if next_due is not None:
+            self.note(ch, (ch.start_ns + next_due) * 1e-9 + _TIMER_SLACK_S,
+                      _WINDOW)
+
+    # ---- the task --------------------------------------------------------
+
+    def start(self) -> None:
+        """Called with a loop running (a channel was created in it)."""
+        loop = asyncio.get_running_loop()
+        if self._task is not None and self._task.get_loop() is loop:
+            return
+        self._wakeup = asyncio.Event()
+        self._wakeup.set()  # work noted before the loop ran
+        self._timer = None
+        self._task = loop.create_task(self._run())
+        self._task.add_done_callback(_on_tick_task_done)
+
+    def stop(self) -> None:
+        """Tests that tick by hand inside a loop."""
+        task, self._task, self._wakeup = self._task, None, None
+        self._sleep_until = -_NEVER
+        if task is not None and not task.get_loop().is_closed():
+            task.cancel()
+
+    def reset(self) -> None:
+        self.stop()
+        self.ticks.clear()
+        self._work.clear()
+        self._heap.clear()
+        self._last.clear()
+        self._seen_close_epoch = _connection().close_epoch
+
+    def _arm(self, until: float) -> None:
+        if self._timer is not None:
+            self._timer.cancel()
+            self._timer = None
+        self._sleep_until = until
+        if until <= time.monotonic():
+            # Ready now (a message to an idle channel): no trip through
+            # the loop's timers, and nothing sooner to re-arm for.
+            self._sleep_until = -_NEVER
+            self._wakeup.set()
+        elif until < _NEVER:
+            # The loop's clock is time.monotonic.
+            self._timer = self._task.get_loop().call_at(
+                until, self._wakeup.set)
+
+    async def _run(self) -> None:
+        wakeup = self._wakeup
+        while True:
+            self._sleep_until = -_NEVER  # a pass runs: nothing to re-arm
+            try:
+                await self.run_due()
+            except Exception:
+                logger.exception("tick scheduler pass failed")
+            if self.next_at() <= time.monotonic():
+                await asyncio.sleep(0)  # between passes, as within one
+                continue
+            wakeup.clear()
+            self._arm(self.next_at())
+            await wakeup.wait()
+
+
+scheduler = TickScheduler()
 
 
 # ---- registry -----------------------------------------------------------
@@ -1007,6 +1272,7 @@ def remove_channel(ch: Channel) -> None:
     if ch._tick_task is not None:
         ch._tick_task.cancel()
         ch._tick_task = None
+    scheduler.forget(ch)
     # A removed channel can never drain: lift its backpressure now or the
     # reactors that fed it would wait forever.
     _congested_channels.discard(ch.id)
@@ -1043,5 +1309,8 @@ def reset_channels() -> None:
             ch._tick_task.cancel()
     _all_channels.clear()
     _global_channel = None
+    scheduler.reset()
     for acc in (*_tick_late.values(), *window_lag_ns.values()):
         acc[0], acc[1] = 0, 0
+    for ctype in windows_skipped:
+        windows_skipped[ctype] = 0

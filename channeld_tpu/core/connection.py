@@ -749,6 +749,9 @@ class Connection:
         self.state = ConnectionState.CLOSING
         global close_epoch
         close_epoch += 1  # channels' prune scans key off this
+        from .channel import scheduler
+
+        scheduler.wake()  # ... in the pass this starts
         try:
             self.transport.close()
         except Exception:

@@ -268,6 +268,7 @@ def stage_recovery_handle(
             old_sub_time=now,
             old_sub_options=opts,
         )
+        ch.note_work()  # looked at every interval until it is resolved
     return handle
 
 

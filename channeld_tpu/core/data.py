@@ -163,6 +163,11 @@ class ChannelData:
         # adopting update must match it.
         self.channel_type = channel_type
         self.update_msg_buffer: list[UpdateBufferElement] = []
+        # The ring's arrival times, element for element: what every
+        # fan-out window is bisected in (tick_data), kept in step with
+        # the ring by its one writer (on_update) instead of being read
+        # off 512 elements in every tick that owes something.
+        self.update_arrivals: list[int] = []
         self.accumulated_update_msg: Optional[Message] = (
             type(msg)() if msg is not None else None
         )
@@ -231,6 +236,7 @@ class ChannelData:
             UpdateBufferElement(update_msg, arrival_time, sender_conn_id,
                                 self.msg_index, ingest_ns)
         )
+        self.update_arrivals.append(arrival_time)
         if len(self.update_msg_buffer) > MAX_UPDATE_MSG_BUFFER_SIZE:
             oldest = self.update_msg_buffer[0]
             # Only drop it once every subscriber must have seen it. Under
@@ -243,6 +249,7 @@ class ChannelData:
                 retention_ns = int(retention_ns * _governor.fanout_stretch())
             if oldest.arrival_time + retention_ns < arrival_time:
                 self.update_msg_buffer.pop(0)
+                self.update_arrivals.pop(0)
                 if oldest.arrival_time > self.evicted_through:
                     self.evicted_through = oldest.arrival_time
 
@@ -309,32 +316,74 @@ def _device_due_view(channel: "Channel"):
 # How far behind its window a subscription is when it is served:
 # [ns behind, services] by channel type, added to by tick_data and
 # carried to ``fanout_window_lag_ms`` once per GLOBAL tick
-# (core/channel.py ``_flush_wait_counters``).
+# (core/channel.py ``_flush_wait_counters``); beside it the whole empty
+# windows tick_data closed by arithmetic (``fanout_windows_skipped``).
 window_lag_ns: dict = {t: [0, 0] for t in ChannelType}
+windows_skipped: dict = {t: 0 for t in ChannelType}
 
 
-def tick_data(channel: "Channel", now: int) -> None:
+def _owed_close(arrivals: list, last: int, interval_ns: int) -> int:
+    """Close of the window ``[last + k*I, last + (k+1)*I]`` that holds
+    the oldest buffered update at or after ``last`` (there is one). An
+    update ON a boundary belongs to the window that closes there too:
+    it goes out twice, as data.go:230-258."""
+    behind = arrivals[bisect_left(arrivals, last)] - last - 1
+    return last + (max(behind, 0) // interval_ns + 1) * interval_ns
+
+
+def mark_has_work(channel: "Channel", slot: int) -> bool:
+    """Whether a device due mark on ``slot`` is worth a tick of its
+    channel: the subscription awaits its first fan-out, or something
+    was buffered at or after its last one (an eviction left newer
+    updates behind it too). A mark that finds neither costs its dict
+    entry in the controller's pending table and nothing else."""
+    data = channel.data
+    foc = channel.device_sub_slots.get(slot)
+    if foc is None or data is None or data.msg is None:
+        return False
+    if not foc.had_first_fanout:
+        return True
+    arrivals = data.update_arrivals
+    return bool(arrivals) and arrivals[-1] >= foc.last_fanout_time
+
+
+def tick_data(channel: "Channel", now: int) -> Optional[int]:
     """The per-tick fan-out decision + send loop (ref: data.go:175-291).
 
     ``now`` is channel time (integer ns since channel start) so tests can
     drive it with a synthetic clock.
 
+    Channels are ticked when they have work, not every interval
+    (core/channel.py ``TickScheduler``), so a window nothing arrived in
+    is closed here by arithmetic: before a subscription past its first
+    fan-out is served, ``last_fanout_time`` moves over the whole empty
+    windows between it and the oldest update still owed (or ``now``,
+    owed nothing), staying on the lattice ``last + k * interval``. What
+    goes out, and no sooner than its window's close, is what a tick
+    every interval would have sent. Returns the channel time at which a
+    window that holds an owed update (or a first fan-out) closes next,
+    None when nothing is owed: the scheduler's timer.
+
     Spatial channels under a TPU controller consume the batched device due
     mask: only subscribers the engine marked due are visited (flat host
     cost in subscriber count); subscriptions without a device slot — table
-    full or pre-engine — keep the host time check.
+    full or pre-engine — keep the host time check. A mark is a trigger,
+    not a window: one that arrives before the host's window has closed
+    (a stale mark met by a fresh update, a brownout stretch) is kept
+    until it has.
     """
     data = channel.data
     if data is None or data.msg is None:
-        return
+        return None
 
     # Buffered updates arrive in channel-time order, so each subscriber's
     # inclusive [last, last+interval] window (the reference's bounds,
     # boundary elements delivered twice like data.go:230-258) is a
     # contiguous slice — O(log B) to locate instead of scanning the whole
-    # ring per subscriber. Built lazily: ticks with no due subscriber pay
-    # nothing.
-    arrivals = None
+    # ring per subscriber.
+    buffer = data.update_msg_buffer
+    arrivals = data.update_arrivals
+    newest = arrivals[-1] if arrivals else -1
     # Subscribers sharing the same window slice get the same accumulated
     # message unless skip-self excludes one of their own updates from it:
     # (lo, hi) -> [sender_id_set, merged_msg_or_None]. Scoped to this
@@ -350,7 +399,8 @@ def tick_data(channel: "Channel", now: int) -> None:
     stretch = _governor.fanout_stretch() if _governor.level else 1.0
     shed_floor = _governor.shed_priority_floor() if _governor.level else None
 
-    lag_ns = served_late = 0  # this tick's share of window_lag_ns
+    lag_ns = served_late = skipped = 0  # this tick's share of the counters
+    next_due = None
     queue = channel.fan_out_queue
     device = _device_due_view(channel)
     if device is not None:
@@ -389,46 +439,64 @@ def tick_data(channel: "Channel", now: int) -> None:
         interval_ns = cs.options.fanOutIntervalMs * NS_PER_MS
         if stretch != 1.0:
             interval_ns = int(interval_ns * stretch)
-        next_fanout_time = window_due = foc.last_fanout_time + interval_ns
-        if device is None or foc.device_sub_slot is None:
-            # Host time check (no engine, or no device slot for this sub).
-            if now < next_fanout_time:
-                continue
-        else:
-            # The device already decided this sub is due. Under a
-            # brownout stretch the governor overrides the device's
-            # cadence: hold the fan-out until the stretched interval
-            # elapses (the engine re-marks the sub due next window, so
-            # nothing is starved — just coalesced harder).
-            if stretch != 1.0 and now < next_fanout_time:
-                continue
-            # The engine clock can run marginally ahead of this
-            # channel's; clamp the window end so the bisect below never
-            # claims unseen future arrivals.
-            next_fanout_time = min(next_fanout_time, now)
-
-        if (
+        # Marked due by the device (else: the host time check — no
+        # engine, or no device slot for this sub).
+        marked = device is not None and foc.device_sub_slot is not None
+        last = foc.last_fanout_time
+        shed = (
             shed_floor is not None
             and cs.priority >= shed_floor
             and foc.had_first_fanout
-        ):
+        )
+        # Ring gap: updates this subscriber never saw were evicted
+        # (it was held past the retention horizon — e.g. the L2+
+        # priority shed).
+        resync = (
+            foc.had_first_fanout
+            and data.evicted_through > 0
+            and last <= data.evicted_through
+        )
+        owed = not foc.had_first_fanout or newest >= last
+        if foc.had_first_fanout and interval_ns > 0 and not (shed or resync):
+            # Whole empty windows close by arithmetic: up to the window
+            # that holds the oldest update owed, or up to ``now``.
+            if owed:
+                start = _owed_close(arrivals, last, interval_ns) - interval_ns
+            else:
+                start = last + max(now - last, 0) // interval_ns * interval_ns
+            if start > last:
+                skipped += (start - last) // interval_ns
+                foc.last_fanout_time = last = start
+        next_fanout_time = last + interval_ns
+        closed = now >= next_fanout_time
+        if shed and closed:
             # Shed: a DUE delivery is withheld while the ladder holds
             # (first fan-out still goes out so fresh subs handshake) —
             # one count per withheld delivery. The window keeps
             # accumulating from last_fanout_time; delivery resumes,
             # coalesced, once the ladder releases.
             _governor.count_shed("update_priority")
+        if shed or not closed:
+            # Not served now. The device's clock can run ahead of this
+            # channel's, its cadence is not the brownout's, and a mark
+            # left by an empty window meets the next update early: what
+            # is owed keeps its trigger, and the close is the channel's
+            # timer (a close that has passed: its next tick).
+            if owed:
+                if marked:
+                    pending[foc.device_sub_slot] = seq
+                if next_due is None or next_fanout_time < next_due:
+                    next_due = next_fanout_time
             continue
 
         latest_fanout_time = next_fanout_time
 
         if foc.had_first_fanout:
-            # Served now, ``now - window_due`` after its window closed:
-            # the window moves on one interval a service, so a
-            # subscription served less often than its interval carries
-            # this lag forward and grows it.
-            if now > window_due:
-                lag_ns += now - window_due
+            # Served now, ``now - next_fanout_time`` after its window
+            # closed: the window moves on one interval a service, so a
+            # subscription with an update in each window, served less
+            # often than its interval, carries this lag forward.
+            lag_ns += now - next_fanout_time
             served_late += 1
         if not foc.had_first_fanout:
             # First fan-out carries the full channel state.
@@ -436,31 +504,23 @@ def tick_data(channel: "Channel", now: int) -> None:
             foc.had_first_fanout = True
             foc.last_message_index = data.msg_index
             latest_fanout_time = now
-            if device is not None and foc.device_sub_slot is not None:
+            if marked:
                 # Mirror the window snap on the device sub clock.
                 ctl.device_sub_first_fanout(foc.device_sub_slot)
-        elif (
-            data.evicted_through > 0
-            and foc.last_fanout_time <= data.evicted_through
-        ):
-            # Ring gap: updates this subscriber never saw were evicted
-            # (it was held past the retention horizon — e.g. the L2+
-            # priority shed). Deltas can't reconstruct its view, so
-            # resync with full state — this is what keeps the brownout
-            # lossless at the STATE level no matter how long the hold.
+        elif resync:
+            # Deltas can't reconstruct its view, so resync with full
+            # state — this is what keeps the brownout lossless at the
+            # STATE level no matter how long the hold.
             fan_out_data_update(channel, conn, cs, data.msg, body_cache)
             foc.last_message_index = data.msg_index
             latest_fanout_time = now
-        elif data.update_msg_buffer:
-            if arrivals is None:
-                arrivals = [be.arrival_time for be in data.update_msg_buffer]
-            last_update_time = max(foc.last_fanout_time, 0)
-            lo = bisect_left(arrivals, last_update_time)
+        elif owed:
+            lo = bisect_left(arrivals, max(last, 0))
             hi = bisect_right(arrivals, next_fanout_time)
             entry = shared_windows.get((lo, hi))
             if entry is None:
                 entry = shared_windows[(lo, hi)] = [
-                    {be.sender_conn_id for be in data.update_msg_buffer[lo:hi]},
+                    {be.sender_conn_id for be in buffer[lo:hi]},
                     None,
                     False,  # delivery-SLO sample taken for this window
                 ]
@@ -468,7 +528,7 @@ def tick_data(channel: "Channel", now: int) -> None:
                 # This subscriber's own update is in the slice: accumulate
                 # its personal window with the self-updates excluded.
                 window = [
-                    be for be in data.update_msg_buffer[lo:hi]
+                    be for be in buffer[lo:hi]
                     if be.sender_conn_id != conn.id
                 ]
                 if window:
@@ -487,24 +547,20 @@ def tick_data(channel: "Channel", now: int) -> None:
                         )
                     if _slo.enabled:
                         _record_window_delivery(
-                            channel, window,
-                            "device" if device is not None
-                            and foc.device_sub_slot is not None
-                            else "host",
-                        )
+                            channel, window, "device" if marked else "host")
             elif hi > lo:
                 # Shared path: merge the slice once, reuse for every
                 # subscriber with this exact window. The cached message
                 # outlives this iteration, so it gets its own object
                 # rather than the per-sub scratch accumulator.
                 if entry[1] is None:
-                    window = data.update_msg_buffer[lo:hi]
+                    window = buffer[lo:hi]
                     entry[1] = (
                         window[0].update_msg
                         if len(window) == 1
                         else _accumulate_window(data, window, fresh=True)
                     )
-                foc.last_message_index = data.update_msg_buffer[hi - 1].message_index
+                foc.last_message_index = buffer[hi - 1].message_index
                 fan_out_data_update(channel, conn, cs, entry[1], body_cache)
                 if _slo.enabled and not entry[2]:
                     # ONE sample per distinct window per tick, however
@@ -512,24 +568,35 @@ def tick_data(channel: "Channel", now: int) -> None:
                     # first deliverer's path labels it).
                     entry[2] = True
                     _record_window_delivery(
-                        channel, data.update_msg_buffer[lo:hi],
-                        "device" if device is not None
-                        and foc.device_sub_slot is not None
-                        else "host",
-                    )
+                        channel, buffer[lo:hi],
+                        "device" if marked else "host")
 
-        foc.last_fanout_time = latest_fanout_time
+        foc.last_fanout_time = last = latest_fanout_time
+        if newest >= last and interval_ns > 0:
+            # Still owed. A marked subscription waits for the device's
+            # next mark, unless the window that holds it has closed
+            # already (marks coalesce in the pending table: the host's
+            # window must not fall behind for it).
+            close = _owed_close(arrivals, last, interval_ns)
+            if not marked or close <= now:
+                if marked:
+                    pending[foc.device_sub_slot] = seq
+                if next_due is None or close < next_due:
+                    next_due = close
 
     if served_late:
         lag = window_lag_ns[channel.channel_type]
         lag[0] += lag_ns
         lag[1] += served_late
+    if skipped:
+        windows_skipped[channel.channel_type] += skipped
     # Keep the queue ordered by last_fanout_time (the reference maintains
     # this invariant with in-place move-to-back; a stable sort is the same
     # end state). Device mode doesn't iterate the queue, so its order is
     # re-established lazily if the engine ever goes away.
     if device is None:
         queue.sort(key=lambda f: f.last_fanout_time)
+    return next_due
 
 
 def fan_out_data_update(

@@ -722,10 +722,31 @@ tick_stage_ms = Histogram(
 )
 tick_late_ms = SumCount(
     "tick_late_ms",
-    "How long after it was due (the tick before it plus the channel's "
-    "tick interval) a channel tick started, milliseconds, by channel "
-    "type: the time work waited for the event loop. A tick that follows "
-    "a park is late against nothing and is not counted",
+    "How long after its work was ready a channel tick started, "
+    "milliseconds, by channel type: the time ready work waited for the "
+    "event loop. GLOBAL is due one tick interval after its last tick "
+    "began (its first tick and one after a park are not counted); every "
+    "other channel when its message was enqueued or its fan-out window "
+    "closed, or one tick interval after its last tick if that is later",
+    ["channel_type"],
+    registry=registry,
+)
+channel_ticks = Counter(
+    "channel_ticks",
+    "Channel ticks the scheduler made (every type but GLOBAL), by what "
+    "made the channel ready: message (its queue held one), window (a "
+    "fan-out window that holds an owed update closed, or the device "
+    "marked it due), housekeeping (backpressure to lift, a closed "
+    "subscriber to prune, a recoverable subscription, a new "
+    "subscriber); the first that applied",
+    ["channel_type", "cause"],
+    registry=registry,
+)
+fanout_windows_skipped = Counter(
+    "fanout_windows_skipped",
+    "Whole fan-out windows nothing arrived in, closed by arithmetic "
+    "when their subscription was next served instead of by a tick "
+    "each, by channel type",
     ["channel_type"],
     registry=registry,
 )

@@ -89,6 +89,8 @@ def subscribe_to_channel(
                 if (ch.data is not None and
                         ch.data.max_fanout_interval_ms < cs.options.fanOutIntervalMs):
                     ch.data.max_fanout_interval_ms = cs.options.fanOutIntervalMs
+                # Its window may close sooner than the channel's timer.
+                ch.note_work(ch.get_time())
         return cs, data_access_changed
 
     merged = default_sub_options(ch.channel_type)
@@ -113,11 +115,9 @@ def subscribe_to_channel(
         ch.data.max_fanout_interval_ms = merged.fanOutIntervalMs
 
     ch.subscribed_connections[conn] = cs
-    # A parked channel must start fanning out to its new subscriber now,
-    # not at the next heartbeat.
-    wake = getattr(ch, "wake", None)
-    if callable(wake):
-        wake()
+    # The first fan-out is work the channel has at the close of the
+    # subscription's first window, whatever else it has.
+    ch.note_work(foc.last_fanout_time + merged.fanOutIntervalMs * NS_PER_MS)
 
     if ch.channel_type == ChannelType.SPATIAL:
         conn.spatial_subscriptions[ch.id] = cs.options

@@ -583,10 +583,14 @@ class TPUSpatialController(StaticGrid2DSpatialController):
     def _publish_due(self, result) -> None:
         import numpy as np
 
+        from ..core.channel import get_channel, scheduler
+        from ..core.data import mark_has_work
+
         self._due_seq += 1
         due = np.unpackbits(np.asarray(result["due_packed"]))
         churn = result.get("churn")
         gone = churn.subs if churn is not None else ()
+        told = set()  # channels the scheduler has heard of this step
         for slot in np.nonzero(due)[0].tolist():
             if slot in gone:
                 # Freed (and perhaps taken again) while the step ran:
@@ -595,6 +599,14 @@ class TPUSpatialController(StaticGrid2DSpatialController):
             ch_id = self._slot_channel.get(slot)
             if ch_id is not None:
                 self._due_pending.setdefault(ch_id, {})[slot] = self._due_seq
+                if ch_id not in told:
+                    ch = get_channel(ch_id)
+                    if ch is not None and mark_has_work(ch, slot):
+                        # The mark is the channel's work. One on a
+                        # subscription owed nothing costs its entry
+                        # above and no tick.
+                        told.add(ch_id)
+                        scheduler.note_device_due(ch)
 
     # ---- auto-following interest (channeld-tpu extension) ----------------
 

@@ -105,22 +105,28 @@ def test_fanout_timeline():
     assert c1.latest_data_update().text == "d"
     assert len(c2.data_updates()) == 2
 
-    # U5 from the server at 460ms. Each due tick advances a subscriber's
-    # window by exactly one fanOutInterval (window = (last, last+interval]),
-    # so U5 fans out only once the windows catch up to its arrival time.
+    # U5 from the server at 460ms; the next tick comes at 500ms, two of
+    # c1's intervals after its last one. The windows nothing arrived in
+    # ((350,400], (400,450]) close by arithmetic and each subscriber is
+    # served the window that holds U5, no sooner than that window's close:
+    # what a tick every interval, on time, would have sent.
     ch.data.on_update(
         testdata_pb2.TestChannelDataMessage(text="e"), t0 + 360 * MS, c0.id, None
     )
-    tick_data(ch, t0 + 400 * MS)  # c1 (350,400] miss; c2 (350,450] miss
-    assert len(c1.data_updates()) == 4
-    assert len(c2.data_updates()) == 2
-    tick_data(ch, t0 + 450 * MS)  # c1 (400,450] miss; c2 (450,550] hits 460
-    assert len(c1.data_updates()) == 4
-    assert len(c2.data_updates()) == 3
-    assert c2.latest_data_update().text == "e"
-    tick_data(ch, t0 + 500 * MS)  # c1 (450,500] contains 460 -> "e"
+    tick_data(ch, t0 + 400 * MS)  # c1 (450,500] holds 460; c2 (450,550] open
     assert len(c1.data_updates()) == 5
     assert c1.latest_data_update().text == "e"
+    assert len(c2.data_updates()) == 2
+    assert cs1.fanout_conn.last_fanout_time == t0 + 400 * MS
+    assert cs2.fanout_conn.last_fanout_time == t0 + 350 * MS
+    tick_data(ch, t0 + 450 * MS)  # c2 (450,550] closes -> "e"; c1 owed nothing
+    assert len(c1.data_updates()) == 5
+    assert len(c2.data_updates()) == 3
+    assert c2.latest_data_update().text == "e"
+    tick_data(ch, t0 + 500 * MS)  # nothing owed: windows move on, nothing sent
+    assert len(c1.data_updates()) == 5
+    assert len(c2.data_updates()) == 3
+    assert cs1.fanout_conn.last_fanout_time == t0 + 500 * MS
 
 
 def test_skip_self_update_fanout():

@@ -16,6 +16,7 @@ import time
 import numpy as np
 import pytest
 
+import channeld_tpu.core.channel as channel_mod
 import channeld_tpu.core.connection as connection_mod
 from channeld_tpu.chaos import arm, disarm
 from channeld_tpu.core import metrics
@@ -391,7 +392,7 @@ def test_hang_on_the_task_path_fences_abandons_and_rebuilds():
         eng = ctl.engine
         await until(lambda: stage_count("device_step") >= 1)
         other = create_channel(ChannelType.SUBWORLD, None)
-        subscribe_to_channel(StubConnection(7), other, None)  # never parks
+        subscribe_to_channel(StubConnection(7), other, None)
         global_settings.device_step_deadline_s = 0.08
         arm({"seed": 3, "faults": [
             {"point": "device.step_hang", "every_n": 1, "max_fires": 1,
@@ -400,6 +401,7 @@ def test_hang_on_the_task_path_fences_abandons_and_rebuilds():
         step = ctl._in_flight.guarded
         gen0, pool0 = eng.generation, guard._pool
         frames0, other0 = gch.tick_frames, other.tick_frames
+        other.execute(lambda ch: None)  # work for the scheduler meanwhile
         t0 = time.monotonic()
         await until(lambda: guard.failure_counts.get("hang"))
         waited = time.monotonic() - t0
@@ -541,10 +543,12 @@ def _record_decisions(ctl):
     return seen
 
 
-def _tick_the_rest(gch) -> None:
+def _tick_the_rest(gch, tick: int) -> None:
+    """The other channels on the engine's clock (a mark that comes
+    before the host's own window has closed is kept until it has)."""
     for ch in list(all_channels().values()):
         if ch is not gch:
-            ch.tick_once(ch.get_time())
+            ch.tick_once(10_000_000_000 + tick * 50_000_000)
 
 
 def test_the_task_path_and_the_direct_path_decide_alike():
@@ -559,7 +563,7 @@ def test_the_task_path_and_the_direct_path_decide_alike():
             clock[0] = t
             _walk(ctl, rng, pos)
             gch.tick_once(gch.get_time())
-            _tick_the_rest(gch)
+            _tick_the_rest(gch, t)
         return seen, stage_count("step.await")
 
     async def by_task():
@@ -569,15 +573,15 @@ def test_the_task_path_and_the_direct_path_decide_alike():
         # This coroutine is GLOBAL's tick task, and paces the others:
         # what reaches the engine, and in which tick, is then the same
         # on both paths.
-        for ch in all_channels().values():
-            ch._tick_task.cancel()
-            ch._tick_task = None
+        gch._tick_task.cancel()
+        gch._tick_task = None
+        channel_mod.scheduler.stop()
         seen = _record_decisions(ctl)
         for t in range(ticks):
             clock[0] = t
             _walk(ctl, rng, pos)
             await gch._tick_global(gch.get_time(), time.monotonic())
-            _tick_the_rest(gch)
+            _tick_the_rest(gch, t)
         return seen
 
     awaits0 = stage_count("step.await")

@@ -8,6 +8,7 @@ the two wait counters (tick lateness, fan-out window lag)."""
 import asyncio
 import json
 import os
+import time
 
 import pytest
 
@@ -399,6 +400,7 @@ def test_no_annotation_is_open_while_the_global_task_awaits(monkeypatch):
     stacks: dict = {}
     closed: list = []
     torn: list = []
+    on_closed = [None]  # the scenario's hook, called as a span closes
 
     class Recorded:
         def __init__(self, name):
@@ -418,9 +420,15 @@ def test_no_annotation_is_open_while_the_global_task_awaits(monkeypatch):
                 torn.append((self.name, list(stack)))
             stack.remove(self.name)
             closed.append((threading.get_ident(), self.name))
+            if on_closed[0] is not None:
+                on_closed[0](self.name)
 
     monkeypatch.setattr(tracing, "_annotation", Recorded)
     loop_thread = threading.get_ident()
+
+    def global_spans() -> int:
+        return sum(1 for t, n in closed
+                   if t == loop_thread and n == "channeld/tick.GLOBAL")
 
     async def scenario():
         gch = tsa.new_runtime()
@@ -428,18 +436,37 @@ def test_no_annotation_is_open_while_the_global_task_awaits(monkeypatch):
         held = tsa.Held(ctl.engine)
         await tsa.until(held.entered.is_set)
         assert recorder.profiling
-        ticks = sum(1 for t, n in closed
-                    if t == loop_thread and n == "channeld/tick.GLOBAL")
+        ticks = global_spans()
+        awaits = tsa.stage_count("step.await")
         in_flight = []
         for _ in range(5):  # other tasks run; GLOBAL's is parked
             in_flight.append(list(stacks.get(loop_thread, ())))
-            await asyncio.sleep(0.005)
+            await asyncio.sleep(0)
         assert ctl._in_flight is not None
         assert in_flight == [[]] * 5
         assert "channeld/step.begin" in {n for _, n in closed}
+        # The end is an event, not a count that moves when a tick
+        # BEGINS: the close of the second span of a whole tick after the
+        # one in flight. GLOBAL's task is stopped there, between two
+        # ticks, with no step in flight and no span open on any thread.
+        # (This test waited for ``tick_frames >= 3``, true from the
+        # start of the third tick on, and under load returned with that
+        # tick's step still inside the worker's ``step.*`` spans.)
+        finished = asyncio.Event()
+
+        def tick_closed(name):
+            if (name == "channeld/tick.GLOBAL"
+                    and threading.get_ident() == loop_thread
+                    and ctl._in_flight is None
+                    and global_spans() >= ticks + 3):
+                on_closed[0] = None
+                gch._tick_task.cancel()
+                finished.set()
+
+        on_closed[0] = tick_closed
         held.release.set()
-        await tsa.until(lambda: tsa.stage_count("step.await") >= 1)
-        await tsa.until(lambda: gch.tick_frames >= 3)
+        await asyncio.wait_for(finished.wait(), 30.0)
+        assert tsa.stage_count("step.await") >= awaits + 2
         return ticks
 
     before = asyncio.run(scenario())
@@ -504,28 +531,39 @@ def test_tick_lateness_from_an_injected_clock():
     assert channel_mod._tick_late[gch.channel_type] == [0, 0]
 
 
-def test_tick_loop_counts_running_ticks_and_no_parked_ones():
-    """The real loop: a channel that may park adds no lateness sample, a
-    channel held awake by a subscriber adds one a tick after its first."""
+def test_the_scheduler_counts_a_sample_a_tick_and_none_for_an_idle_channel():
+    """The real task: a channel with no work is not ticked and adds no
+    lateness sample; a channel that is sent messages adds one a tick,
+    each against the instant its work was ready."""
     from channeld_tpu.core import channel as channel_mod
+    from channeld_tpu.core import metrics
     from channeld_tpu.core.channel import create_channel
-    from channeld_tpu.core.subscription import subscribe_to_channel
     from channeld_tpu.core.types import ChannelType
-    from helpers import StubConnection, fresh_runtime
+    from helpers import fresh_runtime
 
     async def scenario():
         fresh_runtime()
         idle = create_channel(ChannelType.SUBWORLD, None)
         busy = create_channel(ChannelType.PRIVATE, None)
-        subscribe_to_channel(StubConnection(1), busy, None)
-        await asyncio.sleep(busy.tick_interval * 4 + 0.05)
-        late = dict(channel_mod._tick_late)
-        assert idle._may_park() and not busy._may_park()
-        return late[ChannelType.SUBWORLD][1], late[ChannelType.PRIVATE][1]
+        handled = []
+        end = time.monotonic() + busy.tick_interval * 4 + 0.05
+        while time.monotonic() < end:
+            busy.execute(lambda ch: handled.append(ch.tick_frames))
+            await asyncio.sleep(0.005)
+        late = channel_mod._tick_late
+        assert idle.tick_frames == 0 and busy.tick_frames >= 3
+        assert len(set(handled)) == busy.tick_frames
+        # What the GLOBAL tick has not carried to /metrics yet.
+        return (late[ChannelType.SUBWORLD][1], late[ChannelType.PRIVATE][1],
+                busy.tick_frames)
 
-    parked, running = asyncio.run(scenario())
-    assert parked == 0
-    assert running >= 2
+    counts0 = [_sum_count(metrics.tick_late_ms, t)[1]
+               for t in ("SUBWORLD", "PRIVATE")]
+    parked, running, frames = asyncio.run(scenario())
+    flushed = [_sum_count(metrics.tick_late_ms, t)[1] - c0
+               for t, c0 in zip(("SUBWORLD", "PRIVATE"), counts0)]
+    assert parked + flushed[0] == 0
+    assert running + flushed[1] == frames
 
 
 def test_fanout_window_lag_of_a_subscription_served_late():
@@ -556,10 +594,17 @@ def test_fanout_window_lag_of_a_subscription_served_late():
     tick_data(ch, foc.last_fanout_time + 50 * ms)  # not due: not served
     assert window_lag_ns[ChannelType.SUBWORLD] == [0, 0]
     due = foc.last_fanout_time + 100 * ms
+    ch.data.on_update(sim_pb2.SimEntityChannelData(),
+                      foc.last_fanout_time + 10 * ms, 7)
     tick_data(ch, due + 200 * ms)  # served two intervals late
     assert window_lag_ns[ChannelType.SUBWORLD] == [200 * ms, 1]
-    # The window moved on by one interval, so it is still one behind.
+    assert len(conn.sent) == 2
+    # The window that held the update moved on by one interval; the two
+    # empty ones behind it will cost a subtraction, and no lag sample.
     assert foc.last_fanout_time == due
+    tick_data(ch, due + 250 * ms)
+    assert window_lag_ns[ChannelType.SUBWORLD] == [200 * ms, 1]
+    assert foc.last_fanout_time == due + 200 * ms
     sum0, count0 = _sum_count(metrics.fanout_window_lag_ms, "SUBWORLD")
     gch.tick_once(gch.get_time())
     total, count = _sum_count(metrics.fanout_window_lag_ms, "SUBWORLD")
