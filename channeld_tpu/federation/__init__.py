@@ -2,8 +2,7 @@
 
 The reference's distributed story is "N independent nodes" — gateways
 scale only by splitting disjoint client populations, so the seamless
-open world ends at one gateway's grid (scripts/federation_bench.py
-documents the gap). This package shards the *world itself* across
+open world ends at one gateway's grid. This package shards the *world itself* across
 gateway processes, CheetahGIS-style distributed spatial partitioning
 with Spider-style transactional cross-node migration (PAPERS.md):
 

@@ -90,10 +90,10 @@ def compact_handovers(
     rank = jnp.cumsum(handover_mask, dtype=jnp.int32) - 1
     reported = handover_mask & (rank < max_out)
     # First max_out crossing slots, in slot order: scatter each reported
-    # slot's index into its rank (reuses the cumsum; ~25% faster on v5e
-    # than the jnp.nonzero(size=...) compaction it replaced — 0.34 vs
-    # 0.45 ms net at N=100K, bench_breakdown.py). Unreported slots write
-    # into a discard lane.
+    # slot's index into its rank. This reuses the cumsum above, which
+    # the jnp.nonzero(size=...) compaction it replaced computed again;
+    # the ledger's breakdown.device_ops has what the fusion costs on
+    # the chip. Unreported slots write into a discard lane.
     slot = jnp.where(reported, rank, max_out)
     idx = (
         jnp.zeros(max_out + 1, jnp.int32)

@@ -758,10 +758,10 @@ class StaticGrid2DSpatialController:
         pass, one remove/add Execute hop per channel, one fan-out message
         per recipient per pair — preserving the reference's per-pair
         ordering (owner swap -> remove/add -> fan-out,
-        ref: spatial.go:612-858). The device detects crossings in batch
-        (~1.5K per tick at the flagship load); per-crossing orchestration
-        measured 87.8us each (11.4K/s, scripts/bench_handover.py) — far
-        under the 44.5K/s detection rate, hence this path."""
+        ref: spatial.go:612-858). The device detects a tick's crossings
+        in one batch, and orchestrating them one by one repeats the
+        hop into each channel for every entity, hence this path; what it
+        costs the GLOBAL tick is the ledger's ``handover_host_ms``."""
         groups: dict = {}  # insertion-ordered: first-crossing pair order
         remote_groups: dict = {}  # (src, dst) -> providers, dst on a peer
         frozen = _balancer.frozen_cells

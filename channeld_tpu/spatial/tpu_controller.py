@@ -907,10 +907,10 @@ class TPUSpatialController(StaticGrid2DSpatialController):
             self._publish_due(result)
         if handovers or self._deferred_crossings:
             # Batched orchestration: one owner-swap/remove-add/fan-out
-            # pass per (src,dst) cell pair, not per crossing — the device
-            # detects ~1.5K crossings per tick and per-crossing host
-            # orchestration measured 3.9x slower than the detection rate
-            # (scripts/bench_handover.py).
+            # pass per (src,dst) cell pair, not per crossing: the device
+            # hands over a tick's crossings at once and the host must
+            # not pay a channel hop for each (the ``handover`` stage,
+            # handover_host_ms in the ledger).
             pending = self._deferred_crossings
             for e, s, d in handovers:
                 if (self._micro_leaf is not None

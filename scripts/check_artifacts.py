@@ -1,17 +1,22 @@
 """Artifact + doc drift checker (run from tier-1: tests/test_artifacts.py).
 
-Two classes of silent rot this repo has accumulated defenses against,
+Three classes of silent rot this repo has accumulated defenses against,
 now checked in one place on every test run:
 
-1. **Committed artifacts** — every ``SOAK_*.json`` / ``BENCH_*.json`` /
-   ``TRACE_*.json`` at the repo root must parse and match its schema
-   (the required keys its soak/bench writer emits and its README/docs
+1. **Committed artifacts** — every ``SOAK_*.json`` / ``TRACE_*.json`` /
+   ``OBS_*.json`` at the repo root must parse and match its schema
+   (the required keys its soak writer emits and its README/docs
    claims cite). A soak refactor that silently changes an artifact's
    shape fails here instead of when a reviewer re-reads the claim.
+   Speed is not recorded here: the benchmark (``benchmark/``) and the
+   driver's ``PERF_LEDGER.jsonl`` are its one record.
 2. **Doc'd metric names** — every Prometheus metric a doc or the README
    references must exist in ``core/metrics.py``. Renaming a metric
    without fixing the docs (or documenting a metric that was never
    registered) fails fast.
+3. **Doc'd files** — every source, record or document a doc or the
+   README names in backticks must exist: a document may not cite a
+   program that has gone.
 
 Usage: ``python scripts/check_artifacts.py`` (exit 0 = clean).
 """
@@ -45,11 +50,6 @@ SCHEMAS: dict[str, set] = {
     "SOAK_FED_*.json": _SOAK_KEYS | {
         "census", "gateway_a", "gateway_b", "redirect", "timeline",
     },
-    # Bench artifacts predate the kind tag; pin the keys the docs cite.
-    "BENCH_GATEWAY_*.json": {"headline", "runs", "metric"},
-    "BENCH_HANDOVER_*.json": {"metric", "crossings_per_tick",
-                              "keeps_up_with_detection"},
-    "BENCH_FANOUT_*.json": {"metric", "configs", "p99_under_5ms_all"},
     "SOAK_GLOBAL_*.json": _SOAK_KEYS | {
         "migration", "adoption", "redirect", "census",
     },
@@ -81,24 +81,6 @@ SCHEMAS: dict[str, set] = {
     # ledgers, the honest census/delivery accounting, and the RSS bound.
     "SOAK_ABUSE_*.json": _SOAK_KEYS | {
         "attackers", "edge", "census", "delivery", "rss",
-    },
-    # Standing-query plane bench (doc/query_engine.md acceptance
-    # artifact): the 10K+ one-transfer-per-tick scale record, the
-    # host-vs-device crossover curve, the changed-rows fraction with
-    # its O(changed) apply evidence, the 1K-follower per-follower
-    # cost, and the double-entry ledgers.
-    "BENCH_QUERY_*.json": {
-        "metric", "scale", "crossover", "changed_rows",
-        "follower_1k", "ledgers",
-    },
-    # On-device simulation bench (doc/simulation.md acceptance
-    # artifact): the 100K-agents-stepped-on-device scale record with
-    # the zero-extra-transfers counter evidence, the steady-tick
-    # overhead, the census exactness proof, and the rebuild
-    # double-entry ledgers.
-    "BENCH_SIM_*.json": {
-        "metric", "agents", "ticks", "steady", "transfers", "census",
-        "ledgers",
     },
     # On-device simulation soak (doc/simulation.md acceptance
     # artifact): exact census (zero agents lost or duplicated) across
@@ -360,132 +342,6 @@ def _check_density_soak(doc: dict) -> list[str]:
     return errors
 
 
-def _check_query_bench(doc: dict) -> list[str]:
-    """The query bench's acceptance bar beyond key presence
-    (doc/query_engine.md): >= 10K standing queries evaluated with
-    exactly ONE query-plane transfer per tick — counter-verified
-    against `query_plane_transfers_total`, not just asserted — host
-    apply scaling O(changed rows) not O(queries), and the 1K-follower
-    per-follower cost under the PR 7 ~30µs host-loop baseline."""
-    errors: list[str] = []
-    scale = doc.get("scale", {})
-    if scale.get("standing_queries", 0) < 10000:
-        errors.append(
-            f"fewer than 10K standing queries at the scale point "
-            f"({scale.get('standing_queries')})"
-        )
-    ticks = scale.get("ticks")
-    if not ticks or scale.get("transfers") != ticks:
-        errors.append(
-            f"one-transfer-per-tick not proven (ticks={ticks}, "
-            f"transfers={scale.get('transfers')})"
-        )
-    ledgers = doc.get("ledgers", {})
-    for py_key, metric_key in (
-        ("transfers", "query_plane_transfers_total"),
-        ("rows_changed", "query_rows_changed_total"),
-    ):
-        if py_key not in ledgers or metric_key not in ledgers \
-                or ledgers[py_key] != ledgers[metric_key]:
-            errors.append(
-                f"double-entry {py_key} == {metric_key} not proven "
-                f"(ledgers={ledgers})"
-            )
-    if ticks and ledgers.get("transfers") != ticks:
-        errors.append(
-            f"transfer ledger does not counter-verify the tick count "
-            f"(ticks={ticks}, ledger={ledgers.get('transfers')})"
-        )
-    changed = doc.get("changed_rows", {})
-    frac = changed.get("steady_fraction")
-    if frac is None or frac >= 0.5:
-        errors.append(
-            f"steady changed-rows fraction not small ({frac}) — the "
-            "O(changed) premise"
-        )
-    ratio = changed.get("apply_us_per_changed_ratio_10x")
-    if ratio is None or ratio > 3.0:
-        errors.append(
-            "host apply not O(changed): per-changed-row apply cost at "
-            f"10x queries is {ratio}x the small-registry cost (> 3.0)"
-        )
-    fol = doc.get("follower_1k", {})
-    if fol.get("followers", 0) < 1000:
-        errors.append(
-            f"no 1K-follower point recorded ({fol.get('followers')})"
-        )
-    us = fol.get("us_per_follower")
-    baseline = fol.get("baseline_us")
-    if us is None or baseline is None or us >= baseline:
-        errors.append(
-            f"per-follower cost not under the host-loop baseline "
-            f"(us_per_follower={us}, baseline_us={baseline})"
-        )
-    if not doc.get("crossover"):
-        errors.append("no host-vs-device crossover curve recorded")
-    return errors
-
-
-def _check_sim_bench(doc: dict) -> list[str]:
-    """The sim bench's acceptance bar beyond key presence
-    (doc/simulation.md): >= 100K agents actually stepped on device
-    every tick, ZERO extra device->host fetches on a steady tick —
-    the counted per-tick fetch rate with the sim pass armed must be
-    bit-equal to the no-sim loop's — and the census exact: rebuild
-    verified clean, every agent id preserved, double-entry between the
-    engine rebuild ledger and the sim_device_rebuilds metric."""
-    errors: list[str] = []
-    if doc.get("agents", 0) < 100_000:
-        errors.append(
-            f"fewer than 100K agents at the scale point "
-            f"({doc.get('agents')})"
-        )
-    steady = doc.get("steady", {})
-    ticks = doc.get("ticks")
-    if not ticks or steady.get("sim_ticks_advanced") != ticks:
-        errors.append(
-            f"sim pass did not run every tick (ticks={ticks}, "
-            f"advanced={steady.get('sim_ticks_advanced')})"
-        )
-    tr = doc.get("transfers", {})
-    if tr.get("extra_per_tick") != 0:
-        errors.append(
-            f"steady tick not transfer-free: extra_per_tick="
-            f"{tr.get('extra_per_tick')}"
-        )
-    if tr.get("sim_fetches_per_tick") is None or \
-            tr.get("sim_fetches_per_tick") != tr.get(
-                "no_sim_fetches_per_tick"):
-        errors.append(
-            f"per-tick fetch rate with sim armed does not match the "
-            f"no-sim loop (sim={tr.get('sim_fetches_per_tick')}, "
-            f"no_sim={tr.get('no_sim_fetches_per_tick')})"
-        )
-    census = doc.get("census", {})
-    if census.get("verify_errors") != 0:
-        errors.append(
-            f"post-census rebuild not verified clean "
-            f"(verify_errors={census.get('verify_errors')})"
-        )
-    if not census.get("ids_exact"):
-        errors.append("census did not preserve every agent id")
-    if census.get("agents", 0) < doc.get("agents", 0):
-        errors.append(
-            f"census covered fewer agents than seeded "
-            f"({census.get('agents')} < {doc.get('agents')})"
-        )
-    ledgers = doc.get("ledgers", {})
-    eng = ledgers.get("sim_rebuilds_verified")
-    met = ledgers.get("sim_device_rebuilds_total_verified")
-    if not eng or eng != met:
-        errors.append(
-            f"double-entry sim_rebuilds_verified == "
-            f"sim_device_rebuilds_total_verified not proven "
-            f"(ledgers={ledgers})"
-        )
-    return errors
-
-
 def _check_sim_soak(doc: dict) -> list[str]:
     """The sim soak's acceptance bar beyond key presence
     (doc/simulation.md): all five phases ran, the kill -9 phase
@@ -525,10 +381,15 @@ EXTRA_CHECKS = {
     "OBS_*.json": _check_obs_soak,
     "SOAK_ABUSE_*.json": _check_abuse_soak,
     "SOAK_SPLIT_*.json": _check_density_soak,
-    "BENCH_QUERY_*.json": _check_query_bench,
-    "BENCH_SIM_*.json": _check_sim_bench,
     "SOAK_SIM_*.json": _check_sim_soak,
 }
+
+
+def _artifact_paths(repo: str) -> list[str]:
+    """Every root file that looks like a pinned record."""
+    return sorted(
+        path for pattern in ("SOAK_*.json", "TRACE_*.json", "OBS_*.json")
+        for path in glob.glob(os.path.join(repo, pattern)))
 
 
 def check_artifacts(repo: str = REPO) -> list[str]:
@@ -560,12 +421,7 @@ def check_artifacts(repo: str = REPO) -> list[str]:
                 errors.extend(f"{name}: {e}" for e in extra(doc))
     # Nothing at the root may LOOK like a pinned artifact yet escape
     # every schema (a new SOAK_X_rNN.json must land with a schema row).
-    for path in sorted(
-        glob.glob(os.path.join(repo, "SOAK_*.json"))
-        + glob.glob(os.path.join(repo, "BENCH_*.json"))
-        + glob.glob(os.path.join(repo, "TRACE_*.json"))
-        + glob.glob(os.path.join(repo, "OBS_*.json"))
-    ):
+    for path in _artifact_paths(repo):
         name = os.path.basename(path)
         if name not in matched:
             errors.append(f"{name}: no schema registered in "
@@ -593,7 +449,7 @@ _BRACED_RE = re.compile(r"`([a-z][a-z0-9_]*)\{([a-zA-Z_0-9,=\" ]*)\}`")
 # abut the brace and the label text allows no bare quote or brace, so
 # JSON structure itself ("stats": {...}) can never match.
 # no lookbehind char may extend the name or be a backslash: embedded
-# stdout in old bench artifacts contains escaped "\n{...}" sequences
+# stdout in an artifact contains escaped "\n{...}" sequences
 # whose 'n' would otherwise read as a one-letter metric name.
 _ARTIFACT_BRACED_RE = re.compile(
     r'(?<![A-Za-z0-9_\\])([a-z][a-z0-9_]*)\{((?:[^}{"\\\n]|\\")+)\}')
@@ -700,27 +556,86 @@ def check_doc_metrics(repo: str = REPO) -> list[str]:
 
 
 def check_artifact_metrics(repo: str = REPO) -> list[str]:
-    """Metric references inside committed soak/bench/trace artifacts
+    """Metric references inside committed soak and trace artifacts
     (invariant-check names cite families with their label sets) must
     also exist and carry the declared labels."""
     names = registered_metric_names()
     label_sets = registered_label_sets()
     errors: list[str] = []
-    for pattern in ("SOAK_*.json", "BENCH_*.json", "TRACE_*.json",
-                    "OBS_*.json"):
+    for path in _artifact_paths(repo):
+        text = open(path).read()
+        braced = _ARTIFACT_BRACED_RE.findall(text)
+        # Artifacts carry free-form soak-local stat keys that may
+        # end in _total; only braced refs (deliberate metric
+        # citations, label set included) and bare _total tokens
+        # matching a registered family are validated.
+        totals = {
+            base for base in _TOTAL_RE.findall(text) if base in names
+        }
+        errors.extend(_check_metric_refs(
+            os.path.basename(path), totals, braced, names, label_sets,
+        ))
+    return errors
+
+
+# ---------------------------------------------------------------------------
+# doc'd files vs the tree
+# ---------------------------------------------------------------------------
+
+_PATH_RE = re.compile(
+    r"`([A-Za-z0-9_.*/-]+\.(?:py|json|md|sh|cc|proto))(?::[0-9][0-9,-]*)?`")
+# Where the upstream Go project keeps its sources (SURVEY.md): a path
+# under one of these cites the reference, which is not part of this tree.
+_REFERENCE_DIRS = ("pkg/", "cmd/")
+_SKIP_DIRS = {"chiprun_out", "profiles", "build", "__pycache__"}
+
+
+def _tree_files(repo: str) -> tuple[list[str], str]:
+    """(every file git would commit, the text of its programs): what a
+    bare name may resolve against. Walked, not asked of git: a checkout
+    under test need not be a repository."""
+    names: list[str] = []
+    sources: list[str] = []
+    for root, dirs, files in os.walk(repo):
+        dirs[:] = [d for d in dirs
+                   if not d.startswith(".") and d not in _SKIP_DIRS]
+        names.extend(files)
+        for name in files:
+            if name.endswith(".py"):
+                with open(os.path.join(root, name), errors="replace") as f:
+                    sources.append(f.read())
+    return names, "\n".join(sources)
+
+
+def check_doc_paths(repo: str = REPO) -> list[str]:
+    """Every ``*.py``, ``*.json``, ``*.md``, ``*.sh``, ``*.cc`` and
+    ``*.proto`` file that ``README.md`` or a ``doc/*.md`` names in
+    backticks exists: from the root, or under ``channeld_tpu/``, or, a
+    bare name or bare glob, anywhere in the tree. Two kinds of name are
+    rightly absent: a path in the reference's tree, and a bare name
+    that a program of this tree writes at run time (it stands quoted in
+    that program's source)."""
+    import fnmatch
+
+    names, sources = _tree_files(repo)
+    errors: list[str] = []
+    for pattern in DOC_GLOBS:
         for path in sorted(glob.glob(os.path.join(repo, pattern))):
-            text = open(path).read()
-            braced = _ARTIFACT_BRACED_RE.findall(text)
-            # Artifacts carry free-form soak-local stat keys that may
-            # end in _total; only braced refs (deliberate metric
-            # citations, label set included) and bare _total tokens
-            # matching a registered family are validated.
-            totals = {
-                base for base in _TOTAL_RE.findall(text) if base in names
-            }
-            errors.extend(_check_metric_refs(
-                os.path.basename(path), totals, braced, names, label_sets,
-            ))
+            with open(path) as f:
+                cited = sorted(set(_PATH_RE.findall(f.read())))
+            for token in cited:
+                if token.startswith(_REFERENCE_DIRS):
+                    continue
+                if glob.glob(os.path.join(repo, token)) or glob.glob(
+                        os.path.join(repo, "channeld_tpu", token)):
+                    continue
+                if "/" not in token and (
+                        fnmatch.filter(names, token)
+                        or f'"{token}"' in sources):
+                    continue
+                errors.append(
+                    f"{os.path.relpath(path, repo)}: names `{token}`, "
+                    "which is not in the tree")
     return errors
 
 
@@ -877,6 +792,7 @@ def check_simulation_doc(repo: str = REPO) -> list[str]:
 
 def main() -> int:
     errors = (check_artifacts() + check_doc_metrics()
+              + check_doc_paths()
               + check_artifact_metrics() + check_concurrency_doc()
               + check_partitioning_doc() + check_query_engine_doc()
               + check_simulation_doc())
@@ -884,13 +800,7 @@ def main() -> int:
         for e in errors:
             print(f"DRIFT: {e}")
         return 1
-    n_artifacts = len(
-        glob.glob(os.path.join(REPO, "SOAK_*.json"))
-        + glob.glob(os.path.join(REPO, "BENCH_*.json"))
-        + glob.glob(os.path.join(REPO, "TRACE_*.json"))
-        + glob.glob(os.path.join(REPO, "OBS_*.json"))
-    )
-    print(f"clean: {n_artifacts} artifacts, "
+    print(f"clean: {len(_artifact_paths(REPO))} artifacts, "
           f"{len(registered_metric_names())} metric families")
     return 0
 

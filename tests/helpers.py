@@ -126,3 +126,32 @@ def stage_count(stage: str) -> float:
 
     child = metrics.tick_stage_ms.labels(stage=stage)
     return sum(b.get() for b in child._buckets)
+
+
+class FetchCounter:
+    """Counts device->host fetches while it is entered: every readback
+    in the codebase is an ``np.asarray`` on a jax array (the tpulint
+    hot-readback rule holds the idiom), so a counting ``np.asarray`` in
+    numpy's own namespace sees them all, on every thread."""
+
+    def __init__(self):
+        self.count = 0
+
+    def __enter__(self):
+        import jax
+        import numpy as np
+
+        self._orig = orig = np.asarray
+
+        def counting(a, *args, **kwargs):
+            if isinstance(a, jax.Array):
+                self.count += 1
+            return orig(a, *args, **kwargs)
+
+        np.asarray = counting
+        return self
+
+    def __exit__(self, *exc):
+        import numpy as np
+
+        np.asarray = self._orig

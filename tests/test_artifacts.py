@@ -1,7 +1,8 @@
-"""Tier-1 drift gate: every committed SOAK_*/BENCH_*/TRACE_* artifact
-matches its schema and every doc-referenced Prometheus metric exists in
-core/metrics.py (scripts/check_artifacts.py — the checker the CI story
-in doc/observability.md describes)."""
+"""Tier-1 drift gate: every committed SOAK_*/TRACE_*/OBS_* artifact
+matches its schema, every doc-referenced Prometheus metric exists in
+core/metrics.py and every file a doc names exists
+(scripts/check_artifacts.py — the checker the CI story in
+doc/observability.md describes)."""
 
 import os
 import sys
@@ -18,6 +19,39 @@ def test_committed_artifacts_match_their_schemas():
 
 def test_doc_referenced_metrics_exist():
     assert check_artifacts.check_doc_metrics() == []
+
+
+def test_doc_named_files_exist():
+    """Every source, record and document that README.md or a doc/*.md
+    names in backticks is in the tree."""
+    assert check_artifacts.check_doc_paths() == []
+
+
+def test_doc_named_missing_file_is_flagged(tmp_path):
+    """The guard actually guards: a document citing a program that has
+    gone is flagged; what is there, by path, by package path, by bare
+    name or by glob, what the reference holds and what a run writes
+    are not."""
+    (tmp_path / "doc").mkdir()
+    (tmp_path / "scripts").mkdir()
+    (tmp_path / "channeld_tpu" / "core").mkdir(parents=True)
+    (tmp_path / "scripts" / "a_soak.py").write_text(
+        'OUT = "a_report.json"\n')
+    (tmp_path / "channeld_tpu" / "core" / "thing_pb2.py").write_text("")
+    (tmp_path / "README.md").write_text(
+        "`scripts/a_soak.py:12-14` `core/thing_pb2.py` `thing_pb2.py` "
+        "`*_pb2.py` `scripts/*_soak.py` `pkg/channeldpb/channeld.proto` "
+        "`a_report.json` `scripts/<name>.py` `{a,b}.py`\n")
+    assert check_artifacts.check_doc_paths(str(tmp_path)) == []
+
+    (tmp_path / "doc" / "x.md").write_text(
+        "run `scripts/gone_driver.py`, read `NOPE_r01.json:3` and "
+        "`scripts/*_nope.py`\n")
+    errors = check_artifacts.check_doc_paths(str(tmp_path))
+    assert len(errors) == 3 and all(e.startswith("doc/x.md") for e in errors)
+    assert any("`scripts/gone_driver.py`" in e for e in errors)
+    assert any("`NOPE_r01.json`" in e for e in errors)
+    assert any("`scripts/*_nope.py`" in e for e in errors)
 
 
 def test_new_artifact_without_schema_fails(tmp_path):
@@ -496,65 +530,6 @@ def test_missing_concurrency_doc_is_flagged(tmp_path):
     assert errors and "missing" in errors[0]
 
 
-def _query_bench_doc():
-    return {
-        "metric": "standing_queries_one_transfer_per_tick",
-        "scale": {"standing_queries": 10240, "ticks": 200,
-                  "transfers": 200},
-        "crossover": [{"queries": 256, "host_ms": 1.2, "device_ms": 0.9}],
-        "changed_rows": {"steady_fraction": 0.02,
-                         "apply_us_per_changed_ratio_10x": 1.3},
-        "follower_1k": {"followers": 1024, "us_per_follower": 4.0,
-                        "baseline_us": 30.0},
-        "ledgers": {"transfers": 200, "query_plane_transfers_total": 200,
-                    "rows_changed": 5000,
-                    "query_rows_changed_total": 5000},
-    }
-
-
-def test_query_bench_schema_gate(tmp_path):
-    """BENCH_QUERY_*.json extra checks (doc/query_engine.md): a clean
-    artifact passes; under-scale query counts, a transfer count off the
-    tick count, a ledger!=metric mismatch, a large changed fraction, a
-    non-O(changed) apply ratio, and a per-follower cost at/over the
-    host-loop baseline are each flagged."""
-    import json
-
-    path = tmp_path / "BENCH_QUERY_r99.json"
-    path.write_text(json.dumps(_query_bench_doc()))
-    assert check_artifacts.check_artifacts(str(tmp_path)) == []
-
-    doc = _query_bench_doc()
-    doc["scale"]["standing_queries"] = 4096
-    path.write_text(json.dumps(doc))
-    assert any("fewer than 10K standing queries" in e
-               for e in check_artifacts.check_artifacts(str(tmp_path)))
-
-    doc = _query_bench_doc()
-    doc["scale"]["transfers"] = 201
-    path.write_text(json.dumps(doc))
-    assert any("one-transfer-per-tick not proven" in e
-               for e in check_artifacts.check_artifacts(str(tmp_path)))
-
-    doc = _query_bench_doc()
-    doc["ledgers"]["query_plane_transfers_total"] = 199
-    path.write_text(json.dumps(doc))
-    assert any("double-entry transfers" in e
-               for e in check_artifacts.check_artifacts(str(tmp_path)))
-
-    doc = _query_bench_doc()
-    doc["changed_rows"]["apply_us_per_changed_ratio_10x"] = 8.0
-    path.write_text(json.dumps(doc))
-    assert any("not O(changed)" in e
-               for e in check_artifacts.check_artifacts(str(tmp_path)))
-
-    doc = _query_bench_doc()
-    doc["follower_1k"]["us_per_follower"] = 31.0
-    path.write_text(json.dumps(doc))
-    assert any("not under the host-loop baseline" in e
-               for e in check_artifacts.check_artifacts(str(tmp_path)))
-
-
 def test_query_engine_doc_matches_declared_knobs():
     """doc/query_engine.md documents exactly the queryplane_* knobs
     core/settings.py declares, and the planes the standing-query
@@ -584,74 +559,6 @@ def test_query_engine_doc_drift_is_flagged(tmp_path):
     assert any("queryplane_ghost_knob" in e for e in errors)
     assert any("queryplane_rows_max" in e for e in errors)
     assert sum("no cross-link" in e for e in errors) == 4
-
-
-def _sim_bench_doc():
-    return {
-        "metric": "sim_100k_agents_on_device_zero_extra_transfers",
-        "agents": 100000,
-        "ticks": 30,
-        "steady": {"no_sim_tick_ms_p50": 13.5, "sim_tick_ms_p50": 42.9,
-                   "sim_overhead_ms_p50": 29.4, "sim_ticks_advanced": 30},
-        "transfers": {"no_sim_fetches_per_tick": 1.0,
-                      "sim_fetches_per_tick": 1.0, "extra_per_tick": 0.0,
-                      "census_tick_fetches": 4,
-                      "census_column_fetches": 4},
-        "census": {"agents": 100000, "movement_l1": 1.0,
-                   "verify_errors": 0, "ids_exact": True},
-        "ledgers": {"sim_rebuilds_verified": 1,
-                    "sim_device_rebuilds_total_verified": 1},
-    }
-
-
-def test_sim_bench_schema_gate(tmp_path):
-    """BENCH_SIM_*.json extra checks (doc/simulation.md): a clean
-    artifact passes; an under-scale population, a sim pass that
-    skipped ticks, any extra steady-tick transfer, a dirty census, and
-    a rebuild ledger!=metric mismatch are each flagged."""
-    import json
-
-    path = tmp_path / "BENCH_SIM_r99.json"
-    path.write_text(json.dumps(_sim_bench_doc()))
-    assert check_artifacts.check_artifacts(str(tmp_path)) == []
-
-    doc = _sim_bench_doc()
-    doc["agents"] = doc["census"]["agents"] = 50000
-    path.write_text(json.dumps(doc))
-    assert any("fewer than 100K agents" in e
-               for e in check_artifacts.check_artifacts(str(tmp_path)))
-
-    doc = _sim_bench_doc()
-    doc["steady"]["sim_ticks_advanced"] = 29
-    path.write_text(json.dumps(doc))
-    assert any("did not run every tick" in e
-               for e in check_artifacts.check_artifacts(str(tmp_path)))
-
-    doc = _sim_bench_doc()
-    doc["transfers"]["sim_fetches_per_tick"] = 2.0
-    doc["transfers"]["extra_per_tick"] = 1.0
-    path.write_text(json.dumps(doc))
-    errors = check_artifacts.check_artifacts(str(tmp_path))
-    assert any("not transfer-free" in e for e in errors)
-    assert any("does not match the no-sim loop" in e for e in errors)
-
-    doc = _sim_bench_doc()
-    doc["census"]["verify_errors"] = 3
-    path.write_text(json.dumps(doc))
-    assert any("rebuild not verified clean" in e
-               for e in check_artifacts.check_artifacts(str(tmp_path)))
-
-    doc = _sim_bench_doc()
-    doc["census"]["ids_exact"] = False
-    path.write_text(json.dumps(doc))
-    assert any("did not preserve every agent id" in e
-               for e in check_artifacts.check_artifacts(str(tmp_path)))
-
-    doc = _sim_bench_doc()
-    doc["ledgers"]["sim_device_rebuilds_total_verified"] = 0
-    path.write_text(json.dumps(doc))
-    assert any("double-entry sim_rebuilds_verified" in e
-               for e in check_artifacts.check_artifacts(str(tmp_path)))
 
 
 def _sim_soak_doc():

@@ -36,7 +36,7 @@ from channeld_tpu.sim.plane import AGENT_ID_OFFSET
 from channeld_tpu.spatial.controller import SpatialInfo, set_spatial_controller
 from channeld_tpu.spatial.tpu_controller import TPUSpatialController
 
-from helpers import StubConnection, fresh_runtime
+from helpers import FetchCounter, StubConnection, fresh_runtime
 
 ENTITY_START = 0x80000
 AGENT_BASE = ENTITY_START + AGENT_ID_OFFSET
@@ -403,6 +403,46 @@ def test_agents_and_humans_identical_to_query_plane():
     run_ticks(ctl, channels, 2)
     assert dict(ctl.queryplane.sensor_cells(key)) == want
     assert cell_ch.id in want
+
+
+def _fetches_per_tick(ticks, **world):
+    """Device->host fetches of each of ``ticks`` controller ticks (the
+    guarded step on its worker, as the gateway runs it), and whether
+    each carried a census, after three ticks to compile and settle."""
+    ctl, _server, channels = make_world(**world)
+    run_ticks(ctl, channels, 3)
+    ledgers = ctl.simplane.ledgers if ctl.simplane is not None else {}
+    fetches, census = [], []
+    for _ in range(ticks):
+        before = ledgers.get("census_transfers", 0)
+        with FetchCounter() as fc:
+            run_ticks(ctl, channels, 1)
+        fetches.append(fc.count)
+        census.append(ledgers.get("census_transfers", 0) > before)
+    return ctl, fetches, census
+
+
+def test_sim_pass_adds_no_fetch_to_a_steady_tick():
+    """A steady tick with the sim pass armed makes no device->host
+    fetch beyond the no-sim tick's, and a census tick adds its own
+    columns and no more (doc/simulation.md). The same driver loop over
+    the same world on both sides."""
+    assert global_settings.device_guard_enabled
+    ticks = 12
+    ctl, plain, _ = _fetches_per_tick(ticks, agents=200, sim_enabled=False)
+    assert ctl.simplane is None
+    assert len(set(plain)) == 1 and plain[0] > 0, plain
+    steady = plain[0]
+
+    fresh_runtime()
+    register_sim_types()
+    ctl, armed, census = _fetches_per_tick(ticks, agents=200, census=5)
+    eng = ctl.engine
+    assert eng.agent_count() == 200 and eng.run_sim_pass
+    assert 2 <= sum(census) < ticks, census
+    columns = 4  # position, velocity, state, target
+    assert armed == [steady + (columns if c else 0) for c in census], (
+        plain, armed, census)
 
 
 def test_overload_l2_halves_sim_cadence_with_shed_double_entry():
