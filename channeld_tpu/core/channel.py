@@ -82,15 +82,16 @@ _m_fanout_decision = metrics.fanout_decision_latency.labels(backend="host")
 # How long work waited for the event loop: [seconds late, ticks] by
 # channel type. GLOBAL's tick loop and the scheduler add into the type's
 # pair (a subtraction and two adds a tick); the GLOBAL tick carries the
-# pairs, the scheduler's ticks by cause, core/data.py's fan-out window
-# lag and skipped windows and core/tracing.py's collector pauses to
-# /metrics.
+# pairs, the scheduler's ticks by cause and what its window ticks cost,
+# core/data.py's fan-out window lag and skipped windows and
+# core/tracing.py's collector pauses to /metrics.
 _tick_late: dict = {t: [0.0, 0] for t in ChannelType}
 
 
 def _flush_wait_counters() -> None:
     """``tick_late_ms``, ``fanout_window_lag_ms``, ``channel_ticks``,
-    ``fanout_windows_skipped`` and ``gc_pause_ms``, once per GLOBAL
+    ``fanout_windows_skipped``, ``window_ticks_early``,
+    ``window_tick_subscriptions`` and ``gc_pause_ms``, once per GLOBAL
     tick: a registry call for each of some thousand channel ticks a
     second would sit on the thread that is the bottleneck, and one from
     inside the collector could wait for a lock its own thread holds."""
@@ -113,6 +114,13 @@ def _flush_wait_counters() -> None:
         metrics.channel_ticks.labels(
             channel_type=ctype.name, cause=cause).inc(n)
     ticks.clear()
+    for ctype, acc in scheduler.window_ticks.items():
+        if acc[0]:
+            metrics.window_ticks_early.labels(
+                channel_type=ctype.name).inc(acc[1])
+            metrics.window_tick_subscriptions.labels(
+                channel_type=ctype.name).add(acc[2], acc[0])
+            acc[0] = acc[1] = acc[2] = 0
     flush_gc_pauses()
 
 
@@ -465,9 +473,8 @@ class Channel:
         elif due_ns is None:
             scheduler.note(self, time.monotonic(), _HOUSEKEEPING)
         else:
-            scheduler.note(
-                self, (self.start_ns + due_ns) * 1e-9 + _TIMER_SLACK_S,
-                _WINDOW)
+            scheduler.note_close(
+                self, (self.start_ns + due_ns) * 1e-9 + _TIMER_SLACK_S)
 
     def _may_park(self) -> bool:
         """GLOBAL's task, the only one there is."""
@@ -941,8 +948,22 @@ class TickScheduler:
     is one entry ``channel -> (ready_at, cause)``; an entry whose
     instant lies ahead (a window's close, the pacing) waits in one heap
     behind one ``call_at``. A channel with no work has no task, no
-    timer and no visit. Pacing: never more often than the channel's
-    tick interval, and at once where it has been idle longer.
+    timer and no visit.
+
+    What is paced and what is not. A message and a duty (``note``)
+    wait until one tick interval after the channel's last tick that
+    handled a message or a duty, and not at all where it has been idle
+    longer: a message stream ticks a channel at most once an interval.
+    A window's close (``note_close``: the ``next_due`` a tick returns,
+    ``Channel.note_work(due_ns)``, a device mark) is due AT the close,
+    whatever the channel's last tick was: the tick serves every window
+    that has closed by then and names the next close, so what bounds
+    such ticks is the subscribers' own lattices, one close a subscriber
+    a fan-out interval, fewer where closes fall inside one pass. A
+    close the tick met and did not serve (the ladder withholds it, or
+    its subscription is a window behind and moves one a tick) is looked
+    at again one interval on. A window tick that finds a message
+    handles it, as any tick does, and then counts as a message's.
 
     Each visit is ``tick_once()``. ``tick_late_ms`` reads how long
     ready work waited: the tick's start less ``ready_at``.
@@ -953,7 +974,14 @@ class TickScheduler:
         self._work: dict = {}  # channel -> (ready_at, cause)
         self._heap: list = []  # (ready_at, n, channel); stale entries linger
         self._n = 0
-        self._last: dict = {}  # channel -> start of its last tick (pacing)
+        # channel -> start of its last tick that handled a message or a
+        # duty (the pacing), and of its last tick of any cause.
+        self._last: dict = {}
+        self._ticked: dict = {}
+        # channel type -> [window ticks, of them sooner than one tick
+        # interval after the channel's last tick, subscriptions they
+        # served], to /metrics.
+        self.window_ticks: dict = {t: [0, 0, 0] for t in ChannelType}
         self._seen_close_epoch = 0
         self._task: Optional[asyncio.Task] = None
         self._wakeup: Optional[asyncio.Event] = None
@@ -970,11 +998,21 @@ class TickScheduler:
             self.note(ch, time.monotonic(), _MESSAGE)
 
     def note(self, ch: "Channel", at: float, cause: str) -> None:
-        """``ch`` has work from ``at`` on (loop clock), or from one tick
-        interval after its last tick if that is later."""
+        """``ch`` has a message or a duty from ``at`` on (loop clock),
+        or from one tick interval after its last tick for one if that
+        is later."""
         last = self._last.get(ch)
         if last is not None:
             at = max(at, last + ch.tick_interval)
+        self._put(ch, at, cause)
+
+    def note_close(self, ch: "Channel", at: float) -> None:
+        """A fan-out window of ``ch`` that holds something owed closes
+        at ``at`` (or the device marked it): its tick is due then,
+        whatever its last tick was."""
+        self._put(ch, at, _WINDOW)
+
+    def _put(self, ch: "Channel", at: float, cause: str) -> None:
         entry = self._work.get(ch)
         if entry is not None and entry[0] <= at:
             return
@@ -987,7 +1025,7 @@ class TickScheduler:
     def note_device_due(self, ch: "Channel") -> None:
         """The device marked a subscription of ``ch`` due, and it is
         owed something (spatial/tpu_controller.py ``_publish_due``)."""
-        self.note(ch, time.monotonic(), _WINDOW)
+        self.note_close(ch, time.monotonic())
 
     def wake(self) -> None:
         """Something the next pass looks at moved (a connection closed)."""
@@ -997,6 +1035,7 @@ class TickScheduler:
     def forget(self, ch: "Channel") -> None:
         self._work.pop(ch, None)
         self._last.pop(ch, None)
+        self._ticked.pop(ch, None)
 
     # ---- the pass --------------------------------------------------------
 
@@ -1044,32 +1083,52 @@ class TickScheduler:
 
     def _tick(self, ch: "Channel", ready_at: float, cause: str,
               tick_start: float) -> None:
-        late = _tick_late[ch.channel_type]
+        ctype = ch.channel_type
+        late = _tick_late[ctype]
         if tick_start > ready_at:
             late[0] += tick_start - ready_at
         late[1] += 1
+        for_window = cause is _WINDOW
         if ch.in_msg_queue:
             cause = _MESSAGE
-        key = (ch.channel_type, cause)
+        key = (ctype, cause)
         self.ticks[key] = self.ticks.get(key, 0) + 1
-        self._last[ch] = tick_start
+        if cause is not _WINDOW:
+            self._last[ch] = tick_start
+        early = for_window and (
+            tick_start < self._ticked.get(ch, -_NEVER) + ch.tick_interval)
+        self._ticked[ch] = tick_start
+        services = window_lag_ns[ctype]  # tick_data counts each in [1]
+        served = services[1]
+        now = ch.get_time()
         try:
-            next_due = ch.tick_once(ch.get_time(), tick_start, ingest=False)
+            next_due = ch.tick_once(now, tick_start, ingest=False)
         except Exception:
             # One channel's fault must not stop every channel's ticks.
             ch.logger.exception("channel tick failed")
             next_due = None
+        if for_window:
+            acc = self.window_ticks[ctype]
+            acc[0] += 1
+            acc[1] += early
+            acc[2] += services[1] - served
         if ch.removing:
             self.forget(ch)
             return
-        # What is left, or lies ahead; ``note`` paces it.
+        # What is left, or lies ahead.
         if ch.in_msg_queue:
             self.note(ch, tick_start, _MESSAGE)
         elif ch.id in _congested_channels or ch.recoverable_subs:
             self.note(ch, tick_start, _HOUSEKEEPING)
-        if next_due is not None:
-            self.note(ch, (ch.start_ns + next_due) * 1e-9 + _TIMER_SLACK_S,
-                      _WINDOW)
+        if next_due is None:
+            return
+        if next_due > now:
+            self.note_close(
+                ch, (ch.start_ns + next_due) * 1e-9 + _TIMER_SLACK_S)
+        else:
+            # Met closed and left: withheld by the ladder, or a
+            # subscription a window behind, which moves one a tick.
+            self.note_close(ch, tick_start + ch.tick_interval)
 
     # ---- the task --------------------------------------------------------
 
@@ -1094,9 +1153,12 @@ class TickScheduler:
     def reset(self) -> None:
         self.stop()
         self.ticks.clear()
+        for acc in self.window_ticks.values():
+            acc[0] = acc[1] = acc[2] = 0
         self._work.clear()
         self._heap.clear()
         self._last.clear()
+        self._ticked.clear()
         self._seen_close_epoch = _connection().close_epoch
 
     def _arm(self, until: float) -> None:

@@ -726,8 +726,9 @@ tick_late_ms = SumCount(
     "milliseconds, by channel type: the time ready work waited for the "
     "event loop. GLOBAL is due one tick interval after its last tick "
     "began (its first tick and one after a park are not counted); every "
-    "other channel when its message was enqueued or its fan-out window "
-    "closed, or one tick interval after its last tick if that is later",
+    "other channel when its fan-out window closed, or when its message "
+    "was enqueued or one tick interval after its last tick for a message "
+    "if that is later (a window's close is not paced)",
     ["channel_type"],
     registry=registry,
 )
@@ -738,8 +739,30 @@ channel_ticks = Counter(
     "fan-out window that holds an owed update closed, or the device "
     "marked it due), housekeeping (backpressure to lift, a closed "
     "subscriber to prune, a recoverable subscription, a new "
-    "subscriber); the first that applied",
+    "subscriber); the first that applied. A message tick comes at most "
+    "once a tick interval, a window tick at its window's close whatever "
+    "the channel's last tick was, and one that finds a message counts "
+    "as message",
     ["channel_type", "cause"],
+    registry=registry,
+)
+window_ticks_early = Counter(
+    "window_ticks_early",
+    "Ticks the scheduler made for a fan-out window (its close, a first "
+    "fan-out due, a device mark) sooner than one tick interval after "
+    "their channel's last tick, by channel type: how often serving a "
+    "window at its close takes a tick that one tick an interval would "
+    "not have made",
+    ["channel_type"],
+    registry=registry,
+)
+window_tick_subscriptions = SumCount(
+    "window_tick_subscriptions",
+    "Subscriptions past their first fan-out that a tick made for a "
+    "fan-out window served (the services fanout_window_lag_ms counts), "
+    "by channel type: sum over count is the subscriptions served a "
+    "window tick, near 1 where every close takes a tick of its own",
+    ["channel_type"],
     registry=registry,
 )
 fanout_windows_skipped = Counter(
