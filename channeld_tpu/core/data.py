@@ -24,6 +24,7 @@ from ..protocol import control_pb2
 from ..utils.anyutil import pack_any, unpack_any
 from ..utils.fieldmask import filter_fields
 from ..utils.logger import get_logger
+from . import metrics
 from .overload import governor as _governor
 from .slo import slo as _slo
 from .types import ChannelDataAccess, ChannelType, MessageType
@@ -321,6 +322,15 @@ def _device_due_view(channel: "Channel"):
 window_lag_ns: dict = {t: [0, 0] for t in ChannelType}
 windows_skipped: dict = {t: 0 for t in ChannelType}
 
+# ``fanout_encodes`` and ``fanout_sends`` by channel type: tick_data
+# counts into locals and adds here once a tick, so a send costs an
+# integer add and a tick two ``inc`` calls.
+_fanout_counters: dict = {
+    t: (metrics.fanout_encodes.labels(channel_type=t.name),
+        metrics.fanout_sends.labels(channel_type=t.name))
+    for t in ChannelType
+}
+
 
 def _owed_close(arrivals: list, last: int, interval_ns: int) -> int:
     """Close of the window ``[last + k*I, last + (k+1)*I]`` that holds
@@ -399,7 +409,8 @@ def tick_data(channel: "Channel", now: int) -> Optional[int]:
     stretch = _governor.fanout_stretch() if _governor.level else 1.0
     shed_floor = _governor.shed_priority_floor() if _governor.level else None
 
-    lag_ns = served_late = skipped = 0  # this tick's share of the counters
+    # This tick's share of the counters.
+    lag_ns = served_late = skipped = encodes = sends = 0
     next_due = None
     queue = channel.fan_out_queue
     device = _device_due_view(channel)
@@ -500,7 +511,9 @@ def tick_data(channel: "Channel", now: int) -> Optional[int]:
             served_late += 1
         if not foc.had_first_fanout:
             # First fan-out carries the full channel state.
-            fan_out_data_update(channel, conn, cs, data.msg, body_cache)
+            encodes += fan_out_data_update(
+                channel, conn, cs, data.msg, body_cache)
+            sends += 1
             foc.had_first_fanout = True
             foc.last_message_index = data.msg_index
             latest_fanout_time = now
@@ -511,7 +524,9 @@ def tick_data(channel: "Channel", now: int) -> Optional[int]:
             # Deltas can't reconstruct its view, so resync with full
             # state — this is what keeps the brownout lossless at the
             # STATE level no matter how long the hold.
-            fan_out_data_update(channel, conn, cs, data.msg, body_cache)
+            encodes += fan_out_data_update(
+                channel, conn, cs, data.msg, body_cache)
+            sends += 1
             foc.last_message_index = data.msg_index
             latest_fanout_time = now
         elif owed:
@@ -536,15 +551,16 @@ def tick_data(channel: "Channel", now: int) -> Optional[int]:
                     if len(window) == 1:
                         # A single foreign update is a stable buffered
                         # message — cache-safe like the shared path.
-                        fan_out_data_update(
+                        encodes += fan_out_data_update(
                             channel, conn, cs, window[0].update_msg, body_cache
                         )
                     else:
                         # The scratch accumulator is reused next call; its
                         # bytes must not enter the shared cache.
-                        fan_out_data_update(
+                        encodes += fan_out_data_update(
                             channel, conn, cs, _accumulate_window(data, window)
                         )
+                    sends += 1
                     if _slo.enabled:
                         _record_window_delivery(
                             channel, window, "device" if marked else "host")
@@ -561,7 +577,9 @@ def tick_data(channel: "Channel", now: int) -> Optional[int]:
                         else _accumulate_window(data, window, fresh=True)
                     )
                 foc.last_message_index = buffer[hi - 1].message_index
-                fan_out_data_update(channel, conn, cs, entry[1], body_cache)
+                encodes += fan_out_data_update(
+                    channel, conn, cs, entry[1], body_cache)
+                sends += 1
                 if _slo.enabled and not entry[2]:
                     # ONE sample per distinct window per tick, however
                     # many subscribers share it (bounded cost; the
@@ -590,6 +608,10 @@ def tick_data(channel: "Channel", now: int) -> Optional[int]:
         lag[1] += served_late
     if skipped:
         windows_skipped[channel.channel_type] += skipped
+    if sends:
+        counters = _fanout_counters[channel.channel_type]
+        counters[0].inc(encodes)
+        counters[1].inc(sends)
     # Keep the queue ordered by last_fanout_time (the reference maintains
     # this invariant with in-place move-to-back; a stable sort is the same
     # end state). Device mode doesn't iterate the queue, so its order is
@@ -602,14 +624,16 @@ def tick_data(channel: "Channel", now: int) -> Optional[int]:
 def fan_out_data_update(
     channel: "Channel", conn, cs, update_msg: Message,
     body_cache: Optional[dict] = None,
-) -> None:
+) -> bool:
     """(ref: data.go:293-318).
 
     ``body_cache`` (tick-scoped) shares the serialized update across
     subscribers receiving the identical message: a broadcast channel
     encodes each window once, not once per recipient. Values hold the
     source message alongside the bytes so an ``id()`` key can't be
-    recycled mid-tick.
+    recycled mid-tick. Returns whether this send serialized the update
+    (False: the bytes came from ``body_cache``), which is what
+    ``fanout_encodes`` counts.
     """
     if cs.options.dataFieldMasks:
         update_msg = _filtered_copy(update_msg, list(cs.options.dataFieldMasks))
@@ -622,7 +646,7 @@ def fan_out_data_update(
         if spatial and hit[1].raw_body is not None:
             _note_spatial_fanout(channel, len(hit[1].raw_body))
         conn.send(hit[1])
-        return
+        return False
     ctx = MessageContext(
         msg_type=MessageType.CHANNEL_DATA_UPDATE,
         msg=control_pb2.ChannelDataUpdateMessage(data=pack_any(update_msg)),
@@ -637,6 +661,7 @@ def fan_out_data_update(
         # the send queue), so one context object serves every recipient.
         body_cache[id(update_msg)] = (update_msg, ctx)
     conn.send(ctx)
+    return True
 
 
 def _filtered_copy(msg: Message, masks: list[str]) -> Message:

@@ -713,7 +713,9 @@ tick_stage_ms = Histogram(
     "pass; query_plane: standing-query consume and apply; handover: "
     "crossing orchestration; overload: governor update; trunk: trunk "
     "ingress dispatch; trace_freeze: the ring copy of an anomaly "
-    "dump). The flight recorder observes these whether or not span "
+    "dump; send_pump: one pass of the shared send pump that flushed "
+    "at least one connection, from its first flush to its last). The "
+    "flight recorder observes these whether or not span "
     "recording is enabled",
     ["stage"],
     buckets=(0.01, 0.05, 0.1, 0.5, 1.0, 2.0, 5.0, 10.0, 33.0, 100.0,
@@ -770,6 +772,24 @@ fanout_windows_skipped = Counter(
     "Whole fan-out windows nothing arrived in, closed by arithmetic "
     "when their subscription was next served instead of by a tick "
     "each, by channel type",
+    ["channel_type"],
+    registry=registry,
+)
+fanout_encodes = Counter(
+    "fanout_encodes",
+    "Channel data updates the fan-out serialized, by channel type: one "
+    "for each distinct window a tick served (the subscribers that share "
+    "it take the same bytes) and one for each send of per-subscriber "
+    "content (field masks, a skip-self window of several updates); "
+    "tick_data adds its tick's count once a tick",
+    ["channel_type"],
+    registry=registry,
+)
+fanout_sends = Counter(
+    "fanout_sends",
+    "Channel data updates the fan-out handed to a connection's send "
+    "queue, by channel type: fanout_sends over fanout_encodes is how "
+    "many subscribers shared an encode",
     ["channel_type"],
     registry=registry,
 )

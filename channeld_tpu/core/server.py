@@ -37,6 +37,7 @@ from .edge import edge_tick
 from .connection_recovery import connection_recovery_loop
 from .ddos import init_anti_ddos, unauth_reaper_loop
 from .settings import global_settings
+from .tracing import recorder as _trace
 from .types import ConnectionType
 
 logger = get_logger("server")
@@ -433,6 +434,22 @@ async def start_listening(conn_type: ConnectionType, network: str, addr: str):
     raise ValueError(f"unsupported network type: {network}")
 
 
+def _pump_sends(pending) -> int:
+    """Flush every connection of ``pending`` that has output queued;
+    ``monotonic_ns`` at the first flush, 0 when there was none."""
+    first_flush = 0
+    for conn in pending:
+        if not conn.is_closing() and conn.send_queue:
+            if not first_flush:
+                first_flush = time.monotonic_ns()
+            conn.flush(fair=True)
+            if conn.send_queue and not conn.is_closing():
+                # Fairness carry-over: the cap left entries queued;
+                # they go out next cycle, after everyone else's turn.
+                requeue_flush(conn)
+    return first_flush
+
+
 async def flush_loop(interval: float = 0.001) -> None:
     """Shared send pump (ref: the per-conn 1ms flush goroutine,
     connection.go:180-184). The 1ms cadence is the packet-coalescing
@@ -446,13 +463,21 @@ async def flush_loop(interval: float = 0.001) -> None:
         # queue this cycle, so a tick landing between pump cycles sees
         # them no later than the per-read dispatch would have allowed.
         flush_pending_ingest()
-        for conn in drain_pending_flush():
-            if not conn.is_closing() and conn.send_queue:
-                conn.flush(fair=True)
-                if conn.send_queue and not conn.is_closing():
-                    # Fairness carry-over: the cap left entries queued;
-                    # they go out next cycle, after everyone else's turn.
-                    requeue_flush(conn)
+        pending = drain_pending_flush()
+        if pending:
+            # The ``send_pump`` stage: a pass that flushed at least one
+            # connection, from its first flush to its last; one that
+            # found nothing queued reads no clock. Recorded after the
+            # fact, and a region only while a profiler session is live,
+            # as the channel tick's own sites (core/channel.py).
+            if _trace.profiling:
+                with _trace.region("send_pump", stage=True) as region:
+                    if not _pump_sends(pending):
+                        region.discard()
+            else:
+                first_flush = _pump_sends(pending)
+                if first_flush:
+                    _trace.stage("send_pump", first_flush)
         # Advance the edge plane's slow-consumer/quarantine ladder —
         # free while no peer is in distress (core/edge.py).
         edge_tick()
