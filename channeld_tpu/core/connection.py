@@ -64,6 +64,12 @@ def _bind_hot_handles() -> None:
     _handle_c2s_user, _handle_s2c_user = _c2s, _s2c
 
 
+# ``send_pump_messages_total`` by the path that wrote the messages:
+# Connection.write_batch (every transport) or the pump's one native call.
+_m_pump_python = metrics.send_pump_messages.labels(path="python")
+_m_pump_native = metrics.send_pump_messages.labels(path="native")
+
+
 class _ForwardBatch:
     """One batched-ingest run: pre-encoded owner send-queue entries for
     plain user-space forwards to GLOBAL, produced by the native codec's
@@ -603,8 +609,18 @@ class Connection:
         starve the 1ms cycle for every other peer; the remainder stays
         queued and the pump re-schedules it next cycle. Direct callers
         (disconnect, drain) flush everything."""
+        taken = self.take_batch(fair)
+        if taken is not None:
+            self.write_batch(*taken)
+
+    def take_batch(self, fair: bool = False) -> Optional[tuple[list, int]]:
+        """What one flush sends: (entries off the queue's head, the
+        compression their packets take), or None where nothing goes
+        now. The one statement of the transport gate, the fairness cap
+        and the envelope's accounting, for ``flush`` and for the pump's
+        native pass (core/server.py _pump_sends)."""
         if not self.send_queue:
-            return
+            return None
         env = self.envelope
         if fair and global_settings.edge_enabled:
             # Transport-backpressure gate (doc/edge_hardening.md): a peer
@@ -618,7 +634,7 @@ class Connection:
             if gate > 0:
                 getter = getattr(self.transport, "get_write_buffer_size", None)
                 if getter is not None and getter() > gate:
-                    return
+                    return None
         limit = (global_settings.edge_flush_fair_msgs
                  if fair and global_settings.edge_enabled else 0)
         if limit and len(self.send_queue) > limit:
@@ -636,29 +652,50 @@ class Connection:
         ct = self.compression_type
         if ct == CompressionType.SNAPPY and not snappy_codec.available():
             ct = CompressionType.NO_COMPRESSION
+        return batch, int(ct)
 
+    def write_batch(self, batch: list[tuple], ct: int) -> None:
+        """Encode a taken batch and hand its frames to the transport."""
         # Any encode failure must stay contained to this connection: the
         # shared flush pump calls flush() for every connection in turn.
         try:
             if _native_codec is not None:
-                frames, counts = _native_codec.encode_packets(batch, int(ct))
+                frames, counts = _native_codec.encode_packets(batch, ct)
             else:
-                frames, counts = self._encode_packets_py(batch, int(ct))
+                frames, counts = self._encode_packets_py(batch, ct)
         except Exception as e:
             self.logger.error("packet encode failed, dropping batch: %s", e)
             return
 
+        packets = nbytes = combined = msgs = 0
         for frame, count in zip(frames, counts):
             try:
                 self.transport.write(frame)
             except Exception as e:
                 self.logger.error("error writing packet: %s", e)
                 break
-            self._m_packet_sent.inc()
-            self._m_bytes_sent.inc(len(frame))
+            packets += 1
+            nbytes += len(frame)
             if count > 1:
-                self._m_packet_combined.inc()
-            self._m_msg_sent.inc(count)
+                combined += 1
+            msgs += count
+        self.account_sent(packets, nbytes, combined, msgs)
+
+    def account_sent(self, packets: int, nbytes: int, combined: int,
+                     msgs: int, native: bool = False) -> None:
+        """Count what went to the transport: the four sent-counters of
+        this connection's type, and the messages under the path that
+        wrote them (``send_pump_messages_total``). One call a flush; the
+        pump's native pass adds a whole pass's sums through one
+        connection of each type, whose labelled children are its
+        type's."""
+        if packets:
+            self._m_packet_sent.inc(packets)
+            self._m_bytes_sent.inc(nbytes)
+            if combined:
+                self._m_packet_combined.inc(combined)
+            self._m_msg_sent.inc(msgs)
+            (_m_pump_native if native else _m_pump_python).inc(msgs)
 
     def _encode_packets_py(self, batch: list[tuple], ct: int):
         """Pure-Python fallback for the native packet builder; returns

@@ -713,8 +713,9 @@ tick_stage_ms = Histogram(
     "pass; query_plane: standing-query consume and apply; handover: "
     "crossing orchestration; overload: governor update; trunk: trunk "
     "ingress dispatch; trace_freeze: the ring copy of an anomaly "
-    "dump; send_pump: one pass of the shared send pump that flushed "
-    "at least one connection, from its first flush to its last). The "
+    "dump; send_pump: one pass of the shared send pump that sent for "
+    "at least one connection, from the first batch taken to the last "
+    "write, the native call included). The "
     "flight recorder observes these whether or not span "
     "recording is enabled",
     ["stage"],
@@ -791,6 +792,24 @@ fanout_sends = Counter(
     "queue, by channel type: fanout_sends over fanout_encodes is how "
     "many subscribers shared an encode",
     ["channel_type"],
+    registry=registry,
+)
+send_pump_messages = Counter(
+    "send_pump_messages",
+    "Messages handed to a transport, by the path that wrote them: native "
+    "(the send pump's one call a pass into the codec, which encodes and "
+    "writes to the sockets of TCP peers with nothing buffered) or python "
+    "(Connection.flush: every other transport, a TCP peer with bytes "
+    "still buffered, a direct flush at a disconnect or a drain); the "
+    "two add up to messages_out",
+    ["path"],
+    registry=registry,
+)
+send_pump_partial_writes = Counter(
+    "send_pump_partial_writes",
+    "Native sends whose socket took less than the connection's packets "
+    "(a partial write or EAGAIN): the remainder went to the transport's "
+    "buffer, and the connection takes the python path until it drains",
     registry=registry,
 )
 fanout_window_lag_ms = SumCount(

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import asyncio
 import gc
+import os
 import time
 from collections import deque
 from typing import Optional
@@ -24,6 +25,8 @@ from ..chaos.injector import chaos as _chaos
 from ..utils.logger import get_logger, init_logs
 from . import events
 from .channel import congestion_wait, connection_congested, init_channels
+from . import connection as _connection_mod
+from . import metrics
 from .connection import (
     Connection,
     add_connection,
@@ -56,10 +59,28 @@ class TcpTransport:
 
     def __init__(self, transport: asyncio.Transport):
         self.transport = transport
+        self._sock = transport.get_extra_info("socket")
         try:
             transport.set_write_buffer_limits(high=MAX_SEND_BUFFER)
         except (AttributeError, NotImplementedError):
             pass
+
+    def direct_fd(self) -> int:
+        """The socket's descriptor while a write may go to it past the
+        asyncio transport, -1 otherwise: the transport is open and holds
+        no unsent bytes (bytes written past a buffer would overtake
+        it). The send pump's native pass asks (``_pump_sends``)."""
+        t = self.transport
+        if self._sock is None or t.is_closing() or t.get_write_buffer_size():
+            return -1
+        return self._sock.fileno()
+
+    def fail(self, error: OSError) -> None:
+        """A send past the transport failed: what asyncio does for a
+        failed ``transport.write``, the socket dropped unflushed and
+        ``connection_lost`` called, which closes the connection."""
+        logger.info("tcp peer %s: %s; closing", self.remote_addr(), error)
+        self.transport.abort()
 
     def write(self, data: bytes) -> None:
         t = self.transport
@@ -435,19 +456,90 @@ async def start_listening(conn_type: ConnectionType, network: str, addr: str):
 
 
 def _pump_sends(pending) -> int:
-    """Flush every connection of ``pending`` that has output queued;
-    ``monotonic_ns`` at the first flush, 0 when there was none."""
-    first_flush = 0
+    """Send for every connection of ``pending`` that has output queued;
+    ``monotonic_ns`` at the first one, 0 when there was none.
+
+    Each connection's batch is taken by ``Connection.take_batch`` (the
+    transport gate, the fairness cap, the envelope). A TCP peer with
+    nothing buffered before it then waits for the end of the pass,
+    where ONE call into the native codec encodes and writes every such
+    batch: ``socket.send`` gives the interpreter lock up round each
+    system call, a turn the device worker takes and the loop must win
+    back, once a message; the call gives it up at most once a pass,
+    round the whole write loop (doc/concurrency.md). Every other
+    connection (WebSocket, KCP, RUDP, a TCP peer whose transport still
+    buffers, a codec without ``send_packets``) goes through
+    ``Connection.flush`` as a direct flush does."""
+    first = 0
+    send_packets = getattr(_connection_mod._native_codec, "send_packets", None)
+    direct: list = []  # the connections of ``calls``, in its order
+    calls: list = []   # (fd, batch, compression)
     for conn in pending:
-        if not conn.is_closing() and conn.send_queue:
-            if not first_flush:
-                first_flush = time.monotonic_ns()
+        if conn.is_closing() or not conn.send_queue:
+            continue
+        if not first:
+            first = time.monotonic_ns()
+        transport = conn.transport
+        fd = (transport.direct_fd()
+              if send_packets is not None and type(transport) is TcpTransport
+              else -1)
+        if fd < 0:
             conn.flush(fair=True)
-            if conn.send_queue and not conn.is_closing():
-                # Fairness carry-over: the cap left entries queued;
-                # they go out next cycle, after everyone else's turn.
-                requeue_flush(conn)
-    return first_flush
+        else:
+            taken = conn.take_batch(fair=True)
+            if taken is not None:
+                direct.append(conn)
+                calls.append((fd, *taken))
+        if conn.send_queue and not conn.is_closing():
+            # Fairness carry-over: the cap (or the gate) left entries
+            # queued; they go out next cycle, after everyone else's turn.
+            requeue_flush(conn)
+    if calls:
+        try:
+            results = send_packets(calls)
+        except Exception:
+            # Nothing of a connection raises (its fault is its result):
+            # the call itself failed, and the pump must outlive it.
+            logger.exception("native send pass failed, dropping %d batches",
+                             len(calls))
+        else:
+            _account_direct(direct, results)
+    return first
+
+
+def _account_direct(direct: list, results: list) -> None:
+    """What the native call of one pass did, a connection at a time: a
+    remainder the socket did not take goes behind the transport (its
+    buffer was empty, so order holds, and ``MAX_SEND_BUFFER`` still
+    backstops it); a failed send or encode stays with its connection;
+    the sent-counters are added once a pass for each connection type."""
+    sums: dict = {}  # connection type -> [a connection of it, 4 sums]
+    partial = 0
+    for conn, result in zip(direct, results):
+        if isinstance(result, BaseException):
+            conn.logger.error("packet encode failed, dropping batch: %s",
+                              result)
+            continue
+        packets, nbytes, combined, msgs, rest, err = result
+        if err:
+            conn.transport.fail(OSError(err, os.strerror(err)))
+        elif rest is not None:
+            partial += 1
+            try:
+                conn.transport.write(rest)
+            except Exception as e:  # contained, as flush contains it
+                conn.logger.error("error writing packet: %s", e)
+        acc = sums.get(conn.connection_type)
+        if acc is None:
+            acc = sums[conn.connection_type] = [conn, 0, 0, 0, 0]
+        acc[1] += packets
+        acc[2] += nbytes
+        acc[3] += combined
+        acc[4] += msgs
+    for conn, packets, nbytes, combined, msgs in sums.values():
+        conn.account_sent(packets, nbytes, combined, msgs, native=True)
+    if partial:
+        metrics.send_pump_partial_writes.inc(partial)
 
 
 async def flush_loop(interval: float = 0.001) -> None:
@@ -455,8 +547,6 @@ async def flush_loop(interval: float = 0.001) -> None:
     connection.go:180-184). The 1ms cadence is the packet-coalescing
     window; each cycle only visits connections that queued output since
     the last one, so idle connections cost nothing."""
-    from . import metrics
-
     last_sample = 0.0
     while True:
         # Inbound first: deferred fast-path runs reach their channel

@@ -15,9 +15,13 @@
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
 
+#include <sys/socket.h>
+
+#include <cerrno>
 #include <cstdint>
 #include <cstring>
 #include <string>
+#include <vector>
 
 extern "C" {
 // snappy-c.h stable ABI (status: 0 = OK, 1 = INVALID_INPUT, 2 = BUFFER_TOO_SMALL)
@@ -37,51 +41,55 @@ static const size_t MAX_PACKET_SIZE = 0xFFFF;
 
 static PyObject* CodecError;
 
-// Core frame construction shared by encode_frame and encode_packets.
-// The size cap applies to the uncompressed payload (matching the Python
-// codec and the reference's pre-compression packet cap) so that the
-// decoder's decompression cap never rejects an honestly-encoded frame.
-static PyObject* build_frame(const char* payload, size_t payload_len,
-                             int compression) {
-  if (payload_len > MAX_PACKET_SIZE) {
-    PyErr_Format(CodecError, "packet oversized: %zu", payload_len);
-    return nullptr;
+// Core frame construction shared by encode_frame, encode_packets and
+// send_packets. The size cap applies to the uncompressed payload (matching
+// the Python codec and the reference's pre-compression packet cap) so that
+// the decoder's decompression cap never rejects an honestly-encoded frame.
+//
+// frame_payload decides what goes behind the tag: the payload as it is, or
+// its snappy form in ``scratch`` where that was asked for and is smaller.
+static bool frame_payload(const char** payload, size_t* payload_len,
+                          int* compression, std::string& scratch) {
+  if (*payload_len > MAX_PACKET_SIZE) {
+    PyErr_Format(CodecError, "packet oversized: %zu", *payload_len);
+    return false;
   }
-  char* scratch = nullptr;
-  if (compression == 1) {
-    size_t max_len = snappy_max_compressed_length(payload_len);
-    scratch = static_cast<char*>(PyMem_Malloc(max_len));
-    if (!scratch) return PyErr_NoMemory();
-    size_t compressed_len = max_len;
-    if (snappy_compress(payload, payload_len, scratch, &compressed_len) == 0 &&
-        compressed_len < payload_len) {
-      payload = scratch;
-      payload_len = compressed_len;
+  if (*compression == 1) {
+    scratch.resize(snappy_max_compressed_length(*payload_len));
+    size_t compressed_len = scratch.size();
+    if (snappy_compress(*payload, *payload_len, &scratch[0],
+                        &compressed_len) == 0 &&
+        compressed_len < *payload_len) {
+      *payload = scratch.data();
+      *payload_len = compressed_len;
     } else {
       // Incompressible (or error): store raw, mirroring the Python codec.
-      compression = 0;
+      *compression = 0;
     }
   }
+  return true;
+}
 
-  if (payload_len > MAX_PACKET_SIZE) {
-    if (scratch) PyMem_Free(scratch);
-    PyErr_Format(CodecError, "packet oversized: %zu", payload_len);
+static void write_tag(unsigned char* dst, size_t payload_len, int compression) {
+  dst[0] = MAGIC0;
+  dst[1] = MAGIC1;
+  dst[2] = (unsigned char)((payload_len >> 8) & 0xFF);
+  dst[3] = (unsigned char)(payload_len & 0xFF);
+  dst[4] = (unsigned char)compression;
+}
+
+static PyObject* build_frame(const char* payload, size_t payload_len,
+                             int compression, std::string& scratch) {
+  if (!frame_payload(&payload, &payload_len, &compression, scratch))
     return nullptr;
-  }
-
   PyObject* out = PyBytes_FromStringAndSize(nullptr,
                                             (Py_ssize_t)(HEADER_SIZE + payload_len));
   if (out) {
     unsigned char* dst =
         reinterpret_cast<unsigned char*>(PyBytes_AS_STRING(out));
-    dst[0] = MAGIC0;
-    dst[1] = MAGIC1;
-    dst[2] = (unsigned char)((payload_len >> 8) & 0xFF);
-    dst[3] = (unsigned char)(payload_len & 0xFF);
-    dst[4] = (unsigned char)compression;
+    write_tag(dst, payload_len, compression);
     memcpy(dst + HEADER_SIZE, payload, payload_len);
   }
-  if (scratch) PyMem_Free(scratch);
   return out;
 }
 
@@ -90,8 +98,9 @@ static PyObject* codec_encode_frame(PyObject* self, PyObject* args) {
   Py_buffer body;
   int compression = 0;
   if (!PyArg_ParseTuple(args, "y*|i", &body, &compression)) return nullptr;
+  std::string scratch;
   PyObject* out = build_frame(static_cast<const char*>(body.buf),
-                              (size_t)body.len, compression);
+                              (size_t)body.len, compression, scratch);
   PyBuffer_Release(&body);
   return out;
 }
@@ -209,63 +218,30 @@ static void write_varint(std::string& out, uint64_t v) {
   out.push_back((char)v);
 }
 
-// encode_packets(msgs, compression) -> (list[bytes], list[int])
-//
-// msgs: sequence of (channelId, broadcast, stubId, msgType, msgBody).
-// Batches message packs into framed packets, each body <= 64KB before
-// compression (mirroring Connection.flush's batching + oversize skip);
-// returns the ready-to-write frames plus the number of messages packed
-// into each frame (for exact sent-metrics attribution).
-static PyObject* codec_encode_packets(PyObject* self, PyObject* args) {
-  PyObject* seq;
-  int compression = 0;
-  if (!PyArg_ParseTuple(args, "O|i", &seq, &compression)) return nullptr;
-  PyObject* fast = PySequence_Fast(seq, "encode_packets expects a sequence");
-  if (!fast) return nullptr;
-
+// pack_batch walks msgs, a sequence of (channelId, broadcast, stubId,
+// msgType, msgBody), and hands ``emit(body, msgs_in_body)`` each packet
+// body as it fills: <= 64KB before compression, a single message over
+// that skipped (mirroring Connection.flush's batching + oversize skip).
+// The one statement of the batching rule, for encode_packets and
+// send_packets. False with a Python error set.
+template <class Emit>
+static bool pack_batch(PyObject* seq, Emit emit) {
+  PyObject* fast = PySequence_Fast(seq, "expected a sequence of messages");
+  if (!fast) return false;
   Py_ssize_t n = PySequence_Fast_GET_SIZE(fast);
-  PyObject* frames = PyList_New(0);
-  if (!frames) {
-    Py_DECREF(fast);
-    return nullptr;
-  }
-
-  PyObject* counts = PyList_New(0);
-  if (!counts) {
-    Py_DECREF(fast);
-    Py_DECREF(frames);
-    return nullptr;
-  }
 
   std::string body;
   body.reserve(MAX_PACKET_SIZE + 64);
   long body_msgs = 0;
+  bool ok = true;
 
-  auto flush_body = [&](void) -> bool {
-    if (body.empty()) return true;
-    PyObject* frame = build_frame(body.data(), body.size(), compression);
-    if (!frame) return false;
-    int rc = PyList_Append(frames, frame);
-    Py_DECREF(frame);
-    if (rc != 0) return false;
-    PyObject* cnt = PyLong_FromLong(body_msgs);
-    if (!cnt) return false;
-    rc = PyList_Append(counts, cnt);
-    Py_DECREF(cnt);
-    body.clear();
-    body_msgs = 0;
-    return rc == 0;
-  };
-
-  for (Py_ssize_t i = 0; i < n; i++) {
+  for (Py_ssize_t i = 0; i < n && ok; i++) {
     PyObject* item = PySequence_Fast_GET_ITEM(fast, i);
     unsigned long ch, bc, stub, mt;
     Py_buffer mb;
     if (!PyArg_ParseTuple(item, "kkkky*", &ch, &bc, &stub, &mt, &mb)) {
-      Py_DECREF(fast);
-      Py_DECREF(frames);
-      Py_DECREF(counts);
-      return nullptr;
+      ok = false;
+      break;
     }
     // MessagePack submessage payload size.
     size_t pack_size = 0;
@@ -280,13 +256,13 @@ static PyObject* codec_encode_packets(PyObject* self, PyObject* args) {
       PyBuffer_Release(&mb);
       continue;  // oversized single message: skip (caller logs)
     }
-    if (body.size() + entry_size > MAX_PACKET_SIZE) {
-      if (!flush_body()) {
+    if (body.size() + entry_size > MAX_PACKET_SIZE && !body.empty()) {
+      ok = emit(body, body_msgs);
+      body.clear();
+      body_msgs = 0;
+      if (!ok) {
         PyBuffer_Release(&mb);
-        Py_DECREF(fast);
-        Py_DECREF(frames);
-        Py_DECREF(counts);
-        return nullptr;
+        break;
       }
     }
     body.push_back((char)0x0A);  // Packet.messages tag
@@ -316,12 +292,179 @@ static PyObject* codec_encode_packets(PyObject* self, PyObject* args) {
     PyBuffer_Release(&mb);
   }
   Py_DECREF(fast);
-  if (!flush_body()) {
-    Py_DECREF(frames);
-    Py_DECREF(counts);
+  if (ok && !body.empty()) ok = emit(body, body_msgs);
+  return ok;
+}
+
+// encode_packets(msgs, compression) -> (list[bytes], list[int])
+//
+// Batches message packs into framed packets; returns the ready-to-write
+// frames plus the number of messages packed into each frame (for exact
+// sent-metrics attribution).
+static PyObject* codec_encode_packets(PyObject* self, PyObject* args) {
+  PyObject* seq;
+  int compression = 0;
+  if (!PyArg_ParseTuple(args, "O|i", &seq, &compression)) return nullptr;
+
+  PyObject* frames = PyList_New(0);
+  PyObject* counts = PyList_New(0);
+  std::string scratch;
+  bool ok = frames && counts &&
+            pack_batch(seq, [&](const std::string& body, long msgs) -> bool {
+              PyObject* frame = build_frame(body.data(), body.size(),
+                                            compression, scratch);
+              if (!frame) return false;
+              int rc = PyList_Append(frames, frame);
+              Py_DECREF(frame);
+              if (rc != 0) return false;
+              PyObject* cnt = PyLong_FromLong(msgs);
+              if (!cnt) return false;
+              rc = PyList_Append(counts, cnt);
+              Py_DECREF(cnt);
+              return rc == 0;
+            });
+  if (!ok) {
+    Py_XDECREF(frames);
+    Py_XDECREF(counts);
     return nullptr;
   }
   return Py_BuildValue("(NN)", frames, counts);
+}
+
+// send_packets(conns) -> list
+//
+// One pass of the send pump (core/server.py _pump_sends). conns: a
+// sequence of (fd, msgs, compression), one entry a connection whose
+// socket is open, non-blocking and has nothing buffered before it. For
+// each, the packets encode_packets builds for msgs are written to fd in
+// order with send(MSG_DONTWAIT | MSG_NOSIGNAL); everything of the pass is
+// encoded before its first byte goes out, so a bad entry sends nothing.
+// The result holds, in the order given, for a connection
+//   (packets, nbytes, combined, msgs, rest, err)
+// the packets built, their bytes, how many of them hold several messages
+// and the messages in them; ``rest`` the bytes the socket did not take
+// (None when it took all: the caller buffers them behind the transport),
+// ``err`` the errno of a send that failed (0 otherwise); or, in the
+// tuple's place, the exception its encode raised, nothing sent.
+//
+// The interpreter lock is not given up round a send: a lock given up
+// once a message is a turn the device worker takes and the loop thread
+// must win back, once a message (doc/concurrency.md). A pass of
+// RELEASE_AT_PACKETS packets or more gives it up ONCE, round the whole
+// write loop, which touches no Python object: a send to a loopback peer
+// is ~45 us of kernel time on the benchmark's machine and winning the
+// lock back ~29 us (PERF.md section 6, PR 37), so from four packets on the
+// worker gains several times what the one release costs the loop, and
+// held through a pass of dozens of sends the lock starves the worker a
+// millisecond at a time (its step read 2.6 ms longer).
+struct PumpSend {
+  int fd = -1;
+  std::string wire;
+  long packets = 0, combined = 0, msgs = 0;
+  size_t sent = 0;
+  int err = 0;
+  PyObject* failed = nullptr;  // owned: the encode's exception
+};
+
+static const long RELEASE_AT_PACKETS = 4;
+
+static void write_all(std::vector<PumpSend>& sends) {
+  for (PumpSend& s : sends) {
+    while (s.sent < s.wire.size()) {
+      ssize_t took = send(s.fd, s.wire.data() + s.sent, s.wire.size() - s.sent,
+                          MSG_DONTWAIT | MSG_NOSIGNAL);
+      if (took > 0) {
+        s.sent += (size_t)took;
+      } else if (took == 0 || errno != EINTR) {
+        // Took nothing: the remainder is the transport's (EAGAIN), or
+        // the send failed.
+        if (took < 0 && errno != EAGAIN && errno != EWOULDBLOCK) s.err = errno;
+        break;
+      }
+    }
+  }
+}
+
+static PyObject* codec_send_packets(PyObject* self, PyObject* args) {
+  PyObject* seq;
+  if (!PyArg_ParseTuple(args, "O", &seq)) return nullptr;
+  PyObject* fast = PySequence_Fast(seq, "send_packets expects a sequence");
+  if (!fast) return nullptr;
+  Py_ssize_t n = PySequence_Fast_GET_SIZE(fast);
+  std::vector<PumpSend> sends((size_t)n);
+  std::string scratch;
+  long total_packets = 0;
+
+  for (Py_ssize_t i = 0; i < n; i++) {
+    PumpSend& s = sends[(size_t)i];
+    PyObject* msgs;
+    int compression = 0;
+    bool ok =
+        PyArg_ParseTuple(PySequence_Fast_GET_ITEM(fast, i), "iOi", &s.fd,
+                         &msgs, &compression) &&
+        pack_batch(msgs, [&](const std::string& body, long n_msgs) -> bool {
+          const char* payload = body.data();
+          size_t len = body.size();
+          int ct = compression;
+          if (!frame_payload(&payload, &len, &ct, scratch)) return false;
+          unsigned char tag[HEADER_SIZE];
+          write_tag(tag, len, ct);
+          s.wire.append(reinterpret_cast<const char*>(tag), HEADER_SIZE);
+          s.wire.append(payload, len);
+          s.packets++;
+          if (n_msgs > 1) s.combined++;
+          s.msgs += n_msgs;
+          return true;
+        });
+    if (ok) {
+      total_packets += s.packets;
+      continue;
+    }
+    // Contained to this connection: its exception is its result.
+    PyObject *type, *tb;
+    PyErr_Fetch(&type, &s.failed, &tb);
+    PyErr_NormalizeException(&type, &s.failed, &tb);
+    Py_XDECREF(type);
+    Py_XDECREF(tb);
+    if (!s.failed)
+      s.failed = PyObject_CallFunction(PyExc_RuntimeError, "s",
+                                       "send_packets: encode failed");
+    s.wire.clear();
+  }
+  Py_DECREF(fast);
+
+  if (total_packets >= RELEASE_AT_PACKETS) {
+    Py_BEGIN_ALLOW_THREADS
+    write_all(sends);
+    Py_END_ALLOW_THREADS
+  } else {
+    write_all(sends);
+  }
+
+  PyObject* results = PyList_New(n);
+  for (Py_ssize_t i = 0; results && i < n; i++) {
+    PumpSend& s = sends[(size_t)i];
+    PyObject* result = s.failed;
+    s.failed = nullptr;
+    if (!result) {
+      size_t size = s.wire.size();
+      result = (s.err || s.sent == size)
+                   ? Py_BuildValue("(nnllOi)", (Py_ssize_t)s.packets,
+                                   (Py_ssize_t)size, s.combined, s.msgs,
+                                   Py_None, s.err)
+                   : Py_BuildValue("(nnlly#i)", (Py_ssize_t)s.packets,
+                                   (Py_ssize_t)size, s.combined, s.msgs,
+                                   s.wire.data() + s.sent,
+                                   (Py_ssize_t)(size - s.sent), 0);
+    }
+    if (!result) {
+      Py_CLEAR(results);
+      break;
+    }
+    PyList_SET_ITEM(results, i, result);
+  }
+  for (PumpSend& s : sends) Py_XDECREF(s.failed);
+  return results;
 }
 
 // ---- inbound forward fast path ------------------------------------------
@@ -544,6 +687,9 @@ static PyMethodDef codec_methods[] = {
      "decode_frames(buf) -> ([(body, compression)], consumed)"},
     {"encode_packets", codec_encode_packets, METH_VARARGS,
      "encode_packets([(chId, bc, stub, mt, body)], compression) -> ([frames], [counts])"},
+    {"send_packets", codec_send_packets, METH_VARARGS,
+     "send_packets([(fd, [(chId, bc, stub, mt, body)], compression)]) -> "
+     "[(packets, nbytes, combined, msgs, rest, err) | exception]"},
     {"parse_forward", codec_parse_forward, METH_VARARGS,
      "parse_forward(body, conn_id, expect_channel, min_user_type) -> "
      "None | (entries, counts)"},

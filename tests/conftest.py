@@ -28,15 +28,20 @@ def pytest_configure(config):
         "slow: long-running soaks/benches excluded from tier-1 (-m 'not slow')",
     )
 
-# Build the native codec once if a toolchain exists, so the native-path
-# parity tests run instead of skipping (they skip gracefully if this
-# fails — e.g. no g++). Cheap (~5s) and idempotent.
+# Build the native codec if a toolchain exists, where no library lies in
+# the tree or the one that does is older than its source (as
+# benchmark/harness/driver.py:build_native does): a working tree that
+# kept an older library would run the native-path tests against a codec
+# without the newest functions, or skip them. Cheap (~5s) and idempotent;
+# the tests skip gracefully if the build fails (e.g. no g++).
 _repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_codec_src = os.path.join(_repo, "channeld_tpu", "native", "codec.cc")
-_codec_glob = os.path.join(_repo, "channeld_tpu", "native")
-if not any(
-    f.startswith("_codec") and f.endswith(".so")
-    for f in os.listdir(_codec_glob)
+_native = os.path.join(_repo, "channeld_tpu", "native")
+_codec_built = [
+    os.path.getmtime(os.path.join(_native, f)) for f in os.listdir(_native)
+    if f.startswith("_codec") and f.endswith(".so")
+]
+if not _codec_built or min(_codec_built) < os.path.getmtime(
+    os.path.join(_native, "codec.cc")
 ):
     import subprocess
 

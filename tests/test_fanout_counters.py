@@ -155,6 +155,8 @@ def test_the_counters_rise_once_a_tick(channel_type, monkeypatch):
 class Queued:
     """What the pump touches of a connection."""
 
+    transport = None  # no TCP socket: the pump calls ``flush``
+
     def __init__(self, messages: int):
         self.send_queue = ["m"] * messages
         self.flushes = 0
