@@ -13,9 +13,10 @@ the machine-readable form of that discipline:
 - **Execution domains** (:data:`DOMAINS`): the named contexts code runs
   in.  Loop-thread domains (``tick-loop``, ``trunk-reader``,
   ``boot-loop``) share one OS thread; own-thread domains (wal-writer,
-  device-worker, trace-dumper, ops-http, grpc-pool, loop-offload) each
-  have their own.  ``steady`` marks the domains where blocking stalls
-  live traffic (boot/shutdown on the loop may block; a tick may not).
+  device-worker, trace-dumper, ops-http, grpc-pool, send-writer,
+  loop-offload) each have their own.  ``steady`` marks the domains
+  where blocking stalls live traffic (boot/shutdown on the loop may
+  block; a tick may not).
 - **Entry-point inference**: ``threading.Thread(target=...)``,
   ``executor.submit(fn, ...)``, ``asyncio.to_thread(fn, ...)`` and
   ``loop.run_in_executor(_, fn, ...)`` sites are scanned; every thread
@@ -222,6 +223,14 @@ DOMAINS: tuple[Domain, ...] = (
         ),
         doc="the gRPC sidecar executor pool (ops/service.py): servicer "
             "methods own a sidecar engine, not the gateway's",
+    ),
+    Domain(
+        "send-writer", thread="own", steady=False,
+        doc="the native codec's send writer (native/codec.cc): one "
+            "C++ thread that makes every send call for the TCP peers "
+            "the loop hands it bytes for; it touches no Python object "
+            "and never takes the interpreter lock, so no Python "
+            "function is reachable from it (doc/concurrency.md)",
     ),
     Domain(
         "loop-offload", thread="own", steady=False,

@@ -57,6 +57,7 @@ DOMAIN_THREADS: dict[str, str] = {
     "trace-dumper": "trace-dumper",
     "ops-http": "ops-http",
     "grpc-pool": "grpc-pool",
+    "send-writer": "send-writer",  # native: runs no Python, never bound
     "loop-offload": "loop-offload",
 }
 

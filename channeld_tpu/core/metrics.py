@@ -19,7 +19,7 @@ from prometheus_client import (
     Summary,
     start_http_server,
 )
-from prometheus_client.core import CounterMetricFamily
+from prometheus_client.core import CounterMetricFamily, GaugeMetricFamily
 
 registry = CollectorRegistry()
 
@@ -792,18 +792,11 @@ send_pump_messages = Counter(
     "send_pump_messages",
     "Messages handed to a transport, by the path that wrote them: native "
     "(the send pump's one call a pass into the codec, which encodes and "
-    "writes to the sockets of TCP peers with nothing buffered) or python "
-    "(Connection.flush: every other transport, a TCP peer with bytes "
-    "still buffered, a direct flush at a disconnect or a drain); the "
-    "two add up to messages_out",
+    "hands the packets of every TCP peer to the send writer thread) or "
+    "python (Connection.flush: every other transport, a build without "
+    "the codec, a direct flush at a disconnect or a drain); the two add "
+    "up to messages_out",
     ["path"],
-    registry=registry,
-)
-send_pump_partial_writes = Counter(
-    "send_pump_partial_writes",
-    "Native sends whose socket took less than the connection's packets "
-    "(a partial write or EAGAIN): the remainder went to the transport's "
-    "buffer, and the connection takes the python path until it drains",
     registry=registry,
 )
 fanout_window_lag_ms = SumCount(
@@ -865,12 +858,13 @@ class ThreadClocks:
     (the second field of ``/proc/self/task/<tid>/schedstat``, read only
     where that file gives a non-zero run time) as
     ``thread_runqueue_seconds_total{thread}``; and the native codec's
-    accounting of the loop thread's two timed lock releases (its
-    ``counters()``; nothing where the codec is not built):
-    ``loop_poll_seconds_total{part="asleep"|"relock"}`` and
+    accounting of the loop thread's timed lock release and of its send
+    writer thread (its ``counters()``; nothing where the codec is not
+    built): ``loop_poll_seconds_total{part="asleep"|"relock"}`` and
     ``loop_polls_total`` (core/tracing.py ``TimedSelector``),
-    ``send_pump_write_seconds_total``, ``send_pump_relock_seconds_total``
-    and ``send_pump_releases_total`` (``send_packets``)."""
+    ``send_pump_write_seconds_total``, ``send_pump_partial_writes_total``,
+    ``send_writer_wait_seconds_total``, ``send_writer_batches_total`` and
+    ``send_writer_backlog_bytes``."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -967,21 +961,33 @@ class ThreadClocks:
             value=c["polls"])
         yield CounterMetricFamily(
             "send_pump_write_seconds",
-            "Time the send pump's native passes spent in their write "
-            "loops (the send system calls), seconds",
+            "Time the send writer thread spent in its send system calls "
+            "for TCP peers, seconds; off the loop thread, so its share "
+            "of wall time is none of the loop's",
             value=c["pump_write_ns"] / 1e9)
         yield CounterMetricFamily(
-            "send_pump_relock_seconds",
-            "Time the send pump's native passes waited to win the "
-            "interpreter lock back after the write loop of a pass that "
-            "gave it up, seconds",
-            value=c["pump_relock_ns"] / 1e9)
+            "send_pump_partial_writes",
+            "The send writer's sends that left part of what they offered "
+            "unsent (a full socket): the rest waits at the head of the "
+            "connection's queue for the socket to drain",
+            value=c["partial_writes"])
         yield CounterMetricFamily(
-            "send_pump_releases",
-            "Native send passes that gave the interpreter lock up round "
-            "their write loop (those of RELEASE_AT_PACKETS packets or "
-            "more)",
-            value=c["pump_releases"])
+            "send_writer_wait_seconds",
+            "Time from a hand-off to the send writer (one connection's "
+            "packets of a pump pass, or one transport write) to its first "
+            "byte written, summed over hand-offs, seconds: the queue "
+            "delay the writer adds to a packet",
+            value=c["writer_wait_ns"] / 1e9)
+        yield CounterMetricFamily(
+            "send_writer_batches",
+            "Hand-offs to the send writer whose first byte was written",
+            value=c["writer_batches"])
+        yield GaugeMetricFamily(
+            "send_writer_backlog_bytes",
+            "Bytes handed to the send writer and not yet written, every "
+            "TCP peer together; one that keeps growing is a peer that "
+            "does not drain its socket",
+            value=c["writer_backlog"])
 
 
 # ``thread_clocks.note(name)``: the calling thread is ``loop`` (at

@@ -15,6 +15,7 @@ connection; a shared pump is the asyncio-idiomatic equivalent).
 from __future__ import annotations
 
 import asyncio
+import errno
 import gc
 import os
 import time
@@ -55,67 +56,112 @@ MAX_SEND_BUFFER = 4 * 1024 * 1024
 
 
 class TcpTransport:
-    """Byte sink over a raw asyncio.Transport (no StreamWriter layer)."""
+    """Byte sink over a raw asyncio.Transport (no StreamWriter layer).
+
+    Where the native codec is built, the codec's send writer owns the
+    socket's outbound bytes (doc/concurrency.md): every write is handed
+    to the writer thread's FIFO for this connection, named by the key
+    ``writer_register`` gave, and the asyncio transport only reads.
+    Without the codec (or where registering failed) the asyncio
+    transport writes."""
 
     def __init__(self, transport: asyncio.Transport):
         self.transport = transport
         self._sock = transport.get_extra_info("socket")
+        self._key = 0
+        register = getattr(_connection_mod._native_codec, "writer_register",
+                           None)
+        fileno = getattr(self._sock, "fileno", None)
+        if register is not None and fileno is not None:
+            try:
+                self._key = register(fileno())
+            except OSError as e:
+                logger.warning("send writer refused a socket: %s", e)
+            else:
+                _writer_transports[self._key] = self
         try:
             transport.set_write_buffer_limits(high=MAX_SEND_BUFFER)
         except (AttributeError, NotImplementedError):
             pass
 
-    def direct_fd(self) -> int:
-        """The socket's descriptor while a write may go to it past the
-        asyncio transport, -1 otherwise: the transport is open and holds
-        no unsent bytes (bytes written past a buffer would overtake
-        it). The send pump's native pass asks (``_pump_sends``)."""
-        t = self.transport
-        if self._sock is None or t.is_closing() or t.get_write_buffer_size():
-            return -1
-        return self._sock.fileno()
+    def writer_key(self) -> int:
+        """The send writer's key for this socket while the transport is
+        open, 0 otherwise: the send pump's native pass hands a batch to
+        it (``_pump_sends``)."""
+        return 0 if self.transport.is_closing() else self._key
+
+    def _release(self, drain: bool) -> None:
+        """Give the socket back: the writer closes its dup after the last
+        queued byte (``drain``) or now, dropping what it holds."""
+        key, self._key = self._key, 0
+        if key:
+            del _writer_transports[key]
+            _connection_mod._native_codec.writer_release(key, drain)
 
     def fail(self, error: OSError) -> None:
-        """A send past the transport failed: what asyncio does for a
-        failed ``transport.write``, the socket dropped unflushed and
+        """A send failed: what asyncio does for a failed
+        ``transport.write``, the socket dropped unflushed and
         ``connection_lost`` called, which closes the connection."""
+        if self.transport.is_closing():
+            return
         logger.info("tcp peer %s: %s; closing", self.remote_addr(), error)
-        self.transport.abort()
+        self.abort()
 
     def write(self, data: bytes) -> None:
         t = self.transport
         if t.is_closing():
             return
-        try:
-            buffered = t.get_write_buffer_size()
-        except (AttributeError, NotImplementedError):
-            buffered = 0
+        buffered = self.get_write_buffer_size()
         if buffered + len(data) > MAX_SEND_BUFFER:
-            # Backstop behind the edge plane's transport gate
-            # (edge_transport_high_bytes normally defers the pump well
-            # before this point); double-entry counted like every other
-            # edge reap (doc/edge_hardening.md).
-            logger.warning("tcp peer %s too slow (%d bytes unsent); closing",
-                           self.remote_addr(), buffered)
-            _edge.ledgers.count_reap("send_buffer")
-            t.close()
+            self.reap_slow(buffered)
             return
-        t.write(data)
+        if self._key:
+            err = _connection_mod._native_codec.writer_write(self._key, data)
+            if err:
+                self.fail(OSError(err, os.strerror(err)))
+        else:
+            t.write(data)
+
+    def reap_slow(self, buffered: int) -> None:
+        """A write would take the unsent bytes past ``MAX_SEND_BUFFER``:
+        the backstop behind the edge plane's transport gate
+        (edge_transport_high_bytes normally defers the pump well before
+        this point) drops it and closes, double-entry counted like every
+        other edge reap (doc/edge_hardening.md). The send pump's
+        hand-offs meet the same bound in the codec (``_pump_sends``)."""
+        logger.warning("tcp peer %s too slow (%d bytes unsent); closing",
+                       self.remote_addr(), buffered)
+        _edge.ledgers.count_reap("send_buffer")
+        self.close()
 
     def get_write_buffer_size(self) -> int:
-        """Unsent bytes buffered in the transport — the edge plane's
+        """Unsent bytes: the send writer's backlog for this socket, or the
+        asyncio transport's buffer without the writer. The edge plane's
         flush gate reads this to detect a peer not draining its socket."""
+        if self._key:
+            return _connection_mod._native_codec.writer_pending(self._key)
         try:
             return self.transport.get_write_buffer_size()
         except (AttributeError, NotImplementedError):
             return 0
 
     def close(self) -> None:
+        """The peer reads every byte handed off, then EOF."""
+        self._release(drain=True)
         if not self.transport.is_closing():
             self.transport.close()
 
+    def abort(self) -> None:
+        """Drop what is unsent; the peer reads EOF now."""
+        self._release(drain=False)
+        self.transport.abort()
+
     def remote_addr(self) -> Optional[tuple]:
         return self.transport.get_extra_info("peername")
+
+
+# The send writer's key -> its transport, for ``writer_completions``.
+_writer_transports: dict[int, TcpTransport] = {}
 
 
 class _TcpServerProtocol(asyncio.Protocol):
@@ -144,10 +190,11 @@ class _TcpServerProtocol(asyncio.Protocol):
                 sock.setsockopt(_socket.IPPROTO_TCP, _socket.TCP_NODELAY, 1)
             except OSError:
                 pass
+        tcp = TcpTransport(transport)
         try:
-            self.conn = add_connection(TcpTransport(transport), self.conn_type)
+            self.conn = add_connection(tcp, self.conn_type)
         except ConnectionRefusedError:
-            transport.abort()
+            tcp.abort()
 
     def data_received(self, data: bytes) -> None:
         conn = self.conn
@@ -194,7 +241,7 @@ class _TcpServerProtocol(asyncio.Protocol):
             # Peer reset before the read was processed: bytes lost, the
             # connection takes the unexpected-close path (recovery
             # eligibility, metrics, channel prune).
-            self.transport.abort()
+            conn.transport.abort()
             conn.close(unexpected=True)
             return None
         if _chaos.fire("transport.truncate"):
@@ -202,7 +249,7 @@ class _TcpServerProtocol(asyncio.Protocol):
             # decoder must hold the partial frame without corrupting
             # state, and teardown must not double-count.
             conn.on_bytes(bytes(data[: max(1, len(data) // 2)]))
-            self.transport.abort()
+            conn.transport.abort()
             conn.close(unexpected=True)
             return None
         if _chaos.fire("transport.corrupt"):
@@ -231,9 +278,12 @@ class _TcpServerProtocol(asyncio.Protocol):
                     pass
 
     def connection_lost(self, exc) -> None:
-        # EOF/error: an unexpected close from the peer's side.
+        # EOF/error: an unexpected close from the peer's side. The send
+        # writer's dup goes too, after what it still holds, whichever
+        # side closed first.
         if self.conn is not None:
             self.conn.close(unexpected=True)
+            self.conn.transport.close()
 
 
 class WebSocketTransport:
@@ -459,44 +509,47 @@ def _pump_sends(pending) -> int:
     """Send for every connection of ``pending`` that has output queued;
     ``monotonic_ns`` at the first one, 0 when there was none.
 
-    Each connection's batch is taken by ``Connection.take_batch`` (the
-    transport gate, the fairness cap, the envelope). A TCP peer with
-    nothing buffered before it then waits for the end of the pass,
-    where ONE call into the native codec encodes and writes every such
-    batch: ``socket.send`` gives the interpreter lock up round each
-    system call, a turn the device worker takes and the loop must win
-    back, once a message; the call gives it up at most once a pass,
-    round the whole write loop (doc/concurrency.md). Every other
-    connection (WebSocket, KCP, RUDP, a TCP peer whose transport still
-    buffers, a codec without ``send_packets``) goes through
-    ``Connection.flush`` as a direct flush does."""
+    A pass first fails every connection whose send the writer saw fail
+    since the last pass (``writer_completions``). Each connection's
+    batch is then taken by ``Connection.take_batch`` (the transport
+    gate, the fairness cap, the envelope). A TCP peer the codec's send
+    writer holds then waits for the end of the pass, where ONE call into
+    the native codec encodes every such batch and hands each to its
+    connection's FIFO on the writer thread, which makes the send calls:
+    the pass makes none and never gives the interpreter lock up
+    (doc/concurrency.md). Every other connection (WebSocket, KCP, RUDP,
+    the sim authority's in-process one, a build without the codec) goes
+    through ``Connection.flush`` as a direct flush does."""
+    codec = _connection_mod._native_codec
+    if _writer_transports:
+        for key, err in codec.writer_completions():
+            transport = _writer_transports.get(key)
+            if transport is not None:
+                transport.fail(OSError(err, os.strerror(err)))
     first = 0
-    send_packets = getattr(_connection_mod._native_codec, "send_packets", None)
     direct: list = []  # the connections of ``calls``, in its order
-    calls: list = []   # (fd, batch, compression)
+    calls: list = []   # (key, batch, compression)
     for conn in pending:
         if conn.is_closing() or not conn.send_queue:
             continue
         if not first:
             first = time.monotonic_ns()
         transport = conn.transport
-        fd = (transport.direct_fd()
-              if send_packets is not None and type(transport) is TcpTransport
-              else -1)
-        if fd < 0:
+        key = transport.writer_key() if type(transport) is TcpTransport else 0
+        if not key:
             conn.flush(fair=True)
         else:
             taken = conn.take_batch(fair=True)
             if taken is not None:
                 direct.append(conn)
-                calls.append((fd, *taken))
+                calls.append((key, *taken))
         if conn.send_queue and not conn.is_closing():
             # Fairness carry-over: the cap (or the gate) left entries
             # queued; they go out next cycle, after everyone else's turn.
             requeue_flush(conn)
     if calls:
         try:
-            results = send_packets(calls)
+            results = codec.send_packets(calls, MAX_SEND_BUFFER)
         except Exception:
             # Nothing of a connection raises (its fault is its result):
             # the call itself failed, and the pump must outlive it.
@@ -508,27 +561,25 @@ def _pump_sends(pending) -> int:
 
 
 def _account_direct(direct: list, results: list) -> None:
-    """What the native call of one pass did, a connection at a time: a
-    remainder the socket did not take goes behind the transport (its
-    buffer was empty, so order holds, and ``MAX_SEND_BUFFER`` still
-    backstops it); a failed send or encode stays with its connection;
-    the sent-counters are added once a pass for each connection type."""
+    """What the native call of one pass handed off, a connection at a
+    time: a hand-off the codec refused for ``MAX_SEND_BUFFER`` reaps its
+    connection as ``TcpTransport.write`` does; a send that failed since
+    the connection's last pass, or an encode that failed, stays with its
+    connection; the sent-counters
+    are added once a pass for each connection type."""
     sums: dict = {}  # connection type -> [a connection of it, 4 sums]
-    partial = 0
     for conn, result in zip(direct, results):
         if isinstance(result, BaseException):
             conn.logger.error("packet encode failed, dropping batch: %s",
                               result)
             continue
-        packets, nbytes, combined, msgs, rest, err = result
+        packets, nbytes, combined, msgs, err = result
+        if err == errno.ENOBUFS:
+            conn.transport.reap_slow(conn.transport.get_write_buffer_size())
+            continue
         if err:
             conn.transport.fail(OSError(err, os.strerror(err)))
-        elif rest is not None:
-            partial += 1
-            try:
-                conn.transport.write(rest)
-            except Exception as e:  # contained, as flush contains it
-                conn.logger.error("error writing packet: %s", e)
+            continue
         acc = sums.get(conn.connection_type)
         if acc is None:
             acc = sums[conn.connection_type] = [conn, 0, 0, 0, 0]
@@ -538,8 +589,6 @@ def _account_direct(direct: list, results: list) -> None:
         acc[4] += msgs
     for conn, packets, nbytes, combined, msgs in sums.values():
         conn.account_sent(packets, nbytes, combined, msgs, native=True)
-    if partial:
-        metrics.send_pump_partial_writes.inc(partial)
 
 
 async def flush_loop(interval: float = 0.001) -> None:
@@ -583,6 +632,20 @@ async def flush_loop(interval: float = 0.001) -> None:
             metrics.overload_level.set(int(governor.level))
             metrics.overload_pressure.set(governor.pressure)
         await asyncio.sleep(interval)
+
+
+async def _writer_drained(timeout: float) -> None:
+    """Wait, ``timeout`` at most, until the send writer has written what
+    it holds: the writer sends a moment after a hand-off, and a parked
+    client must read its ServerBusyMessage and EOF before the process
+    exits. A peer that does not read is not waited for past
+    ``timeout``."""
+    counters = getattr(_connection_mod._native_codec, "counters", None)
+    if counters is None:
+        return
+    deadline = time.monotonic() + timeout
+    while counters()["writer_backlog"] and time.monotonic() < deadline:
+        await asyncio.sleep(0.001)
 
 
 async def drain_gateway(listeners: Optional[list] = None) -> dict:
@@ -630,6 +693,7 @@ async def drain_gateway(listeners: Optional[list] = None) -> dict:
     for conn in list(all_connections().values()):
         if conn.connection_type == ConnectionType.CLIENT:
             conn.close()
+    await _writer_drained(1.0)
     # Trunk goodbye: peers drop the link now and the leader fast-tracks
     # the death declaration (federation/control.py on_peer_goodbye).
     if global_settings.federation_config:

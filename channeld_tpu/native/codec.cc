@@ -15,15 +15,26 @@
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
 
+#include <fcntl.h>
+#include <pthread.h>
+#include <signal.h>
 #include <sys/epoll.h>
+#include <sys/eventfd.h>
 #include <sys/socket.h>
+#include <sys/uio.h>
 #include <time.h>
+#include <unistd.h>
 
 #include <atomic>
 #include <cerrno>
 #include <cstdint>
 #include <cstring>
+#include <deque>
+#include <exception>
+#include <mutex>
 #include <string>
+#include <thread>
+#include <unordered_map>
 #include <vector>
 
 extern "C" {
@@ -46,15 +57,15 @@ static PyObject* CodecError;
 
 // ---- wait accounting -------------------------------------------------------
 //
-// Where the two lock-release sites of the loop thread spend their time
-// (epoll_wait, send_packets): the system call itself, read inside the
-// released region, and the Py_END_ALLOW_THREADS after it, which is the
-// wait to win the interpreter lock back. Relaxed atomics: each is added
-// to by one thread and read by the /metrics collector (core/metrics.py),
-// which needs no ordering between them. counters() reads them.
+// Where the loop thread's lock-release site (epoll_wait) spends its time:
+// the system call itself, read inside the released region, and the
+// Py_END_ALLOW_THREADS after it, which is the wait to win the interpreter
+// lock back; and the send writer's time in its send calls. Relaxed
+// atomics: each is added to by one thread and read by the /metrics
+// collector (core/metrics.py), which needs no ordering between them.
+// counters() reads them.
 static std::atomic<uint64_t> poll_asleep_ns{0}, poll_relock_ns{0}, polls{0};
-static std::atomic<uint64_t> pump_write_ns{0}, pump_relock_ns{0},
-    pump_releases{0};
+static std::atomic<uint64_t> pump_write_ns{0};
 
 static inline uint64_t mono_ns() {
   struct timespec ts;
@@ -356,76 +367,422 @@ static PyObject* codec_encode_packets(PyObject* self, PyObject* args) {
   return Py_BuildValue("(NN)", frames, counts);
 }
 
-// send_packets(conns) -> list
+// ---- the send writer -------------------------------------------------------
 //
-// One pass of the send pump (core/server.py _pump_sends). conns: a
-// sequence of (fd, msgs, compression), one entry a connection whose
-// socket is open, non-blocking and has nothing buffered before it. For
-// each, the packets encode_packets builds for msgs are written to fd in
-// order with send(MSG_DONTWAIT | MSG_NOSIGNAL); everything of the pass is
-// encoded before its first byte goes out, so a bad entry sends nothing.
-// The result holds, in the order given, for a connection
-//   (packets, nbytes, combined, msgs, rest, err)
-// the packets built, their bytes, how many of them hold several messages
-// and the messages in them; ``rest`` the bytes the socket did not take
-// (None when it took all: the caller buffers them behind the transport),
-// ``err`` the errno of a send that failed (0 otherwise); or, in the
-// tuple's place, the exception its encode raised, nothing sent.
+// One thread (started on first use) owns every registered TCP peer's
+// outbound bytes: the loop thread encodes and hands off, the writer makes
+// the send calls (doc/concurrency.md). It never touches a Python object
+// and never takes the interpreter lock; what it shares with the loop
+// thread is the state below, under ``mu``, which neither side holds
+// across a system call but the epoll bookkeeping.
 //
-// The interpreter lock is not given up round a send: a lock given up
-// once a message is a turn the device worker takes and the loop thread
-// must win back, once a message (doc/concurrency.md). A pass of
-// RELEASE_AT_PACKETS packets or more gives it up ONCE, round the whole
-// write loop, which touches no Python object: a send to a loopback peer
-// is ~45 us of kernel time on the benchmark's machine and winning the
-// lock back ~29 us (PERF.md section 6, PR 37), so from four packets on the
-// worker gains several times what the one release costs the loop, and
-// held through a pass of dozens of sends the lock starves the worker a
-// millisecond at a time (its step read 2.6 ms longer).
-struct PumpSend {
-  int fd = -1;
-  std::string wire;
-  long packets = 0, combined = 0, msgs = 0;
-  size_t sent = 0;
-  int err = 0;
-  PyObject* failed = nullptr;  // owned: the encode's exception
+// A peer is named by a key the writer hands out, never by its fd, and
+// the writer writes to its own dup of the socket: the kernel reuses a
+// closed fd's number, and a late buffer of a closed connection must not
+// reach the socket that takes it. Each peer has ONE FIFO of buffers, so
+// every byte of a connection, whoever handed it off, goes out in the
+// order handed off. A send that meets a full socket keeps the rest at
+// the head of the FIFO and waits for EPOLLOUT on the writer's own epoll
+// set; a send that fails drops the peer's queue, closes the dup and
+// leaves (key, errno) for writer_completions().
+struct WriterBuf {
+  std::string bytes;
+  uint64_t handed_ns;  // the hand-off's instant, for send_writer_wait
 };
 
-static const long RELEASE_AT_PACKETS = 4;
+enum { OPEN = 0, RELEASE_DRAIN = 1, RELEASE_DROP = 2 };
 
-static void write_all(std::vector<PumpSend>& sends) {
-  for (PumpSend& s : sends) {
-    while (s.sent < s.wire.size()) {
-      ssize_t took = send(s.fd, s.wire.data() + s.sent, s.wire.size() - s.sent,
-                          MSG_DONTWAIT | MSG_NOSIGNAL);
-      if (took > 0) {
-        s.sent += (size_t)took;
-      } else if (took == 0 || errno != EINTR) {
-        // Took nothing: the remainder is the transport's (EAGAIN), or
-        // the send failed.
-        if (took < 0 && errno != EAGAIN && errno != EWOULDBLOCK) s.err = errno;
+struct WriterPeer {
+  int fd = -1;                  // the writer's dup; -1 once closed
+  std::deque<WriterBuf> fifo;   // bytes not yet taken by the writer
+  size_t head_sent = 0;         // bytes of fifo.front() already written
+  size_t pending = 0;           // bytes handed off and not yet written
+  int err = 0;                  // errno of a failed send (queue dropped)
+  bool err_reported = false;    // a completion or a hand-off said so
+  bool scheduled = false;       // the writer comes back without a signal
+  bool busy = false;            // the writer is writing it, unlocked
+  bool polled = false;          // fd is in the writer's epoll set
+  int release = OPEN;
+};
+
+struct Writer {
+  pid_t pid;
+  int epfd, evfd;
+  std::mutex mu;
+  std::unordered_map<uint64_t, WriterPeer> peers;
+  std::vector<uint64_t> ready;   // keys to look at; evfd signalled
+  std::vector<uint64_t> failed;  // keys whose send failed, unreported
+};
+
+// Never freed: the thread outlives the interpreter's finalisation.
+static Writer* writer = nullptr;
+static uint64_t next_key = 1;  // 0 is the eventfd's epoll tag
+static std::mutex writer_start;
+
+// Accounting read by counters(): the writer's send calls, the wait of a
+// handed-off buffer for its first byte to go out, sends that left bytes
+// unsent, and every peer's bytes not yet written.
+static std::atomic<uint64_t> writer_wait_ns{0}, writer_batches{0},
+    partial_writes{0}, writer_backlog{0};
+
+static void writer_close(Writer* w, WriterPeer& p) {  // under mu
+  if (p.fd >= 0) {
+    if (p.polled) epoll_ctl(w->epfd, EPOLL_CTL_DEL, p.fd, nullptr);
+    close(p.fd);
+  }
+  p.fd = -1;
+  p.polled = false;
+  writer_backlog.fetch_sub(p.pending, std::memory_order_relaxed);
+  p.pending = 0;
+  p.head_sent = 0;
+  p.fifo.clear();
+  p.scheduled = false;
+}
+
+// Writes what ``key`` has queued until its FIFO is empty, its socket is
+// full, or a send fails.
+static void writer_service(Writer* w, uint64_t key) {
+  std::unique_lock<std::mutex> lock(w->mu);
+  auto found = w->peers.find(key);
+  if (found == w->peers.end()) return;
+  WriterPeer& p = found->second;  // stays put: only erase moves it
+  std::deque<WriterBuf> batch;
+  size_t off = 0;
+  for (;;) {
+    if (p.release == RELEASE_DROP || p.fd < 0) {
+      writer_close(w, p);
+      if (p.release != OPEN) w->peers.erase(key);
+      return;
+    }
+    if (batch.empty()) {
+      if (p.fifo.empty()) {
+        p.scheduled = false;
+        if (p.release == RELEASE_DRAIN) {
+          writer_close(w, p);
+          w->peers.erase(key);
+        }
+        return;
+      }
+      batch.swap(p.fifo);
+      off = p.head_sent;
+      p.head_sent = 0;
+    }
+    p.busy = true;
+    int fd = p.fd;
+    lock.unlock();
+
+    struct iovec iov[64];
+    int count = 0;
+    size_t want = 0;
+    for (size_t i = 0; i < batch.size() && count < 64; i++) {
+      const std::string& bytes = batch[i].bytes;
+      size_t from = i == 0 ? off : 0;
+      iov[count].iov_base = const_cast<char*>(bytes.data() + from);
+      iov[count].iov_len = bytes.size() - from;
+      want += bytes.size() - from;
+      count++;
+    }
+    struct msghdr msg;
+    memset(&msg, 0, sizeof(msg));
+    msg.msg_iov = iov;
+    msg.msg_iovlen = (size_t)count;
+    uint64_t began = mono_ns();
+    ssize_t took = sendmsg(fd, &msg, MSG_DONTWAIT | MSG_NOSIGNAL);
+    int err = took < 0 ? errno : 0;
+    uint64_t ended = mono_ns();
+    add(pump_write_ns, ended - began);
+    size_t done = took > 0 ? (size_t)took : 0;
+    if (done < want && err != EINTR) add(partial_writes, 1);
+    for (size_t left = done; left > 0;) {
+      WriterBuf& head = batch.front();
+      if (off == 0) {
+        add(writer_wait_ns, ended - head.handed_ns);
+        add(writer_batches, 1);
+      }
+      size_t rest = head.bytes.size() - off;
+      if (left < rest) {
+        off += left;
         break;
       }
+      left -= rest;
+      off = 0;
+      batch.pop_front();
+    }
+
+    lock.lock();
+    p.busy = false;
+    p.pending -= done;
+    writer_backlog.fetch_sub(done, std::memory_order_relaxed);
+    if (err == EINTR || p.release == RELEASE_DROP) continue;
+    if (err == EAGAIN || err == EWOULDBLOCK || (err == 0 && done < want)) {
+      // The socket is full: the rest stays at the head of the FIFO, in
+      // front of whatever was handed off meanwhile, until EPOLLOUT.
+      for (auto r = batch.rbegin(); r != batch.rend(); ++r)
+        p.fifo.push_front(std::move(*r));
+      p.head_sent = off;
+      struct epoll_event ev;
+      memset(&ev, 0, sizeof(ev));
+      ev.events = EPOLLOUT | EPOLLONESHOT;
+      ev.data.u64 = key;
+      epoll_ctl(w->epfd, p.polled ? EPOLL_CTL_MOD : EPOLL_CTL_ADD, p.fd, &ev);
+      p.polled = true;
+      return;  // still scheduled: EPOLLOUT brings the writer back
+    }
+    if (err) {
+      p.err = err;
+      writer_close(w, p);
+      if (p.release == OPEN)
+        w->failed.push_back(key);
+      else
+        w->peers.erase(key);
+      return;
     }
   }
 }
 
+static void writer_main(Writer* w) {
+  struct epoll_event events[64];
+  std::vector<uint64_t> work;
+  for (;;) {
+    int n = epoll_wait(w->epfd, events, 64, -1);
+    if (n < 0) continue;  // EINTR
+    work.clear();
+    for (int i = 0; i < n; i++) {
+      if (events[i].data.u64 == 0) {
+        uint64_t signals;
+        ssize_t got = read(w->evfd, &signals, sizeof(signals));
+        (void)got;
+      } else {
+        work.push_back(events[i].data.u64);
+      }
+    }
+    {
+      std::lock_guard<std::mutex> guard(w->mu);
+      work.insert(work.end(), w->ready.begin(), w->ready.end());
+      w->ready.clear();
+    }
+    for (uint64_t key : work) writer_service(w, key);
+  }
+}
+
+// The writer of this process, started on first use (again in a child
+// after fork: the thread does not follow it). Null with a Python error
+// set where it cannot start. Under writer_start.
+static Writer* writer_started() {
+  if (writer && writer->pid == getpid()) return writer;
+  int epfd = epoll_create1(EPOLL_CLOEXEC);
+  int evfd = eventfd(0, EFD_CLOEXEC | EFD_NONBLOCK);
+  struct epoll_event ev;
+  memset(&ev, 0, sizeof(ev));
+  ev.events = EPOLLIN;
+  ev.data.u64 = 0;
+  if (epfd < 0 || evfd < 0 || epoll_ctl(epfd, EPOLL_CTL_ADD, evfd, &ev) < 0) {
+    PyErr_SetFromErrno(PyExc_OSError);
+    if (epfd >= 0) close(epfd);
+    if (evfd >= 0) close(evfd);
+    return nullptr;
+  }
+  Writer* w = new Writer();
+  w->pid = getpid();
+  w->epfd = epfd;
+  w->evfd = evfd;
+  // Every signal blocked in the writer: the interpreter's handlers run on
+  // the main thread, and the thread inherits the mask it is created with.
+  sigset_t all, old;
+  sigfillset(&all);
+  pthread_sigmask(SIG_BLOCK, &all, &old);
+  try {
+    std::thread(writer_main, w).detach();
+  } catch (const std::exception& e) {
+    pthread_sigmask(SIG_SETMASK, &old, nullptr);
+    PyErr_Format(PyExc_OSError, "send writer: %s", e.what());
+    return nullptr;
+  }
+  pthread_sigmask(SIG_SETMASK, &old, nullptr);
+  writer = w;
+  return w;
+}
+
+// Queues ``bytes`` behind what ``p`` holds; true where the writer must be
+// signalled (the ready list was empty). Under mu.
+static bool writer_handoff(Writer* w, uint64_t key, WriterPeer& p,
+                           std::string&& bytes) {
+  size_t n = bytes.size();
+  if (!n) return false;
+  p.fifo.push_back(WriterBuf{std::move(bytes), mono_ns()});
+  p.pending += n;
+  writer_backlog.fetch_add(n, std::memory_order_relaxed);
+  if (p.scheduled) return false;
+  p.scheduled = true;
+  w->ready.push_back(key);
+  return w->ready.size() == 1;
+}
+
+static void writer_signal(Writer* w) {
+  uint64_t one = 1;
+  ssize_t put = write(w->evfd, &one, sizeof(one));
+  (void)put;
+}
+
+// The errno a hand-off to ``p`` reports: that of its failed send, which
+// writer_completions() then leaves out, or EBADF for a key the writer no
+// longer holds; 0 where the hand-off may go. Under mu.
+static int writer_refusal(WriterPeer* p) {
+  if (!p || p->release != OPEN) return EBADF;
+  if (p->err) p->err_reported = true;
+  return p->err;
+}
+
+static WriterPeer* writer_find(Writer* w, uint64_t key) {  // under mu
+  auto found = w->peers.find(key);
+  return found == w->peers.end() ? nullptr : &found->second;
+}
+
+// writer_register(fd) -> key
+static PyObject* codec_writer_register(PyObject* self, PyObject* args) {
+  int fd;
+  if (!PyArg_ParseTuple(args, "i", &fd)) return nullptr;
+  Writer* w;
+  uint64_t key;
+  {
+    std::lock_guard<std::mutex> guard(writer_start);
+    w = writer_started();
+    if (!w) return nullptr;
+    key = next_key++;
+  }
+  int own = fcntl(fd, F_DUPFD_CLOEXEC, 0);
+  if (own < 0) return PyErr_SetFromErrno(PyExc_OSError);
+  std::lock_guard<std::mutex> guard(w->mu);
+  w->peers[key].fd = own;
+  return PyLong_FromUnsignedLongLong(key);
+}
+
+// writer_write(key, data) -> errno (0: handed off)
+static PyObject* codec_writer_write(PyObject* self, PyObject* args) {
+  unsigned long long key;
+  Py_buffer data;
+  if (!PyArg_ParseTuple(args, "Ky*", &key, &data)) return nullptr;
+  std::string bytes(static_cast<const char*>(data.buf), (size_t)data.len);
+  PyBuffer_Release(&data);
+  Writer* w = writer;
+  int err = EBADF;
+  bool signal = false;
+  if (w) {
+    std::lock_guard<std::mutex> guard(w->mu);
+    WriterPeer* p = writer_find(w, key);
+    err = writer_refusal(p);
+    if (!err) signal = writer_handoff(w, key, *p, std::move(bytes));
+  }
+  if (signal) writer_signal(w);
+  return PyLong_FromLong(err);
+}
+
+// writer_release(key, drain): drain=True writes what is queued, then
+// closes the writer's dup (the peer reads EOF after the last byte);
+// drain=False drops the queue and closes it now.
+static PyObject* codec_writer_release(PyObject* self, PyObject* args) {
+  unsigned long long key;
+  int drain;
+  if (!PyArg_ParseTuple(args, "Kp", &key, &drain)) return nullptr;
+  Writer* w = writer;
+  if (!w) Py_RETURN_NONE;
+  std::lock_guard<std::mutex> guard(w->mu);
+  WriterPeer* p = writer_find(w, key);
+  if (!p || p->release != OPEN) Py_RETURN_NONE;
+  p->release = drain ? RELEASE_DRAIN : RELEASE_DROP;
+  // The writer closes a peer it is writing (after that one send), or one
+  // with bytes to drain (after the last).
+  if (!p->busy && (!drain || !p->scheduled)) {
+    writer_close(w, *p);
+    w->peers.erase(key);
+  }
+  Py_RETURN_NONE;
+}
+
+// writer_pending(key) -> bytes handed off and not yet written
+static PyObject* codec_writer_pending(PyObject* self, PyObject* args) {
+  unsigned long long key;
+  if (!PyArg_ParseTuple(args, "K", &key)) return nullptr;
+  Writer* w = writer;
+  size_t pending = 0;
+  if (w) {
+    std::lock_guard<std::mutex> guard(w->mu);
+    WriterPeer* p = writer_find(w, key);
+    if (p) pending = p->pending;
+  }
+  return PyLong_FromSize_t(pending);
+}
+
+// writer_completions() -> [(key, errno)], each failed send reported once
+static PyObject* codec_writer_completions(PyObject* self, PyObject* args) {
+  std::vector<std::pair<uint64_t, int>> out;
+  Writer* w = writer;
+  if (w) {
+    std::lock_guard<std::mutex> guard(w->mu);
+    for (uint64_t key : w->failed) {
+      WriterPeer* p = writer_find(w, key);
+      if (p && !p->err_reported && p->release == OPEN) {
+        p->err_reported = true;
+        out.emplace_back(key, p->err);
+      }
+    }
+    w->failed.clear();
+  }
+  PyObject* list = PyList_New((Py_ssize_t)out.size());
+  for (size_t i = 0; list && i < out.size(); i++) {
+    PyObject* pair = Py_BuildValue("(Ki)", (unsigned long long)out[i].first,
+                                   out[i].second);
+    if (!pair) {
+      Py_CLEAR(list);
+      break;
+    }
+    PyList_SET_ITEM(list, (Py_ssize_t)i, pair);
+  }
+  return list;
+}
+
+// send_packets(conns, cap=0) -> list
+//
+// One pass of the send pump (core/server.py _pump_sends). conns: a
+// sequence of (key, msgs, compression), one entry a connection the writer
+// holds. For each, the packets encode_packets builds for msgs are handed
+// to the writer as one buffer at the tail of the connection's FIFO; the
+// call returns without a send, and without giving the interpreter lock
+// up. A hand-off that would take the bytes the writer holds for the
+// connection past ``cap`` (where non-zero) is refused: the send buffer's
+// backstop, MAX_SEND_BUFFER. The result holds, in the order given, for a
+// connection
+//   (packets, nbytes, combined, msgs, err)
+// the packets built, their bytes, how many of them hold several messages
+// and the messages in them; ``err`` the errno of an earlier send that
+// failed, not yet reported, or ENOBUFS for a hand-off past ``cap``
+// (nothing handed off; 0 otherwise); or, in the tuple's place, the
+// exception its encode raised, nothing handed off.
+struct PumpSend {
+  uint64_t key = 0;
+  std::string wire;
+  long packets = 0, combined = 0, msgs = 0;
+  int err = 0;
+  PyObject* failed = nullptr;  // owned: the encode's exception
+};
+
 static PyObject* codec_send_packets(PyObject* self, PyObject* args) {
   PyObject* seq;
-  if (!PyArg_ParseTuple(args, "O", &seq)) return nullptr;
+  unsigned long long cap = 0;
+  if (!PyArg_ParseTuple(args, "O|K", &seq, &cap)) return nullptr;
   PyObject* fast = PySequence_Fast(seq, "send_packets expects a sequence");
   if (!fast) return nullptr;
   Py_ssize_t n = PySequence_Fast_GET_SIZE(fast);
   std::vector<PumpSend> sends((size_t)n);
   std::string scratch;
-  long total_packets = 0;
 
   for (Py_ssize_t i = 0; i < n; i++) {
     PumpSend& s = sends[(size_t)i];
+    unsigned long long key;
     PyObject* msgs;
     int compression = 0;
     bool ok =
-        PyArg_ParseTuple(PySequence_Fast_GET_ITEM(fast, i), "iOi", &s.fd,
+        PyArg_ParseTuple(PySequence_Fast_GET_ITEM(fast, i), "KOi", &key,
                          &msgs, &compression) &&
         pack_batch(msgs, [&](const std::string& body, long n_msgs) -> bool {
           const char* payload = body.data();
@@ -441,10 +798,8 @@ static PyObject* codec_send_packets(PyObject* self, PyObject* args) {
           s.msgs += n_msgs;
           return true;
         });
-    if (ok) {
-      total_packets += s.packets;
-      continue;
-    }
+    s.key = key;
+    if (ok) continue;
     // Contained to this connection: its exception is its result.
     PyObject *type, *tb;
     PyErr_Fetch(&type, &s.failed, &tb);
@@ -458,39 +813,35 @@ static PyObject* codec_send_packets(PyObject* self, PyObject* args) {
   }
   Py_DECREF(fast);
 
-  // The write loop's time, and the wait to win the lock back after it.
-  uint64_t wrote, written;
-  if (total_packets >= RELEASE_AT_PACKETS) {
-    Py_BEGIN_ALLOW_THREADS
-    wrote = mono_ns();
-    write_all(sends);
-    written = mono_ns();
-    Py_END_ALLOW_THREADS
-    add(pump_relock_ns, mono_ns() - written);
-    add(pump_releases, 1);
-  } else {
-    wrote = mono_ns();
-    write_all(sends);
-    written = mono_ns();
+  // The hand-off: one lock for the pass, one signal at most.
+  std::vector<size_t> sizes((size_t)n);
+  Writer* w = writer;
+  bool signal = false;
+  {
+    std::unique_lock<std::mutex> guard;
+    if (w) guard = std::unique_lock<std::mutex>(w->mu);
+    for (size_t i = 0; i < (size_t)n; i++) {
+      PumpSend& s = sends[i];
+      sizes[i] = s.wire.size();
+      if (s.failed) continue;
+      WriterPeer* p = w ? writer_find(w, s.key) : nullptr;
+      s.err = writer_refusal(p);
+      if (!s.err && cap && p->pending + s.wire.size() > cap) s.err = ENOBUFS;
+      if (!s.err && writer_handoff(w, s.key, *p, std::move(s.wire)))
+        signal = true;
+    }
   }
-  add(pump_write_ns, written - wrote);
+  if (signal) writer_signal(w);
 
   PyObject* results = PyList_New(n);
   for (Py_ssize_t i = 0; results && i < n; i++) {
     PumpSend& s = sends[(size_t)i];
     PyObject* result = s.failed;
     s.failed = nullptr;
-    if (!result) {
-      size_t size = s.wire.size();
-      result = (s.err || s.sent == size)
-                   ? Py_BuildValue("(nnllOi)", (Py_ssize_t)s.packets,
-                                   (Py_ssize_t)size, s.combined, s.msgs,
-                                   Py_None, s.err)
-                   : Py_BuildValue("(nnlly#i)", (Py_ssize_t)s.packets,
-                                   (Py_ssize_t)size, s.combined, s.msgs,
-                                   s.wire.data() + s.sent,
-                                   (Py_ssize_t)(size - s.sent), 0);
-    }
+    if (!result)
+      result = Py_BuildValue("(nnlli)", (Py_ssize_t)s.packets,
+                             (Py_ssize_t)sizes[(size_t)i], s.combined, s.msgs,
+                             s.err);
     if (!result) {
       Py_CLEAR(results);
       break;
@@ -544,17 +895,20 @@ static PyObject* codec_epoll_wait(PyObject* self, PyObject* args) {
   return out;
 }
 
-// counters() -> dict of the wait accounting's totals, nanoseconds and
-// counts since the library was loaded.
+// counters() -> dict of the wait accounting's and the send writer's
+// totals, nanoseconds and counts since the library was loaded, and the
+// writer's backlog in bytes now.
 static PyObject* codec_counters(PyObject* self, PyObject* args) {
   auto get = [](std::atomic<uint64_t>& c) {
     return (unsigned long long)c.load(std::memory_order_relaxed);
   };
   return Py_BuildValue(
-      "{sKsKsKsKsKsK}", "poll_asleep_ns", get(poll_asleep_ns),
+      "{sKsKsKsKsKsKsKsK}", "poll_asleep_ns", get(poll_asleep_ns),
       "poll_relock_ns", get(poll_relock_ns), "polls", get(polls),
-      "pump_write_ns", get(pump_write_ns), "pump_relock_ns",
-      get(pump_relock_ns), "pump_releases", get(pump_releases));
+      "pump_write_ns", get(pump_write_ns), "partial_writes",
+      get(partial_writes), "writer_wait_ns", get(writer_wait_ns),
+      "writer_batches", get(writer_batches), "writer_backlog",
+      get(writer_backlog));
 }
 
 // ---- inbound forward fast path ------------------------------------------
@@ -778,8 +1132,19 @@ static PyMethodDef codec_methods[] = {
     {"encode_packets", codec_encode_packets, METH_VARARGS,
      "encode_packets([(chId, bc, stub, mt, body)], compression) -> ([frames], [counts])"},
     {"send_packets", codec_send_packets, METH_VARARGS,
-     "send_packets([(fd, [(chId, bc, stub, mt, body)], compression)]) -> "
-     "[(packets, nbytes, combined, msgs, rest, err) | exception]"},
+     "send_packets([(key, [(chId, bc, stub, mt, body)], compression)], "
+     "cap=0) -> [(packets, nbytes, combined, msgs, err) | exception]"},
+    {"writer_register", codec_writer_register, METH_VARARGS,
+     "writer_register(fd) -> key: the send writer takes a dup of the socket"},
+    {"writer_write", codec_writer_write, METH_VARARGS,
+     "writer_write(key, data) -> errno, 0 where handed off"},
+    {"writer_release", codec_writer_release, METH_VARARGS,
+     "writer_release(key, drain): drain=True writes what is queued, then "
+     "closes; drain=False drops it and closes now"},
+    {"writer_pending", codec_writer_pending, METH_VARARGS,
+     "writer_pending(key) -> bytes handed off and not yet written"},
+    {"writer_completions", codec_writer_completions, METH_NOARGS,
+     "writer_completions() -> [(key, errno)] of sends that failed"},
     {"parse_forward", codec_parse_forward, METH_VARARGS,
      "parse_forward(body, conn_id, expect_channel, min_user_type) -> "
      "None | (entries, counts)"},
@@ -787,7 +1152,7 @@ static PyMethodDef codec_methods[] = {
      "epoll_wait(epfd, timeout_ms, maxevents) -> [(fd, events)], timed"},
     {"counters", codec_counters, METH_NOARGS,
      "counters() -> {poll_asleep_ns, poll_relock_ns, polls, pump_write_ns, "
-     "pump_relock_ns, pump_releases}"},
+     "partial_writes, writer_wait_ns, writer_batches, writer_backlog}"},
     {"compress", codec_compress, METH_VARARGS, "snappy compress"},
     {"uncompress", codec_uncompress, METH_VARARGS, "snappy uncompress"},
     {nullptr, nullptr, 0, nullptr},

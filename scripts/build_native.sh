@@ -3,7 +3,7 @@
 set -e
 PY_INC=$(python -c "import sysconfig; print(sysconfig.get_paths()['include'])")
 EXT=$(python -c "import sysconfig; print(sysconfig.get_config_var('EXT_SUFFIX'))")
-g++ -O2 -fPIC -shared -std=c++17 \
+g++ -O2 -fPIC -shared -std=c++17 -pthread \
     -I"$PY_INC" \
     channeld_tpu/native/codec.cc \
     -l:libsnappy.so.1 -L/usr/lib/x86_64-linux-gnu \

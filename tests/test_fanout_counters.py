@@ -48,13 +48,16 @@ def counted(channel_type) -> tuple[float, float]:
 def channel_with(channel_type, subscribers: int, **options):
     """A channel of ``channel_type`` with data, its owner, and that many
     subscribers past their first fan-out, all served at ``T0``: their
-    windows close together from then on."""
+    windows close together from then on. The channel's clock stands at
+    0 while they subscribe, so each first window closes before ``T0``
+    however long a loaded host takes to make them."""
     owner = StubConnection(1, ConnectionType.SERVER)
     if channel_type == ChannelType.ENTITY:
         ch = create_entity_channel(
             global_settings.entity_channel_id_start + 7, owner)
     else:
         ch = create_channel(channel_type, owner)
+    ch.get_time = lambda: 0
     ch.init_data(testdata_pb2.TestChannelDataMessage(text="a", num=1), None)
     conns = [StubConnection(10 + i) for i in range(subscribers)]
     for conn in conns:

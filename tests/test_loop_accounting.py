@@ -1,7 +1,7 @@
 """Where the loop thread's and the device worker's wall time goes: the
 event loop's timed wait (``core/tracing.py TimedSelector`` over the
-native codec's ``epoll_wait``), the send pump's write loop and the lock
-it wins back (``send_packets``), each named thread's CPU and run-queue
+native codec's ``epoll_wait``), the send calls the pump hands to the
+codec's writer thread (``send_packets``), each named thread's CPU and run-queue
 time read at scrape (``core/metrics.py ThreadClocks``), and the ingest
 stages on the device trace's clock while a profiler session is live.
 
@@ -260,12 +260,14 @@ def test_without_the_codec_the_stock_selector_and_no_counter(monkeypatch):
     names = {name for name, _ in scrape()}
     assert not names & {"loop_poll_seconds_total", "loop_polls_total",
                         "send_pump_write_seconds_total",
-                        "send_pump_relock_seconds_total",
-                        "send_pump_releases_total"}
+                        "send_pump_partial_writes_total",
+                        "send_writer_wait_seconds_total",
+                        "send_writer_batches_total",
+                        "send_writer_backlog_bytes"}
 
 
 # ---------------------------------------------------------------------------
-# the send pump's write loop
+# the send pump's hand-off and the writer's send calls
 # ---------------------------------------------------------------------------
 
 
@@ -274,25 +276,37 @@ def batch(n: int) -> list:
     return [(0, 0, 0, 100 + i, bytes([i]) * 40_000) for i in range(n)]
 
 
-@pytest.mark.skipif(getattr(codec, "send_packets", None) is None,
-                    reason="the native codec (with send_packets) is not "
+@pytest.mark.skipif(getattr(codec, "writer_register", None) is None,
+                    reason="the native codec (with its send writer) is not "
                            "built")
-@pytest.mark.parametrize("packets, releases", [(4, 1), (6, 1), (1, 0)])
+@pytest.mark.parametrize("packets", [4, 6, 1])
 def test_a_pass_adds_its_write_time_and_one_release_from_four_packets(
-        packets, releases):
+        packets):
+    """However many packets a pass holds, it hands them off and gives
+    the lock up nowhere (no release is counted at all); the writer's
+    send calls add their time, and the hand-off one batch and its
+    wait."""
     a, b = socket.socketpair()
     a.setblocking(False)
     a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 1 << 22)
+    key = codec.writer_register(a.fileno())
     try:
         before = counters()
-        (result,) = codec.send_packets([(a.fileno(), batch(packets), 0)])
+        (result,) = codec.send_packets([(key, batch(packets), 0)])
+        assert result[0] == packets and not result[4]
+        deadline = time.monotonic() + 10
+        while codec.writer_pending(key):
+            assert time.monotonic() < deadline, "the writer did not drain"
+            time.sleep(0.001)
         after = counters()
-        assert result[0] == packets
-        assert after["pump_releases"] - before["pump_releases"] == releases
         assert after["pump_write_ns"] > before["pump_write_ns"]
-        if not releases:
-            assert after["pump_relock_ns"] == before["pump_relock_ns"]
+        assert after["writer_batches"] - before["writer_batches"] == 1
+        assert after["writer_wait_ns"] > before["writer_wait_ns"]
+        got = scrape()
+        assert ("send_pump_releases_total", ()) not in got
+        assert ("send_pump_relock_seconds_total", ()) not in got
     finally:
+        codec.writer_release(key, False)
         a.close()
         b.close()
 
@@ -306,11 +320,16 @@ def test_the_codecs_counters_reach_metrics():
         pytest.approx(c["poll_asleep_ns"] / 1e9)
     assert got[("loop_poll_seconds_total", (("part", "relock"),))] == \
         pytest.approx(c["poll_relock_ns"] / 1e9)
-    assert got[("send_pump_releases_total", ())] == c["pump_releases"]
     assert got[("send_pump_write_seconds_total", ())] == \
         pytest.approx(c["pump_write_ns"] / 1e9)
-    assert got[("send_pump_relock_seconds_total", ())] == \
-        pytest.approx(c["pump_relock_ns"] / 1e9)
+    assert got[("send_pump_partial_writes_total", ())] == c["partial_writes"]
+    assert got[("send_writer_wait_seconds_total", ())] == \
+        pytest.approx(c["writer_wait_ns"] / 1e9)
+    assert got[("send_writer_batches_total", ())] == c["writer_batches"]
+    assert got[("send_writer_backlog_bytes", ())] == c["writer_backlog"]
+    # The pump gives the lock up nowhere: nothing counts a release.
+    assert not {name for name, _ in got} & {
+        "send_pump_releases_total", "send_pump_relock_seconds_total"}
 
 
 # ---------------------------------------------------------------------------
