@@ -1,11 +1,14 @@
-"""One run of one cell: boot the gateway, build the world over the wire,
-warm up, measure for the window, drain, hold every answer against the
-reference, and make the result the contract's last line carries.
+"""One run of one cell: boot the gateway, have the cell's flow build its
+deployment over the wire, warm up, measure for the window, drain, and
+make the result the contract's last line carries from what the flow
+held against its reference.
 
 Everything that belongs to one configuration, one mix, one generator,
-one kernel or one per-layer metric is data or a file of its own, found
-from ``BENCHMARK.json`` by name (``load_cell``). This module is what is
-left: the flow that all of them share.
+one kernel, one per-layer metric or one kind of deployment (a flow) is
+data or a file of its own, found from ``BENCHMARK.json`` by name
+(``load_cell``, ``load_flow``). This module is what every deployment
+shares: the gateway, the master peer and GLOBAL, the clocks of the run,
+the gateway's own counters and trace, and the result.
 """
 
 from __future__ import annotations
@@ -20,12 +23,9 @@ import subprocess
 import sys
 import time
 
-import numpy as np
-
 from . import stats, wire
 from .gateway import REPO, BenchFailure, Gateway, delta, total
-from .reference import Grid
-from .workers import CELL_PATH, plan, run_worker
+from .workers import run_worker
 
 TRACE_S = 3.0  # the traced part of a --trace 1 window
 
@@ -77,6 +77,27 @@ def load_file(path: str, name: str):
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def load_flow(cell: dict):
+    """The module that builds and accounts for the cell's kind of
+    deployment: ``flows/<flow>.py`` for the ``flow`` its configuration
+    names, ``spatial`` where it names none.
+
+    A flow gives ``WORKERS`` (its peer kinds, ``harness/workers.py:Worker``
+    subclasses, by name); ``prepare(cell, seed, seconds)``, before the
+    gateway boots, with ``specs``, a ``(kind, spec)`` for each worker
+    process; ``build(cell, prepared, gw, master, peers, workers)`` once
+    GLOBAL exists, which makes the world and returns it; ``after_window(
+    world, workers)`` once the drain is over; and ``account(cell, run)``
+    (``assemble`` says what it returns)."""
+    name = cell["config"].get("flow", "spatial")
+    where = os.path.join(cell["base"], "flows")
+    path = os.path.join(where, name + ".py")
+    if not os.path.isfile(path):
+        there = sorted(f[:-3] for f in os.listdir(where) if f.endswith(".py"))
+        raise BenchFailure(f"no flow {name!r}; there are: {', '.join(there)}")
+    return load_file(path, "flow_" + name)
 
 
 def roofline_pct(ctx: dict, kernel: str):
@@ -194,49 +215,29 @@ class Workers:
                 proc.join()
 
 
-def _split(items: list, parts: int) -> list:
-    return [items[i::parts] for i in range(parts)]
-
-
 def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
              started: float, need_platform: str = "tpu", control: str = "",
              fault: str = "") -> dict:
     """Drive one run; returns the result the last line carries."""
+    flow = load_flow(cell)
     config, mix = cell["config"], cell["mix"]
-    pop = config["populations"]
     out_dir = os.path.join(cell["base"], "out", cell["name"])
     shutil.rmtree(out_dir, ignore_errors=True)  # the last run's logs
     os.makedirs(out_dir)
     build_native()
-    scc = os.path.join(REPO, config["world"]["scc"])
-    grid = Grid.load(scc, config["world"].get("cell_start", 0x10000),
-                     config["world"].get("entity_start", 0x80000))
     warmup, drain = float(mix["warmup_s"]), float(mix["drain_s"])
-    servers = [f"bench-spatial-{i}" for i in range(grid.num_servers)]
-    spec = {
-        "repo": REPO, "scc": scc, "cell_start": grid.cell_start,
-        "entity_start": grid.entity_start, "mix": mix, "seed": seed,
-        "entities": pop["wire_entities"], "clients_total": pop["clients"],
-        "radius": pop["client_radius"], "all_servers": servers,
-        "seconds_total": warmup + seconds + drain + 1.0,
-        "window": (warmup, warmup + seconds),
-    }
-    the_plan = plan(spec)
-    centres = the_plan["centres"]
-    say(f"layout {the_plan['layouts_drawn']} of seed {seed} offers "
-        f"{the_plan['deliveries_per_update']:.4f} deliveries an update")
+    prepared = flow.prepare(cell, seed, seconds)
 
     say(f"{cell['name']}: booting the gateway")
     gw = Gateway(config["gateway_argv"], out_dir, control, fault,
                  settings=config.get("settings"))
-    workers = master = None
+    workers = None
+    peers = wire.Peers()  # the master's
     try:
-        n_send, n_recv = config["workers"]["senders"], config["workers"]["receivers"]
-        specs = [("sender", dict(spec, sport=gw.sport, servers=part))
-                 for part in _split(servers, n_send)]
-        specs += [("receiver", dict(spec, cport=gw.cport, clients=part))
-                  for part in _split(list(range(pop["clients"])), n_recv)]
-        workers = Workers(specs)  # they import and plan while it boots
+        workers = Workers([  # they import and plan while it boots
+            (kind, dict(spec, repo=REPO, flow=flow.__file__,
+                        sport=gw.sport, cport=gw.cport))
+            for kind, spec in prepared["specs"]])
         gw.wait_listening(1100.0)
         engine = gw.introspect().get("engine") or {}
         say(f"listening after {time.monotonic() - gw.started:.1f}s on "
@@ -250,225 +251,93 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
                 f"the gateway holds {engine.get('device_count')} devices, the "
                 f"cell asks for {cell['chips']}")
 
-        master = wire.connect(gw.sport, "bench-master")
+        master = wire.connect(gw.sport, "bench-master", peers)
         master.queue(0, wire.CREATE_CHANNEL,
                      wire.control_pb2.CreateChannelMessage(
                          channelType=wire.GLOBAL).SerializeToString())
-        wire.pump([master], 0.3)
-        claimed = {}
-        for answer in workers.call("sender", "claim"):
-            claimed.update(answer)
-        blocks = {}
-        for c in range(grid.num_cells):
-            blocks.setdefault(grid.server_of_cell(c), set()).add(c)
-        want_blocks = sorted(map(sorted, blocks.values()))
-        checks = {"server_blocks_mismatched": sum(
-            a != b for a, b in zip(sorted(claimed.values()), want_blocks))
-            + abs(len(claimed) - len(want_blocks))}
-        owner_of = {c: servers.index(name)
-                    for name, cells in claimed.items() for c in cells}
-        workers.call("sender", "ready", owner_of)
-        agents = pop.get("sim_agents", 0)
-        if agents:
-            say(f"waiting for {agents} agents")
-            _wait_metric(gw, [master], "sim_agents_num",
-                         lambda s: total(s, "sim_agents_num") >= agents, 600.0)
-        say(f"creating {pop['wire_entities']} wire entities, connecting "
-            f"{pop['clients']} clients")
-        workers.call("sender", "spawn")
-        conn_ids = workers.call("receiver", "connect", timeout=600.0)
-        parts = _split(list(range(pop["clients"])), n_recv)
-        for part, ids in zip(parts, conn_ids):
-            for i, conn_id in zip(part, ids):
-                cx, cz, _ = centres[i]
-                master.queue(grid.cell_start, wire.INTEREST,
-                             wire.sphere_interest(conn_id, cx, cz,
-                                                  pop["client_radius"]))
-            wire.pump([master], 0.05)
-        want_cells = [sorted(cells) for _, _, cells in centres]
-
-        def interest_mismatched() -> int:
-            got = workers.call("receiver", "subs")
-            return sum(got[w][j] != want_cells[i]
-                       for w, part in enumerate(parts)
-                       for j, i in enumerate(part))
-
-        resident = pop["wire_entities"] + agents
-        _wait_metric(gw, [master], "tpu_entities",
-                     lambda s: total(s, "tpu_entities") >= resident, 120.0)
-        end = time.monotonic() + 90.0
-        while interest_mismatched() and time.monotonic() < end:
-            wire.pump([master], 1.0)
-        ladder = _settle(gw, [master])
+        peers.pump(0.3)
+        world = flow.build(cell, prepared, gw, master, peers, workers)
+        ladder = _settle(gw, peers)
         say(f"ladder before the window: {ladder}")
 
         # ---- warm-up, window, drain ----
         t0 = time.monotonic() + 0.5
         w0, w1 = t0 + warmup, t0 + warmup + seconds
         end = w1 + drain
-        running = workers.start(("sender", "receiver"), "go", t0, w1, end)
+        running = workers.start(set(workers.kinds), "go", t0, w1, end)
         setup_s = w0 - started
         say(f"set-up took {setup_s:.1f}s; warm-up {warmup:g}s, window "
             f"{seconds:g}s, drain {drain:g}s")
-        _sleep_until(w0, [master])
+        _sleep_until(w0, peers)
         m0, c0, at0 = gw.metrics(), cache_entries(), time.monotonic()
         traced_s = None
         trace_dir = os.path.join(out_dir, "trace")
         if trace:
-            _sleep_until(w0 + 1.0, [master])
+            _sleep_until(w0 + 1.0, peers)
             answer = gw.ask(f"trace_start {trace_dir}")
             if not answer.get("ok"):
                 raise BenchFailure(f"trace_start: {answer}")
-            _sleep_until(time.monotonic() + TRACE_S, [master])
+            _sleep_until(time.monotonic() + TRACE_S, peers)
             traced_s = gw.ask("trace_stop").get("traced_s")
         # No scrape inside the window: rendering /metrics holds the
         # gateway's interpreter for milliseconds.
-        _sleep_until(w1, [master])
+        _sleep_until(w1, peers)
         m1, c1, at1 = gw.metrics(), cache_entries(), time.monotonic()
         levels = [int(total(m, "overload_level")) for m in (m0, m1)]
         pressures = [round(total(m, "overload_pressure"), 3) for m in (m0, m1)]
 
-        answers = workers.collect(running, "go",
-                                  end + 60.0 - time.monotonic())
-        sent = [a for i, a in zip(running, answers)
-                if workers.kinds[i] == "sender"]
-        received = [a for i, a in zip(running, answers)
-                    if workers.kinds[i] == "receiver"]
-        checks["interest_mismatched"] = interest_mismatched()
+        answers: dict = {}  # each kind's answers, in the workers' order
+        for i, answer in zip(running, workers.collect(
+                running, "go", end + 60.0 - time.monotonic())):
+            answers.setdefault(workers.kinds[i], []).append(answer)
+        flow.after_window(world, workers)
         memory = gw.ask("memory")
         state = gw.introspect().get("device")
         m2 = gw.metrics()
         workers.close()
         workers = None
-        master.close()
-        master = None
+        peers.close()
         say("draining the gateway")
         gw.drain()
     finally:
         if workers is not None:
             workers.close()
-        if master is not None:
-            master.close()
+        peers.close()
         gw.close()
         for path in sorted(glob.glob(os.path.join(out_dir, "profiles", "*")),
                            key=os.path.getmtime)[:-4]:
             os.remove(path)
 
-    return account(cell, {
-        "plan": the_plan, "grid": grid, "owner_of": owner_of, "checks": checks,
-        "t0": t0, "w0": w0, "w1": w1, "end": end, "seconds": seconds,
-        "setup_s": setup_s, "sent": sent, "received": received,
-        "window": delta(m1, m0), "wall_s": at1 - at0, "at_end": m2,
-        "compiles": c1 - c0, "levels": levels, "pressures": pressures,
-        "ladder": ladder,
-        "engine": engine, "memory": memory, "device_state": state,
-        "trace_dir": trace_dir if trace else None, "traced_s": traced_s,
-        "chip_needed": bool(need_platform)})
+    return assemble(cell, flow, dict(
+        world, answers=answers, t0=t0, w0=w0, w1=w1, end=end,
+        seconds=seconds, setup_s=setup_s, window=delta(m1, m0),
+        wall_s=at1 - at0, at_end=m2, compiles=c1 - c0, levels=levels,
+        pressures=pressures, ladder=ladder, engine=engine, memory=memory,
+        device_state=state, trace_dir=trace_dir if trace else None,
+        traced_s=traced_s, chip_needed=bool(need_platform)))
 
 
-def account(cell: dict, run: dict) -> dict:
-    """From what the peers and the gateway recorded to the result: every
-    answer held against the reference, and the metrics."""
-    plan_, t0, w0, w1 = run["plan"], run["t0"], run["w0"], run["w1"]
-    horizon, owner_of, checks = run["end"], run["owner_of"], run["checks"]
-    sent, received = run["sent"], run["received"]
-    config, mix = cell["config"], cell["mix"]
-
-    # ---- deliveries ----
-    cols = {c: np.concatenate([r[c] for r in received])
-            for c in ("client", "k", "n", "due", "read")}
-    due, read = cols["due"], cols["read"]
-    in_window = (due >= w0) & (due < w1)
-    lat = stats.latency_ms(due[in_window], read[in_window], horizon)
-    unreflected = int(np.isnan(read[in_window]).sum())
-    reflected_in_window = int(((read >= w0) & (read < w1)).sum())
-    cross_at = plan_["cells"] != plan_["prev"]
-    unreflected_sample = [
-        {"client": int(cols["client"][i]), "n": int(cols["n"][i]),
-         "k": int(cols["k"][i]),
-         "crossing": bool(cross_at[cols["k"][i], cols["n"][i]])}
-        for i in np.nonzero(in_window & np.isnan(read))[0][:8]]
-    lags = np.concatenate([r["lags"] for r in received])
-    lags = lags[(lags[:, 1] >= w0) & (lags[:, 1] < w1)]
-    half = (w0 + w1) / 2.0
-    cell_rows_ms = lags[lags[:, 0] == CELL_PATH, 2] * 1000.0  # each row read
-    lag_by_path = {}  # how stale each path's rows ran, and whether it grew
-    for path, name in enumerate(("cell", "entity", "handover")):
-        for part, rows in (("first_half", lags[:, 1] < half),
-                           ("second_half", lags[:, 1] >= half)):
-            v = lags[(lags[:, 0] == path) & rows, 2] * 1000.0
-            if len(v):
-                lag_by_path[f"{name}.{part}"] = {
-                    "rows": len(v), "p50_ms": round(stats.percentile(v, 50), 1),
-                    "p95_ms": round(stats.percentile(v, 95), 1)}
-
-    # ---- handovers ----
-    k, n, src, dst = stats.crossings(plan_["start_cells"], plan_["cells"])
-    cross_due = t0 + plan_["due"][k, n]
-    log = np.concatenate([s["handovers"] for s in sent])
-    seen: dict = {}
-    reads_of: dict = {}
-    for t, server, en, s, d in log[np.argsort(log[:, 0], kind="stable")]:
-        key = (int(server), int(en), int(s), int(d))
-        seen[key] = seen.get(key, 0) + 1
-        reads_of.setdefault(key, []).append(t)
-    sends = np.concatenate([s["sent"] for s in sent])
-    sent_at = {(int(a), int(b)): t for a, b, t in sends[:, :3]}
-    times: dict = {}  # crossings that must be complete: due before the close
-    owners: dict = {}
-    ho_ms, ho_failed, ho_early, nth = [], 0, 0, {}
-    for kk, nn, s, d, due_t in zip(k.tolist(), n.tolist(), src.tolist(),
-                                   dst.tolist(), cross_due.tolist()):
-        pair = (nn, s, d)
-        nth[pair] = nth.get(pair, 0) + 1
-        if due_t >= w1:
-            continue  # the drain's own crossings need not complete
-        times[pair] = nth[pair]
-        owners[pair] = {owner_of[s], owner_of[d]}
-        reads = reads_of.get((owner_of[d], nn, s, d), ())
-        done = reads[nth[pair] - 1] if len(reads) >= nth[pair] else None
-        if done is not None and done < sent_at.get((kk, nn), 0.0):
-            ho_early += 1  # read before the update that crossed was sent
-        if due_t >= w0:
-            ho_ms.append(((horizon if done is None else done) - due_t)
-                         * 1000.0)
-            ho_failed += done is None
-    checks.update(stats.handover_account(times, owners, seen))
-    # A crossing of the drain may be read or not; it is never unpredicted,
-    # and is duplicated only when read more often than it happens at all.
-    checks["handovers_unpredicted"] = sum(
-        got for key, got in seen.items() if key[1:] not in nth)
-    checks["handovers_duplicated"] = sum(
-        max(0, got - nth[key[1:]]) for key, got in seen.items()
-        if key[1:] in nth)
-    checks["handovers_early"] = ho_early
-    checks["deliveries_unreflected"] = unreflected
-    checks["rows_wrong"] = sum(r["wrong_rows"] for r in received)
-    checks["device_faults"] = int(
+def assemble(cell: dict, flow, run: dict) -> dict:
+    """The result, from what the flow accounts for and what the gateway
+    recorded. The flow's ``account(cell, run)`` returns the due and read
+    times (NaN: never read) of the deliveries due in the window, the
+    count ``reflected_in_window`` (read inside it, whenever due),
+    ``attempted``, ``failed``, its ``checks``, its ``info`` and what it
+    adds to the readers' ``ctx``."""
+    out = flow.account(cell, run)
+    # device_faults first: the ledger keeps the head of a refused run's
+    # checks.
+    checks = {"device_faults": int(
         total(run["at_end"], "device_recoveries_total")
         + total(run["at_end"], "device_step_failures_total")
-        + (run["device_state"] != "ACTIVE"))
+        + (run["device_state"] != "ACTIVE")), **out["checks"]}
 
-    # ---- the generator's own account ----
-    send_due = t0 + plan_["due"][sends[:, 0].astype(int),
-                                 sends[:, 1].astype(int)]
-    sent_in_window = (send_due >= w0) & (send_due < w1)
-    late = (sends[:, 2] - sends[:, 3] > mix["frame_ms"] / 1000.0)
-    generator = {
-        "updates_in_window": int(sent_in_window.sum()),
-        "late": int((late & sent_in_window).sum()),
-        "unsent": sum(s["unsent"] for s in sent),
-        "rows_read": sum(r["rows"] for r in received),
-        "entity_subs_at_end": sum(r["entity_subs"] for r in received),
-    }
-    checks["updates_unsent"] = generator["unsent"]
-
-    # ---- the result ----
     engine, window = run["engine"], run["window"]
+    lat = stats.latency_ms(out["due"], out["read"], run["end"])
     e2e = {
         "delivery_p50_ms": stats.percentile(lat, 50),
         "delivery_p95_ms": stats.percentile(lat, 95),
-        "deliveries_per_s": reflected_in_window / run["seconds"],
+        "deliveries_per_s": out["reflected_in_window"] / run["seconds"],
         "setup_s": run["setup_s"],
     }
     device = {"platform": engine.get("platform"),
@@ -476,24 +345,16 @@ def account(cell: dict, run: dict) -> dict:
               "count": engine.get("device_count"),
               "memory_peak_bytes": run["memory"].get("peak_bytes_in_use", 0)}
     result = {"correct": not any(checks.values()),
-              "attempted": int(in_window.sum()) + len(ho_ms),
-              "failed": unreflected + ho_failed}
-    info = {"crossings_in_window": len(ho_ms),
-            "handover_p95_ms": stats.percentile(ho_ms, 95) if ho_ms else None,
-            "deliveries_in_window": int(in_window.sum()),
-            "layouts_drawn": plan_["layouts_drawn"],
-            "offered_per_update": plan_["deliveries_per_update"],
-            "ladder": run["ladder"],
-            "ladder_at_window_ends": run["levels"],
-            "pressure_at_window_ends": run["pressures"],
-            "generator": generator,
-            "unreflected_sample": unreflected_sample,
-            "new_row_lag_by_path": lag_by_path,
-            "sheds_in_window": total(window, "overload_sheds_total"),
-            "global_ticks_in_window": total(
-                window, "tick_stage_ms_count", stage="device_step"),
-            "use_pallas": engine.get("use_pallas"),
-            "native_codec": engine.get("native_codec")}
+              "attempted": out["attempted"], "failed": out["failed"]}
+    info = dict(out["info"],
+                ladder=run["ladder"],
+                ladder_at_window_ends=run["levels"],
+                pressure_at_window_ends=run["pressures"],
+                sheds_in_window=total(window, "overload_sheds_total"),
+                global_ticks_in_window=total(
+                    window, "tick_stage_ms_count", stage="device_step"),
+                use_pallas=engine.get("use_pallas"),
+                native_codec=engine.get("native_codec"))
     if run["trace_dir"] is None:
         result["metrics"] = {
             m["name"]: {"value": e2e[m["name"]], "unit": m["unit"]}
@@ -511,26 +372,10 @@ def account(cell: dict, run: dict) -> dict:
             # are rare.
             reduced["window_s"] = max(run["traced_s"] or 0.0,
                                       reduced["window_s"])
-        dev, pop = config["device"], config["populations"]
-        ctx = {
-            "base": cell["base"], "metrics": window, "wall_s": run["wall_s"],
-            "trace": reduced if reduced.get("device_planes") else None,
-            "peak": cell["peaks"].get(kind), "generator": generator,
-            "compiles": run["compiles"], "cell_rows_ms": cell_rows_ms,
-            "handover_ms": np.array(ho_ms),
-            # What a kernel needs is reckoned over the rows that are live,
-            # not over the arrays' width: the entities on the device, the
-            # clients' queries, and the spatial subscriptions (each
-            # client's cells, and one owner a cell). Padding is no work.
-            "shapes": {"entities": pop["wire_entities"]
-                       + pop.get("sim_agents", 0),
-                       "queries": pop["clients"],
-                       "subs": sum(len(c) for _, _, c in plan_["centres"])
-                       + run["grid"].num_cells,
-                       "max_handovers": dev["max_handovers"],
-                       "query_rows_max": dev["query_rows_max"],
-                       "cells": run["grid"].num_cells},
-        }
+        ctx = dict(out["ctx"], base=cell["base"], metrics=window,
+                   wall_s=run["wall_s"], compiles=run["compiles"],
+                   trace=reduced if reduced.get("device_planes") else None,
+                   peak=cell["peaks"].get(kind))
         result["metrics"] = {}
         for m in cell["per_layer"]:
             reader = load_file(os.path.join(
@@ -570,15 +415,15 @@ def reduce_trace(trace_dir: str) -> dict:
     return json.loads(done.stdout.strip().splitlines()[-1])
 
 
-def _sleep_until(when: float, peers: list) -> None:
+def _sleep_until(when: float, peers) -> None:
     while True:
         left = when - time.monotonic()
         if left <= 0:
             return
-        wire.pump(peers, min(left, 0.5))
+        peers.pump(min(left, 0.5))
 
 
-def _wait_metric(gw, peers, what: str, pred, timeout: float) -> dict:
+def wait_metric(gw, peers, what: str, pred, timeout: float) -> dict:
     """Poll ``/metrics`` (paced: a tight scrape loop is load)."""
     end = time.monotonic() + timeout
     while True:
@@ -588,7 +433,7 @@ def _wait_metric(gw, peers, what: str, pred, timeout: float) -> dict:
         if time.monotonic() >= end:
             raise BenchFailure(f"timed out after {timeout:.0f}s waiting for "
                                f"{what}")
-        wire.pump(peers, 1.0)
+        peers.pump(1.0)
 
 
 def _settle(gw, peers, timeout: float = 60.0) -> dict:
@@ -597,7 +442,7 @@ def _settle(gw, peers, timeout: float = 60.0) -> dict:
     on: it will count its failures."""
     t0, calm, level = time.monotonic(), 0, -1
     while calm < 3 and time.monotonic() - t0 < timeout:
-        wire.pump(peers, 1.0)
+        peers.pump(1.0)
         now = gw.metrics()
         level = int(total(now, "overload_level"))
         calm = calm + 1 if level == 0 else 0

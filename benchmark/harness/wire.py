@@ -10,6 +10,7 @@ client SDK cannot move what the benchmark times. A frame's read time is
 
 from __future__ import annotations
 
+import selectors
 import socket
 import struct
 import time
@@ -109,6 +110,8 @@ class Peer:
     def flush(self) -> bool:
         """Frame what is queued and write what the socket takes; True
         while bytes are left over for a later call."""
+        if not self._queued and not self._out:
+            return False
         packet, size = wire_pb2.Packet(), 0
         for mp in self._queued:
             n = mp.ByteSize() + 6
@@ -187,38 +190,86 @@ class Peer:
             pass
 
 
-def pump(peers, seconds: float, until=None, on_message=None) -> bool:
-    """Read and flush ``peers`` for ``seconds`` or until ``until()``;
-    ``on_message(peer, read time, MessagePack)`` sees what arrives."""
-    import select
+class Peers:
+    """The peers of one process on one selector (epoll on Linux, which
+    takes any fd number, where ``select`` refuses one of 1,024 or more).
+    A peer is registered once, for reading; it is watched for writing
+    only while bytes are left over from its last ``flush``."""
 
-    end = time.monotonic() + seconds
-    while True:
-        if until is not None and until():
-            return True
-        left = end - time.monotonic()
-        if left <= 0:
-            return until is None
-        writers = [p for p in peers if p.flush()]
-        readable, _, _ = select.select(peers, writers, [], min(left, 0.05))
-        for p in readable:
-            for t, mp in p.read():
-                if on_message is not None:
-                    on_message(p, t, mp)
+    def __init__(self):
+        self._selector = selectors.DefaultSelector()
+        self._peers: list = []
+        self._writing: set = set()
+
+    def __iter__(self):
+        return iter(self._peers)
+
+    def __len__(self) -> int:
+        return len(self._peers)
+
+    def add(self, peer: Peer) -> None:
+        self._selector.register(peer.sock, selectors.EVENT_READ, peer)
+        self._peers.append(peer)
+
+    def discard(self, peer: Peer) -> None:
+        self._selector.unregister(peer.sock)
+        self._peers.remove(peer)
+        self._writing.discard(peer)
+
+    def flush(self) -> None:
+        """Flush every peer; watch for writing those with bytes left."""
+        for p in self._peers:
+            left = p.flush()
+            if left != (p in self._writing):
+                (self._writing.add if left else self._writing.discard)(p)
+                self._selector.modify(p.sock, selectors.EVENT_READ | (
+                    selectors.EVENT_WRITE if left else 0), p)
+
+    def wait(self, timeout: float, on_message=None) -> None:
+        """Wait up to ``timeout`` and read every peer that is readable;
+        ``on_message(peer, read time, MessagePack)`` sees what arrives."""
+        for key, events in self._selector.select(timeout):
+            if events & selectors.EVENT_READ:
+                p = key.data
+                for t, mp in p.read():
+                    if on_message is not None:
+                        on_message(p, t, mp)
+
+    def pump(self, seconds: float, until=None, on_message=None) -> bool:
+        """Read and flush for ``seconds`` or until ``until()``."""
+        end = time.monotonic() + seconds
+        while True:
+            if until is not None and until():
+                return True
+            left = end - time.monotonic()
+            if left <= 0:
+                return until is None
+            self.flush()
+            self.wait(min(left, 0.05), on_message)
+
+    def close(self) -> None:
+        for p in self._peers:
+            p.close()
+        self._peers.clear()
+        self._writing.clear()
+        self._selector.close()
 
 
-def connect(port: int, name: str, others=(), attempts: int = 1) -> Peer:
-    """Connect and AUTH. At overload L3 the gateway answers a client's
-    AUTH with ServerBusy and closes: try again, paced."""
+def connect(port: int, name: str, peers: Peers, attempts: int = 1) -> Peer:
+    """Connect and AUTH a peer, and keep it among ``peers``, which are
+    pumped meanwhile. At overload L3 the gateway answers a client's AUTH
+    with ServerBusy and closes: try again, paced."""
     for _ in range(attempts):
         p = Peer(port, name)
+        peers.add(p)
         p.auth()
         try:
-            pump([p, *others], 5.0, until=lambda: p.conn_id or p.busy)
+            peers.pump(5.0, until=lambda: p.conn_id or p.busy)
         except PeerClosed:
             pass
         if p.conn_id:
             return p
+        peers.discard(p)
         p.close()
         time.sleep(1.0)
     raise PeerClosed(f"{name}: AUTH refused {attempts} times")

@@ -1,11 +1,13 @@
-"""The peers: spatial servers that send, clients that receive, each kind
-in a few worker processes. A worker is a selector loop over its own
-sockets and never touches jax. The parent tells it what to do over a
-pipe, one phase at a time, and gets each phase's answer back; between
-phases a worker keeps reading its sockets, because the gateway fans out
-to servers and clients whether or not anyone is timing.
+"""The peers' processes. A worker holds some of a flow's peers on one
+selector (``wire.Peers``) and never touches jax. The parent tells it
+what to do over a pipe, one phase at a time, and gets each phase's
+answer back; between phases a worker keeps reading its sockets, because
+the gateway fans out whether or not anyone is timing. A flow's peer
+kinds subclass ``Worker``, and the flow names them in its ``WORKERS``.
 
-All workers make the same schedule from the seed (``plan``) and share
+The spatial world's peers are here: spatial servers that send
+(``Sender``) and clients that receive (``Receiver``). All of them make
+the same schedule from the seed (``plan``) and share
 ``CLOCK_MONOTONIC``, so a due time means the same instant to each.
 """
 
@@ -14,7 +16,6 @@ from __future__ import annotations
 import collections
 import gc
 import importlib
-import select
 import time
 
 import numpy as np
@@ -87,19 +88,19 @@ def client_centres(grid: Grid, n: int, radius: float, seed) -> list:
 CELL_PATH, ENTITY_PATH, HANDOVER_PATH = 0, 1, 2  # how a row reached a client
 
 
-class _Worker:
+class Worker:
+    """One worker process: its peers, and a ``do_<phase>`` method for each
+    phase the parent asks for."""
+
     def __init__(self, pipe, spec: dict):
         self.pipe, self.spec = pipe, spec
-        self.plan = plan(spec)
-        self.grid = self.plan["grid"]
-        self.peers: list = []
-        self.first_eid = self.grid.entity_start + 1
+        self.peers = wire.Peers()
 
     def on_message(self, peer, t: float, mp) -> None:
         raise NotImplementedError
 
     def pump(self, seconds: float, until=None) -> bool:
-        return wire.pump(self.peers, seconds, until, self.on_message)
+        return self.peers.pump(seconds, until, self.on_message)
 
     def serve(self) -> None:
         """Answer the parent's phases until ``quit``."""
@@ -126,11 +127,18 @@ class _Worker:
             self.pipe.send(("error", f"{type(e).__name__}: {e}\n"
                             f"{traceback.format_exc()}"))
         finally:
-            for p in self.peers:
-                p.close()
+            self.peers.close()
 
 
-class Sender(_Worker):
+class _SpatialWorker(Worker):
+    def __init__(self, pipe, spec: dict):
+        super().__init__(pipe, spec)
+        self.plan = plan(spec)
+        self.grid = self.plan["grid"]
+        self.first_eid = self.grid.entity_start + 1
+
+
+class Sender(_SpatialWorker):
     """Some of the spatial servers: claims their blocks, creates the
     entities that start in them, and in the window sends each update its
     servers own, stamped, on the open-loop schedule."""
@@ -170,7 +178,6 @@ class Sender(_Worker):
         """Connect this worker's servers and claim a block each."""
         for name in self.spec["servers"]:
             p = wire.connect(self.spec["sport"], name, self.peers)
-            self.peers.append(p)
             self.cells_of_peer[p] = set()
             p.queue(0, wire.CREATE_CHANNEL, wire.control_pb2.CreateChannelMessage(
                 channelType=wire.SPATIAL,
@@ -275,12 +282,8 @@ class Sender(_Worker):
                 left = frame_t - time.monotonic()
                 if left <= 0:
                     break
-                writers = [p for p in self.peers if p.flush()]
-                readable, _, _ = select.select(self.peers, writers, [],
-                                               min(left, 0.05))
-                for p in readable:
-                    for t, mp in p.read():
-                        self.on_message(p, t, mp)
+                self.peers.flush()
+                self.peers.wait(min(left, 0.05), self.on_message)
             horizon = f * frame + 1e-9
             while events and events[0][0] <= horizon:
                 _, k, n = events.popleft()
@@ -319,8 +322,7 @@ class Sender(_Worker):
             for (peer, cell), table in tables.items():
                 peer.queue(cell_start + cell, wire.DATA_UPDATE,
                            wire.data_update(table))
-            for p in self.peers:
-                p.flush()
+            self.peers.flush()
             f += 1
         # Held to the end for a handover that never came. What falls due
         # in the drain may still be waiting for one, fairly.
@@ -353,7 +355,7 @@ class _Client:
             connId=peer.conn_id).SerializeToString()
 
 
-class Receiver(_Worker):
+class Receiver(_SpatialWorker):
     """Some of the clients: connects them, keeps their subscriptions as
     an engine's client would, and notes every read of a stamped row."""
 
@@ -461,7 +463,6 @@ class Receiver(_Worker):
             p = wire.connect(self.spec["cport"], f"bench-client-{i}",
                              self.peers, attempts=30)
             self.clients[p] = _Client(p, cells, self.spec["entities"])
-            self.peers.append(p)
         return [p.conn_id for p in self.peers]
 
     def do_subs(self) -> list:
@@ -494,8 +495,12 @@ class Receiver(_Worker):
 
 
 def run_worker(kind: str, pipe, spec: dict) -> None:
-    """Entry of a spawned worker process."""
+    """Entry of a spawned worker process: the peers of kind ``kind`` of
+    the flow in the file ``spec["flow"]``."""
     import sys
 
     sys.path.insert(0, spec["repo"])
-    {"sender": Sender, "receiver": Receiver}[kind](pipe, spec).serve()
+    from .driver import load_file
+
+    flow = load_file(spec["flow"], "flow_" + kind)
+    flow.WORKERS[kind](pipe, spec).serve()

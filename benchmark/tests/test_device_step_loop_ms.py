@@ -58,4 +58,9 @@ def test_the_contract_names_the_reader():
         "name": "device_step_loop_ms", "unit": "ms", "better": "lower",
         "source": "program_span", "layer": "engine",
         "moves": "delivery_p50_ms", "workloads": ["npc-world-50k.roam"]}
-    assert contract["per_layer"][-1] is entry  # appended, nothing moved
+    # Appended, and nothing moved since: the two entries that followed it
+    # still follow it, in their order, whatever was appended after them.
+    names = [m["name"] for m in contract["per_layer"]]
+    at = names.index("device_step_loop_ms")
+    assert names[at:at + 3] == ["device_step_loop_ms",
+                                "fanout_sends_per_encode", "send_pump_busy_pct"]

@@ -1,6 +1,8 @@
-"""The whole flow at a tiny size on the CPU, for both kinds of
-configuration, and the ways a run must refuse to pass without a chip."""
+"""The whole run at a tiny size on the CPU, for both kinds of spatial
+configuration and for a deployment whose flow is a new file, and the ways
+a run must refuse to pass without a chip."""
 
+import glob
 import json
 import os
 import subprocess
@@ -49,6 +51,37 @@ def test_tiny_cell_is_correct_and_names_the_cpu(on_cpu, cell, trace):
         assert all(m["value"] > 0 for m in metrics.values())
 
 
+def test_a_flow_added_as_a_new_file_runs_end_to_end(on_cpu):
+    """GLOBAL chat, no spatial world: a configuration that names the flow
+    ``tiny_global``, which exists only as a new file in the copy."""
+    result = rehearse.rehearse(str(on_cpu / "root"), "tiny-chat.say")
+    checks = {k: v["value"] for k, v in result["checks"].items()}
+    assert result["correct"] is True, checks
+    assert list(checks) == ["device_faults", "says_unsent",
+                            "says_unreflected", "says_wrong"]
+    assert [k for k in result if k in RESULT_KEYS] == RESULT_KEYS
+    assert list(result)[-1] == "checks"
+    assert result["attempted"] > 0 and result["failed"] == 0
+    metrics = result["metrics"]
+    assert set(metrics) == {"delivery_p50_ms", "delivery_p95_ms",
+                            "deliveries_per_s", "setup_s"}
+    assert all(m["value"] > 0 for m in metrics.values())
+    assert not os.path.exists(os.path.join(REPO, "benchmark", "flows",
+                                           "tiny_global.py"))
+
+
+def test_a_flow_that_is_not_there_stops_the_run(tmp_path):
+    from benchmark.harness import driver
+    from benchmark.harness.gateway import BenchFailure
+
+    cell = driver.load_cell(rehearse.make_root(str(tmp_path)), "tiny-chat.say")
+    cell["config"]["flow"] = "nowhere"
+    with pytest.raises(BenchFailure, match="no flow 'nowhere'; there are: "
+                       "spatial, tiny_global"):
+        driver.run_cell(cell, 1, 1.0, False, 0.0, need_platform="")
+    assert not os.path.exists(os.path.join(cell["base"], "out"))
+
+
 def test_new_files_and_entries_are_found_with_no_file_edited(tmp_path):
     from benchmark.harness import driver
 
@@ -60,9 +93,17 @@ def test_new_files_and_entries_are_found_with_no_file_edited(tmp_path):
     assert "channel_ticks_hz" in names and "sim_step_roofline" not in names
     assert "sim_step_roofline" in [
         m["name"] for m in driver.load_cell(root, "tiny-world.amble")["per_layer"]]
+    chat = driver.load_cell(root, "tiny-chat.say")
+    assert chat["config"]["flow"] == "tiny_global"
+    assert driver.load_flow(chat).WORKERS.keys() == {"sayer", "listener"}
+    assert driver.load_flow(cell).WORKERS.keys() == {"sender", "receiver"}
     # ... and the repo's own files under the copy are byte for byte the
     # repo's: nothing was edited to make room.
-    for rel in ("run.py", "harness/driver.py", "traffic/roam.json",
+    harness = sorted(os.path.relpath(p, os.path.join(REPO, "benchmark"))
+                     for p in glob.glob(os.path.join(REPO, "benchmark",
+                                                     "harness", "*.py")))
+    assert "harness/driver.py" in harness and "harness/workers.py" in harness
+    for rel in ("run.py", *harness, "flows/spatial.py", "traffic/roam.json",
                 "layer_metrics/global_tick_hz.py", "generators/walk.py"):
         with open(os.path.join(REPO, "benchmark", rel), "rb") as a, \
                 open(os.path.join(root, "benchmark", rel), "rb") as b:

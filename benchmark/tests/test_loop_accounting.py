@@ -34,10 +34,9 @@ STAGES = {**stage("device_step", 3280.0, 200), **stage("step.flush", 100.0, 200)
           **stage("step.fetch", 2400.0, 200),
           **stage("step.census_fetch", 120.0, 4)}
 # A window of 20 s: the loop thread 9 s on the CPU, 1 s runnable, 6 s
-# asleep in 10,000 waits that took 0.25 s to win the lock back; the pump
-# 4 s in its sends, 1,000 releases and 0.03 s winning the lock back; the
-# worker 1.2 s on the CPU and 0.2 s runnable (operators' counters, which
-# no reader takes).
+# asleep in 10,000 waits that took 0.25 s to win the lock back; the send
+# writer 4 s in its sends; the worker 1.2 s on the CPU and 0.2 s runnable
+# (operators' counters, which no reader takes).
 COUNTERS = {
     **sample("thread_cpu_seconds_total", 9.0, thread="loop"),
     **sample("thread_runqueue_seconds_total", 1.0, thread="loop"),
@@ -47,15 +46,12 @@ COUNTERS = {
     **sample("loop_poll_seconds_total", 0.25, part="relock"),
     **sample("loop_polls_total", 10_000),
     **sample("send_pump_write_seconds_total", 4.0),
-    **sample("send_pump_relock_seconds_total", 0.03),
-    **sample("send_pump_releases_total", 1_000),
 }
 EXPECTED = {
     "loop_asleep_pct": 30.0,            # 6 s of 20
-    "loop_lock_wait_pct": 1.4,          # 0.25 + 0.03 s of 20
+    "loop_lock_wait_pct": 1.25,         # 0.25 s of 20
     "loop_wake_relock_us": 25.0,        # 0.25 s over 10,000
     "send_pump_write_pct": 20.0,        # 4 s of 20
-    "send_pump_relock_us": 30.0,        # 0.03 s over 1,000
 }
 
 
@@ -71,13 +67,13 @@ def test_reader_on_a_hand_made_ctx(name):
     assert read(dict(ctx, metrics={})) is None
 
 
-def test_the_lock_wait_is_the_two_timed_relocks():
-    """The loop's lock wait is what its two timed release sites read:
-    the wait's relock a wait times the waits, and the pump's relock a
-    release times the releases."""
+def test_the_lock_wait_is_the_timed_relock():
+    """The loop's lock wait is what its timed release site reads: the
+    wait's relock a wait times the waits. (The send pump's pass, whose
+    relock was the other site, gives the lock up nowhere since its sends
+    moved to the codec's writer thread.)"""
     ctx = {"metrics": {**STAGES, **COUNTERS}, "wall_s": 20.0}
-    relock_s = (reader("loop_wake_relock_us").read(ctx) * 1e-6 * 10_000
-                + reader("send_pump_relock_us").read(ctx) * 1e-6 * 1_000)
+    relock_s = reader("loop_wake_relock_us").read(ctx) * 1e-6 * 10_000
     assert reader("loop_lock_wait_pct").read(ctx) == \
         pytest.approx(100.0 * relock_s / 20.0)
 
@@ -88,16 +84,7 @@ def test_without_thread_clocks_the_lock_wait_is_still_read():
     counters = {k: v for k, v in COUNTERS.items()
                 if not k[0].startswith("thread_")}
     ctx = {"metrics": {**STAGES, **counters}, "wall_s": 20.0}
-    assert reader("loop_lock_wait_pct").read(ctx) == pytest.approx(1.4)
-
-
-def test_a_window_with_no_release_gives_no_relock_time():
-    ctx = {"metrics": {**COUNTERS,
-                       **sample("send_pump_releases_total", 0),
-                       **sample("send_pump_relock_seconds_total", 0.0)},
-           "wall_s": 20.0}
-    assert reader("send_pump_relock_us").read(ctx) is None
-    assert reader("send_pump_write_pct").read(ctx) == pytest.approx(20.0)
+    assert reader("loop_lock_wait_pct").read(ctx) == pytest.approx(1.25)
 
 
 # ---- the device's idle time under the pump and the wait -------------------

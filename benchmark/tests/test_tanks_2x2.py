@@ -48,11 +48,14 @@ def test_the_cell_reports_the_50k_cells_metrics_but_three_and_two_new_ones():
             cell["base"], "layer_metrics", name + ".py"))
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         per_layer = json.load(f)["per_layer"]
-    # New entries go to the end of the list, after PR 26's: the driver reads
-    # one put anywhere else as a change to what was there.
-    assert [m["name"] for m in per_layer[-3:]] == [
+    # New entries go after those already there: the driver reads one put
+    # anywhere else as a change to what was there. This cell's two follow
+    # device_step_loop_ms, in their order.
+    names = [m["name"] for m in per_layer]
+    at = names.index("device_step_loop_ms")
+    assert names[at:at + 3] == [
         "device_step_loop_ms", "fanout_sends_per_encode", "send_pump_busy_pct"]
-    for m in per_layer[-2:]:
+    for m in per_layer[at + 1:at + 3]:
         assert m["workloads"] == ["npc-world-50k.roam", "npc-world-100k.roam",
                                   "tanks-2x2.drive"]
 
